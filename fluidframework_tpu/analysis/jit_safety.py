@@ -53,7 +53,7 @@ from .core import (  # noqa: F401  (FuncInfo/build_func_index/resolve_in
 )
 
 JIT_NAMES = {"jax.jit"}
-SHARD_MAP_NAMES = {"jax.experimental.shard_map.shard_map", "shard_map"}
+SHARD_MAP_NAMES = {"jax.shard_map", "shard_map"}
 PARTIAL_NAMES = {"functools.partial"}
 # Wrappers that pass their first argument through to the trace.
 TRANSPARENT = {"jax.vmap", "jax.named_call", "jax.checkpoint", "jax.remat"}

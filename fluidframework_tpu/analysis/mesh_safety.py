@@ -19,7 +19,7 @@ has already cost a debugging session:
   ``out_specs`` replicate any output.  This is the live bug class the
   seg-parallel byte-identity fuzz caught: a donated shard_map executable
   with replicated outputs, RELOADED from the persistent XLA compile
-  cache, returns permuted garbage (jax 0.4.37 — see
+  cache, returned permuted garbage (seen on jax 0.4.37 — see
   ``parallel/mesh.py::mesh_seg_program``).  Fires on (a) a statically
   replicated ``out_specs`` (a bare ``P()`` literal in the spec tree)
   jitted with non-empty ``donate_argnums``, and (b) any program declared
@@ -296,7 +296,7 @@ def _jit_wrap_findings(index, pv, calls) -> list:
                     "donated jit over a shard_map whose out_specs "
                     "replicate an output: a donated replicated-output "
                     "executable reloaded from the persistent XLA compile "
-                    "cache mis-aliases its buffers (jax 0.4.37)"
+                    "cache mis-aliased its buffers (seen on jax 0.4.37)"
                 ),
                 hint=(
                     "keep donate_argnums empty for replicated-output "
@@ -361,7 +361,7 @@ def _declared_program_findings(index, pv, mesh_scope: dict,
                         "(mesh_scope) but its jit resolves to NON-EMPTY "
                         "donate_argnums: donated replicated-output "
                         "executables corrupt on persistent-cache reload "
-                        "(jax 0.4.37, two-process repro)"
+                        "(seen on jax 0.4.37, two-process repro)"
                     ),
                     hint=(
                         "keep donation OFF (donate defaults False) until "
@@ -380,7 +380,7 @@ def _declared_program_findings(index, pv, mesh_scope: dict,
                     message=(
                         f"{fn_name} (declared replicated-out) defaults "
                         "donate=True — the cache-reload aliasing bug "
-                        "class (jax 0.4.37)"
+                        "class (seen on jax 0.4.37)"
                     ),
                     hint="default donate=False; see the repro note",
                     detail=f"{fn_name}: donation enabled on replicated-out program",

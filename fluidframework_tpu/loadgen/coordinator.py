@@ -33,6 +33,7 @@ import time
 from dataclasses import dataclass, field
 
 from ..dds.mergetree_ref import RefMergeTree
+from ..dds.shared_string import decode_obliterate_places
 from ..dds.tree.changeset import apply_commit, commit_from_json
 from ..dds.tree.editmanager import EditManager
 from ..dds.tree.forest import Forest
@@ -91,6 +92,11 @@ def oracle_text(log) -> str:
                         c["pos1"], c["pos2"], int(prop), value,
                         msg.seq, client, msg.ref_seq,
                     )
+            elif kind in (DeltaType.OBLITERATE, DeltaType.OBLITERATE_SIDED):
+                p1, s1, p2, s2 = decode_obliterate_places(c)
+                tree.apply_obliterate(
+                    p1, s1, p2, s2, msg.seq, client, msg.ref_seq
+                )
     return tree.visible_text()
 
 
@@ -147,6 +153,7 @@ class _FleetProc:
     docs: list
     drain_file: str
     metrics_port: int | None = None
+    ready: dict = field(default_factory=dict)  # the fleet's readiness line
     final: dict = field(default_factory=dict)
 
 
@@ -206,7 +213,19 @@ class LoadPlant:
         deadline_s: float = 600.0,
         max_pending: int = 4096,
         max_consumer_backlog: int = 1024,
+        fleet_platform: str = "cpu",
     ) -> None:
+        if fleet_platform != "cpu":
+            # start_fleets spawns one fleet_main per (shard, family), and an
+            # accelerator belongs to one process: the second fleet would
+            # hang or fail at backend init.  One process hosting every
+            # family is ROADMAP D1.
+            raise ValueError(
+                f"fleet_platform={fleet_platform!r}: the plant starts one "
+                "fleet process per (shard, family) and cannot share a chip "
+                "between them; only 'cpu' is supported until the single "
+                "fleet host lands (ROADMAP D1)"
+            )
         self.workdir = workdir
         self.sched = schedule
         self.host = host
@@ -237,8 +256,11 @@ class LoadPlant:
         )
         for i in range(2):
             self.pool.add_member(f"scribe-{i}")
-        self._env = dict(os.environ)
-        self._env.setdefault("JAX_PLATFORMS", "cpu")
+        # Shards and workers never need a device, and the fleets run on
+        # the plant's stated ``fleet_platform`` (cpu, checked above): every
+        # child is pinned explicitly — nothing inherits a platform by
+        # accident.
+        self._env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     # --------------------------------------------------------------- spawn
     def _spawn(self, name: str, cmd: list, pipe: bool = True) -> subprocess.Popen:
@@ -321,6 +343,7 @@ class LoadPlant:
                     if "metricsPort" in line and "ready" not in line:
                         fleet.metrics_port = line["metricsPort"]
                     if line.get("ready"):
+                        fleet.ready = line
                         break
                 self.fleets.append(fleet)
                 serial += 1
@@ -649,6 +672,9 @@ class LoadPlant:
                 "bytes": f.final.get("bytes"),
                 "pump_pauses": f.final.get("pump_pauses"),
                 "pump_resumes": f.final.get("pump_resumes"),
+                "platform": f.ready.get("platform"),
+                "device_kind": f.ready.get("device_kind"),
+                "device_count": f.ready.get("device_count"),
             }
             for f in self.fleets
         ]
@@ -672,6 +698,8 @@ class LoadPlant:
             for k in ("requests", "cold_serves", "not_modified_304")
         }
         return {
+            # What the fleets' own readiness lines said they run on.
+            "platform": ",".join(sorted({r["platform"] for r in fleet_rows})),
             "seed": self.sched.seed,
             "workers": len(self.sched.workers),
             "shards": self.n_shards,
@@ -747,9 +775,12 @@ def run_loadgen(
     boots: int = 4,
     deadline_s: float = 600.0,
     host: str = "127.0.0.1",
+    fleet_platform: str = "cpu",
 ) -> dict:
     """Build the plant, run every phase, return the report dict (raises
-    ``LoadgenVerdictError`` on any invariant violation)."""
+    ``LoadgenVerdictError`` on any invariant violation).
+    ``fleet_platform`` is the JAX platform the device fleets run on; only
+    ``"cpu"`` is supported today (see ``LoadPlant``)."""
     matrix = dict(doc_matrix or DEFAULT_DOC_MATRIX)
     docs: list = []
     i = 0
@@ -766,7 +797,8 @@ def run_loadgen(
         seed, n_workers, docs,
         ramp_ops=ramp_ops, steady_ops=steady_ops, boots=boots,
     )
-    plant = LoadPlant(workdir, schedule, host=host, deadline_s=deadline_s)
+    plant = LoadPlant(workdir, schedule, host=host, deadline_s=deadline_s,
+                      fleet_platform=fleet_platform)
     try:
         return plant.run()
     finally:

@@ -930,6 +930,7 @@ class DocBatchEngine:
             # through the columnar fast path (ingest_batch routes lane
             # docs message by message itself, so semantics match).
             self._normalize_native(h)
+            self.counters.bump("ingest_python_chunks")
             lane = self.overflow.get(doc_idx) or self.seg_lanes.get(doc_idx)
             before = len(lane.queue) if lane else len(h.queue)
             msgs = [
@@ -952,6 +953,7 @@ class DocBatchEngine:
                 self.max_insert_len, self.geometry["prop_slots"]
             )
             h.mode = "native"
+        self.counters.bump("ingest_native_chunks")
         with span("ingest", doc=doc_idx, bytes=len(data)):
             ops, payloads = h.native.encode(data)
             if self.recovery != "off":
@@ -1341,11 +1343,7 @@ class DocBatchEngine:
         restore closes the open incident (kill -> first post-restore op
         applied)."""
         with self.ckpt_lock:
-            had_work = bool(
-                self._busy
-                or any(ln.queue for ln in self.overflow.values())
-                or any(ln.queue for ln in self.seg_lanes.values())
-            )
+            had_work = self._has_staged_rows()
             steps = self._step_fleet()
             if had_work and self.recovery_tracker.active:
                 self.recovery_tracker.complete()
@@ -1359,6 +1357,14 @@ class DocBatchEngine:
         # sweep would skip it.
         self.maybe_checkpoint()
         return steps
+
+    def _has_staged_rows(self) -> bool:
+        """Any op row ingested but not yet applied (batch or lanes)."""
+        return bool(
+            self._busy
+            or any(ln.queue for ln in self.overflow.values())
+            or any(ln.queue for ln in self.seg_lanes.values())
+        )
 
     def _step_fleet(self) -> int:
         t0 = time.perf_counter() if self.sampled is not None else 0.0
@@ -1681,7 +1687,17 @@ class DocBatchEngine:
         return True
 
     def compact(self) -> None:
-        """Advance MSNs and run zamboni eviction across the fleet."""
+        """Advance MSNs and run zamboni eviction across the fleet.
+
+        Rows still staged are applied FIRST.  A host's ``min_seq`` is the
+        MSN of the newest message ingested, which may postdate the
+        ref-seq of rows still queued (a consumer that fell behind reads
+        ops and the summary ack that follows them in one pass); zamboni
+        at that floor would evict tombstones those rows still resolve
+        their positions against, and they would land in the wrong place
+        with no error latched."""
+        if self._has_staged_rows():
+            self.step()
         mins = np.zeros((self.capacity,), np.int32)
         for d, h in enumerate(self.hosts):
             mins[self._slot[d]] = h.min_seq
@@ -2159,8 +2175,8 @@ class DocBatchEngine:
         """Fold the native encoder's C++ prop-interning table into the host
         table, so checkpoints and migrations of native-mode docs carry REAL
         property ids instead of private kernel slot numbers (ROADMAP:
-        native-path checkpoint fidelity).  No-op for object-path docs and
-        for native builds without the export; safe to call repeatedly —
+        native-path checkpoint fidelity).  No-op for object-path docs;
+        safe to call repeatedly —
         both tables intern in first-seen stream order, so entries agree."""
         if h.native is None:
             return
@@ -2849,8 +2865,20 @@ class DocBatchEngine:
                 3,
             ),
         )
+        from ..native import ingest_native
+
         snap = self.counters.snapshot()
         snap.update(
+            # Which step path ran (slices): fleet-wide vs bucketed cohort.
+            full_steps=self.full_steps,
+            cohort_steps=self.cohort_steps,
+            # Which wire decoder fed ingest_lines (native/ingest.cpp or
+            # the per-message Python decode).
+            ingest_plane=ingest_native.fed_plane(
+                snap.get("ingest_native_chunks", 0),
+                snap.get("ingest_python_chunks", 0),
+                ingest_native.loaded(),
+            ),
             quarantined_docs=len(self.quarantine),
             overflow_docs=len(self.overflow),
             oracle_docs=len(self.oracles),
@@ -2881,6 +2909,28 @@ class DocBatchEngine:
         if doc_idx in self.oracles:
             return self.oracles[doc_idx].visible_text()
         return mk.visible_text(self.doc_state(doc_idx))
+
+    def texts(self) -> list[str]:
+        """Every document's text, doc-indexed.  The batch state comes to
+        the host in ONE transfer per column and is sliced there: reading
+        ``text(d)`` per document costs ~30 small device slices plus their
+        transfers each, which at fleet size is minutes of readback for
+        what one bulk copy does in about a second."""
+        host = jax.tree.map(np.asarray, self.state)
+        off_batch = (
+            set(self.quarantine) | set(self.oracles)
+            | set(self.overflow) | set(self.seg_lanes)
+        )
+        out = []
+        for d in range(self.n_docs):
+            if d in off_batch:
+                out.append(self.text(d))
+            else:
+                slot = int(self._slot[d])
+                out.append(mk.visible_text(
+                    jax.tree.map(lambda x, _s=slot: x[_s], host)
+                ))
+        return out
 
     def annotations(self, doc_idx: int) -> list[dict[int, int]]:
         if doc_idx in self.quarantine:

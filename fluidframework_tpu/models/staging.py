@@ -271,7 +271,7 @@ class StagingRing:
                     self._shape_ops, self._shape_payloads
                 )
                 self.aliased_swaps += 1
-            elif all(_transfer_done(a) for a in arrs):
+            elif all(a.is_ready() for a in arrs):
                 # The upload that read this buffer already drained: this
                 # pack overlaps the previous megastep's device work.
                 self.overlapped_packs += 1
@@ -383,15 +383,3 @@ def upload_replicated(ops: np.ndarray, payloads: np.ndarray, mesh=None) -> tuple
             return jax.device_put(ops, rep), jax.device_put(payloads, rep)
     with span("upload", kind="seg", shards=1, bytes=nbytes):
         return jnp.asarray(ops), jnp.asarray(payloads)
-
-
-def _transfer_done(arr) -> bool:
-    """Non-blocking transfer-completion probe (best effort: absent on some
-    jax versions/backends, where the caller just blocks)."""
-    probe = getattr(arr, "is_ready", None)
-    if probe is None:
-        return False
-    try:
-        return bool(probe())
-    except Exception:  # noqa: BLE001 — a probe failure must never break staging
-        return False

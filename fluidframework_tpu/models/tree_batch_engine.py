@@ -283,8 +283,9 @@ class TreeBatchEngine:
             from ..dds.tree.device_rebase import DeviceRebaser
 
             self.rebaser = DeviceRebaser(self.markpool)
-        # ingest_lines rides the native tree decoder when its symbol is
-        # present (stale prebuilt .so -> Python decode, never a crash).
+        # ingest_lines rides the native tree decoder when a current
+        # library is loaded (health()['ingest_plane'] names the decoder
+        # that actually fed the fleet).
         self.native_wire = native_wire
         self.hosts = [
             _TreeHost(
@@ -471,11 +472,11 @@ class TreeBatchEngine:
     def ingest_lines(self, doc_idx: int, data: bytes) -> int:
         """Stage newline-separated wire JSON for one tree document — the
         firehose consumer seam (API parity with ``DocBatchEngine``).
-        With the native tree decoder present (native/ingest.cpp
-        ``ing_tree_decode``, symbol-gated like ``_sync_native_props``) and
-        the mark pool enabled, the envelope + mark numeric plane decodes
-        in C++ straight into pool columns; otherwise every line takes the
-        Python parse.  A malformed line lands all EARLIER lines, then
+        With the native tree decoder loaded (native/ingest.cpp
+        ``ing_tree_decode``) and the mark pool enabled, the envelope + mark
+        numeric plane decodes in C++ straight into pool columns; otherwise
+        every line takes the Python parse (``health()['ingest_plane']``
+        says which fed the fleet).  A malformed line lands all EARLIER lines, then
         raises through the Python decode (which owns error semantics) —
         per-document isolation, other docs' feeds are untouched.  Returns
         op rows staged (applied edits for fallback-routed docs)."""
@@ -491,7 +492,7 @@ class TreeBatchEngine:
             from ..native import ingest_native as inat
 
             try:
-                tables = inat.tree_decode(data)  # None: lib/symbol absent
+                tables = inat.tree_decode(data)  # None: no current library
             except ValueError:
                 # Malformed line: re-decode in Python so the error carries
                 # the Python path's exact semantics (earlier lines land).
@@ -501,6 +502,7 @@ class TreeBatchEngine:
             self.counters.bump("tree_native_batches")
             self._ingest_native_tables(doc_idx, data, tables)
         else:
+            self.counters.bump("tree_python_batches")
             for raw in data.split(b"\n"):
                 line = raw.strip()
                 if line:
@@ -1404,7 +1406,20 @@ class TreeBatchEngine:
             ),
         )
         snap = self.counters.snapshot()
+        from ..native import ingest_native as inat
+
+        bound = (
+            self.markpool is not None and self.native_wire and inat.loaded()
+        )
         snap.update(
+            # Which wire decoder fed ingest_lines, and whether the native
+            # ``ing_tree_decode`` entry is bound at all.
+            ingest_plane=inat.fed_plane(
+                snap.get("tree_native_batches", 0),
+                snap.get("tree_python_batches", 0),
+                bound,
+            ),
+            tree_decode_bound=bound,
             fallback_docs=len(self.fallbacks),
             checkpoint_age_seqs=max(
                 (h.last_seq - h.base_seq for h in self.hosts if h.last_seq),

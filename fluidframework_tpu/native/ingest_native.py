@@ -6,22 +6,26 @@ quorum lookup, insert chunking, property interning) runs in C++, replacing
 the per-op Python that bounds the fleet's ingest rate.  Differentially
 tested against the Python path in tests/test_native_ingest.py.
 
-Build: ``native/libtpuingest.so`` compiles with g++ if missing or stale
-(same scheme as the native sequencer; no pip/pybind11 dependencies) — but
-ONLY through ``warm()``/``available()``, which the engines call at
-construction time.  The serving-path accessors (``loaded``,
-``tree_decode``, ``NativeIngestEncoder``) never spawn the compiler: they
-run under the engines' ``ckpt_lock``, where a g++ run would stall every
-ingest contender for seconds (fftpu-check ``blocking-under-lock``).
+Build: ``native/libtpuingest.so`` compiles with g++ whenever the source's
+content hash differs from the one recorded beside the library
+(``native/_build.py``; no pip/pybind11 dependencies) — but ONLY through
+``warm()``/``available()``, which the engines call at construction time.
+The serving-path accessors (``loaded``, ``tree_decode``,
+``NativeIngestEncoder``) never spawn the compiler: they run under the
+engines' ``ckpt_lock``, where a g++ run would stall every ingest
+contender for seconds (fftpu-check ``blocking-under-lock``).  A library
+that is not current is never loaded: the callers take the Python decode
+and ``health()['ingest_plane']`` says so.
 """
 
 from __future__ import annotations
 
 import ctypes
-import subprocess
 from pathlib import Path
 
 import numpy as np
+
+from ._build import NativeBuildError, ensure_built, is_current
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _SRC = _REPO_ROOT / "native" / "ingest.cpp"
@@ -31,55 +35,54 @@ OP_FIELDS = 8
 
 _lib_cache: list = []
 _warmed: list = []
+_build_error: list[str] = []
 
 
 def warm() -> bool:
-    """Build (when missing or stale vs the source) and load the library,
+    """Build (when the recorded source hash differs) and load the library,
     eagerly and idempotently.  This is the ONLY entry that runs g++: call
     it at process/engine startup, never from a serving path — the lazy
     rebuild used to be reachable under the engines' ``ckpt_lock``, and a
     multi-second compiler run under the serving lock convoys every ingest
     (fftpu-check blocking-under-lock: subprocess under ckpt_lock).  The
     engines warm in ``__init__``; the hot-path accessors below only ever
-    LOAD a prebuilt library.
-
-    The idempotence latch is the WARM flag, not the lib cache: a
-    non-building accessor touched first may have cached a loadable but
-    STALE .so, and the first warm() must still run the staleness rebuild
-    (already-constructed encoders keep their old handle; everything after
-    the warm sees the fresh library)."""
+    LOAD a current library.  False means the build failed
+    (``build_error()`` has the compiler's words); serving entry points
+    treat that as fatal."""
     if _warmed:
         return bool(_lib_cache) and _lib_cache[0] is not None
     _warmed.append(True)
     try:
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 "-o", str(_LIB), str(_SRC)],
-                check=True, capture_output=True,
-            )
-    except (OSError, subprocess.CalledProcessError):
-        pass  # a previously-built library may still load below
-    _lib_cache[:] = [_try_load()]
-    return _lib_cache[0] is not None
+        ensure_built(_SRC, _LIB)
+    except NativeBuildError as e:
+        _build_error[:] = [str(e)]
+        _lib_cache[:] = [None]
+        return False
+    _lib_cache[:] = [_load()]
+    return True
+
+
+def build_error() -> str | None:
+    """The compiler failure the last ``warm()`` hit, if any."""
+    return _build_error[0] if _build_error else None
 
 
 def _ensure_built() -> ctypes.CDLL | None:
-    """Serving-path accessor: the cached library, loading a PREBUILT .so
-    on first touch — never compiling.  Returns None when no usable
-    prebuilt library exists (the callers fall back to the Python decode
-    paths); ``warm()`` upgrades a None verdict after building."""
+    """Serving-path accessor: the cached library, loading a CURRENT .so
+    on first touch — never compiling.  Returns None when the library is
+    missing or was built from other source bytes (the callers fall back
+    to the Python decode paths); ``warm()`` upgrades a None verdict after
+    building."""
     if _lib_cache:
         return _lib_cache[0]
-    _lib_cache[:] = [_try_load() if _LIB.exists() else None]
+    _lib_cache[:] = [_load() if is_current(_SRC, _LIB) else None]
     return _lib_cache[0]
 
 
-def _try_load() -> ctypes.CDLL | None:
-    try:
-        lib = ctypes.CDLL(str(_LIB))
-    except OSError:
-        return None
+def _load() -> ctypes.CDLL:
+    lib = ctypes.CDLL(str(_LIB))
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    i64p = ctypes.POINTER(ctypes.c_int64)
     lib.ing_create.restype = ctypes.c_void_p
     lib.ing_create.argtypes = [ctypes.c_int32, ctypes.c_int32]
     lib.ing_destroy.argtypes = [ctypes.c_void_p]
@@ -90,30 +93,19 @@ def _try_load() -> ctypes.CDLL | None:
     lib.ing_encode.restype = ctypes.c_int32
     lib.ing_encode.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
-        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-        ctypes.c_int32,
+        i32p, i32p, ctypes.c_int32,
     ]
-    # Prop-table export (checkpoint fidelity): absent from prebuilt .so
-    # files older than the symbol — gate, don't crash (prop_table()
-    # returns {} and checkpoints keep the legacy slot-number ids).
-    if hasattr(lib, "ing_prop_table"):
-        lib.ing_prop_table.restype = ctypes.c_int32
-        lib.ing_prop_table.argtypes = [
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int32,
-        ]
-    # Tree wire decode: same symbol-presence gate (a stale prebuilt .so
-    # simply keeps the Python tree decode).
-    if hasattr(lib, "ing_tree_decode"):
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        lib.ing_tree_decode.restype = ctypes.c_int32
-        lib.ing_tree_decode.argtypes = [
-            ctypes.c_char_p, ctypes.c_int64,
-            i64p, ctypes.c_int32, i32p, ctypes.c_int32,
-            i32p, ctypes.c_int32, i32p, ctypes.c_int32,
-            i64p, ctypes.c_int32, i32p, i32p,
-        ]
+    lib.ing_prop_table.restype = ctypes.c_int32
+    lib.ing_prop_table.argtypes = [
+        ctypes.c_void_p, i64p, i32p, ctypes.c_int32,
+    ]
+    lib.ing_tree_decode.restype = ctypes.c_int32
+    lib.ing_tree_decode.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64,
+        i64p, ctypes.c_int32, i32p, ctypes.c_int32,
+        i32p, ctypes.c_int32, i32p, ctypes.c_int32,
+        i64p, ctypes.c_int32, i32p, i32p,
+    ]
     return lib
 
 
@@ -125,8 +117,20 @@ def available() -> bool:
 
 def loaded() -> bool:
     """Non-building availability probe for serving paths (safe under the
-    engines' locks): True iff a prebuilt library is loaded/loadable."""
+    engines' locks): True iff a current library is loaded/loadable."""
     return _ensure_built() is not None
+
+
+def fed_plane(native_chunks: int, python_chunks: int, next_native: bool) -> str:
+    """The engines' ``health()['ingest_plane']``: which decoder actually
+    fed ``ingest_lines`` so far — ``native`` (this library only),
+    ``python`` (per-message decode only) or ``mixed``.  Before any feed it
+    names the plane the next chunk would take (``next_native``)."""
+    if native_chunks and python_chunks:
+        return "mixed"
+    if native_chunks or python_chunks:
+        return "native" if native_chunks else "python"
+    return "native" if next_native else "python"
 
 
 class NativeIngestEncoder:
@@ -155,10 +159,7 @@ class NativeIngestEncoder:
         Checkpoint fidelity (ROADMAP): the engine folds this into its host
         table before summarizing a native-mode doc, so checkpoints carry
         the documents' REAL annotation property ids — a restored doc's
-        annotations round-trip instead of surfacing private slot numbers.
-        Empty when the loaded library predates the export."""
-        if not hasattr(self._lib, "ing_prop_table"):
-            return {}
+        annotations round-trip instead of surfacing private slot numbers."""
         cap = 16
         while True:
             props = np.empty((cap,), np.int64)
@@ -213,23 +214,17 @@ _TREE_MARK_FIELDS = 5
 TREE_ST_EDITS, TREE_ST_SKIP, TREE_ST_OPAQUE = 0, 1, 2
 
 
-def tree_decode_available() -> bool:
-    lib = _ensure_built()
-    return lib is not None and hasattr(lib, "ing_tree_decode")
-
-
 def tree_decode(data: bytes):
     """Decode newline-separated sequenced tree messages into mark-pool
     columns (stateless; the whole-feed grow-and-retry contract of
     ``NativeIngestEncoder.encode``).
 
     Returns ``(msgs, chgs, flds, marks, spans)`` numpy tables — see the
-    C header comment for layouts — or ``None`` when the library (or the
-    ``ing_tree_decode`` symbol on a stale prebuilt .so) is unavailable.
-    Raises ``ValueError`` on a malformed line (message index included),
-    matching the Python path's ownership of error semantics."""
+    C header comment for layouts — or ``None`` when no current library is
+    loaded.  Raises ``ValueError`` on a malformed line (message index
+    included), matching the Python path's ownership of error semantics."""
     lib = _ensure_built()
-    if lib is None or not hasattr(lib, "ing_tree_decode"):
+    if lib is None:
         return None
     n_lines = data.count(b"\n") + 1
     m_msgs = max(16, n_lines)

@@ -7,8 +7,10 @@ identical to ``ops.mergetree_kernel.apply_megastep`` /
 tests/test_dispatch_backends.py against the lax oracle).  The dispatch
 plane built on top lives in ``parallel/native_plane.py``.
 
-Build: ``native/libtpumegastep.so`` compiles with g++ if missing or stale
-— but ONLY through ``warm()``/``available()``, which the plane calls at
+Build: ``native/libtpumegastep.so`` compiles with g++ whenever the
+source's content hash differs from the one recorded beside the library
+(``native/_build.py``) — but ONLY through ``warm()``/``available()``,
+which the plane calls at
 program-build time (engine construction).  The serving-path entry points
 (``loaded``, ``megastep``, ``fleet_compact``) never spawn the compiler:
 they can run under the engines' ``ckpt_lock``, where a g++ run would
@@ -18,10 +20,11 @@ stall every ingest contender for seconds (fftpu-check
 from __future__ import annotations
 
 import ctypes
-import subprocess
 from pathlib import Path
 
 import numpy as np
+
+from ._build import NativeBuildError, ensure_built, is_current
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _SRC = _REPO_ROOT / "native" / "megastep.cpp"
@@ -48,44 +51,36 @@ _COL_ORDER = (
 
 
 def warm() -> bool:
-    """Build (when missing or stale vs the source) and load the library,
+    """Build (when the recorded source hash differs) and load the library,
     eagerly and idempotently.  This is the ONLY entry that runs g++: the
     native plane calls it while building its fleet programs (engine
     ``__init__``, outside any serving lock) — the hot-path accessors
-    below only ever LOAD a prebuilt library (same warm/loaded split as
-    ``ingest_native``, the PR 15 blocking-under-lock fix)."""
+    below only ever LOAD a current library (same warm/loaded split as
+    ``ingest_native``, the PR 15 blocking-under-lock fix).  False means
+    the build failed; the plane raises on it."""
     if _warmed:
         return bool(_lib_cache) and _lib_cache[0] is not None
     _warmed.append(True)
     try:
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 "-o", str(_LIB), str(_SRC)],
-                check=True, capture_output=True,
-            )
-    except (OSError, subprocess.CalledProcessError):
-        pass  # a previously-built library may still load below
-    _lib_cache[:] = [_try_load()]
-    return _lib_cache[0] is not None
+        ensure_built(_SRC, _LIB)
+    except NativeBuildError:
+        _lib_cache[:] = [None]
+        return False
+    _lib_cache[:] = [_load()]
+    return True
 
 
 def _ensure_built() -> ctypes.CDLL | None:
-    """Serving-path accessor: the cached library, loading a PREBUILT .so
+    """Serving-path accessor: the cached library, loading a CURRENT .so
     on first touch — never compiling."""
     if _lib_cache:
         return _lib_cache[0]
-    _lib_cache[:] = [_try_load() if _LIB.exists() else None]
+    _lib_cache[:] = [_load() if is_current(_SRC, _LIB) else None]
     return _lib_cache[0]
 
 
-def _try_load() -> ctypes.CDLL | None:
-    try:
-        lib = ctypes.CDLL(str(_LIB))
-    except OSError:
-        return None
-    if not hasattr(lib, "ms_megastep"):
-        return None
+def _load() -> ctypes.CDLL:
+    lib = ctypes.CDLL(str(_LIB))
     lib.ms_abi_version.restype = ctypes.c_int32
     lib.ms_abi_version.argtypes = []
     lib.ms_megastep.restype = ctypes.c_int32
@@ -93,7 +88,11 @@ def _try_load() -> ctypes.CDLL | None:
     lib.ms_compact.restype = ctypes.c_int32
     lib.ms_compact.argtypes = [_I64P, _I32P, _I32P]
     if lib.ms_abi_version() != ABI_VERSION:
-        return None
+        raise RuntimeError(
+            f"{_LIB.name} reports ABI {lib.ms_abi_version()}, binding "
+            f"expects {ABI_VERSION}: megastep.cpp and megastep_native.py "
+            "disagree"
+        )
     return lib
 
 

@@ -7,18 +7,19 @@ than full ClientEntry objects) and bit-identical sequencing decisions —
 enforced by the differential suite in tests/test_native_sequencer.py. The integer state machine runs in C++;
 message-object construction stays in Python (it is not the hot part).
 
-Build: ``native/libtpusequencer.so`` is compiled on demand with g++ if the
-checked-in binary is missing or stale (no pip/pybind11 dependencies).
+Build: ``native/libtpusequencer.so`` is compiled on demand with g++
+whenever the source's content hash differs from the one recorded beside the
+library (``native/_build.py``; no pip/pybind11 dependencies).
 """
 
 from __future__ import annotations
 
 import ctypes
-import subprocess
 import time
 from pathlib import Path
 
 from ..protocol.messages import MessageType, Nack, SequencedMessage, UnsequencedMessage
+from ._build import NativeBuildError, ensure_built
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _SRC = _REPO_ROOT / "native" / "sequencer.cpp"
@@ -34,15 +35,10 @@ _NACK_REASONS = {
 
 def _ensure_built() -> ctypes.CDLL | None:
     try:
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 "-o", str(_LIB), str(_SRC)],
-                check=True, capture_output=True,
-            )
-        lib = ctypes.CDLL(str(_LIB))
-    except (OSError, subprocess.CalledProcessError):
-        return None
+        ensure_built(_SRC, _LIB)
+    except NativeBuildError:
+        return None  # native_available() is False; the Python twin serves
+    lib = ctypes.CDLL(str(_LIB))
     lib.seq_create.restype = ctypes.c_void_p
     lib.seq_create.argtypes = [ctypes.c_int64]
     lib.seq_destroy.argtypes = [ctypes.c_void_p]

@@ -264,19 +264,16 @@ class RecompileWatchdog:
         self.per_program: dict[str, int] = {}
 
     def register(self, name: str, fn: Any) -> None:
-        """Track ``fn`` (idempotent; ignores non-jitted callables).  The
-        baseline is the CURRENT cache size, so compiles that already
-        happened (warmup, shared module-level caches) are not charged."""
+        """Track ``fn`` (idempotent).  ``fn`` must expose ``_cache_size()``
+        as ``jax.jit`` callables do — anything else raises here, because a
+        program that silently went untracked would report zero recompiles
+        forever (a dispatch plane whose programs never compile declares a
+        constant one).  The baseline is the CURRENT cache size, so
+        compiles that already happened (warmup, shared module-level
+        caches) are not charged."""
         if name in self._progs:
             return
-        probe = getattr(fn, "_cache_size", None)
-        if probe is None:
-            return
-        try:
-            size = int(probe())
-        except Exception:  # noqa: BLE001 — a probe failure must never break serving
-            return
-        self._progs[name] = (fn, size)
+        self._progs[name] = (fn, int(fn._cache_size()))
         self.per_program.setdefault(name, 0)
 
     def poll(self) -> int:
@@ -284,10 +281,7 @@ class RecompileWatchdog:
         call.  Each growth emits a ``recompile`` instant event."""
         grew = 0
         for name, (fn, last) in list(self._progs.items()):
-            try:
-                size = int(fn._cache_size())
-            except Exception:  # noqa: BLE001 — see register
-                continue
+            size = int(fn._cache_size())
             if size > last:
                 delta = size - last
                 grew += delta

@@ -10,9 +10,10 @@ blocks, keeping the working set at [Q, BLOCK] and writing each query's hit
 exactly once — the classic memory-bound fusion the guide's "grid over the
 long axis, accumulate into a replicated output block" pattern covers.
 
-``resolve_positions_blocked`` is the public entry: jnp fallback for
-non-TPU backends (tests run it in interpret mode as well, differentially
-against the fallback).
+``resolve_positions_blocked`` is the public entry: the Pallas kernel on a
+TPU, the jnp form on backends Mosaic does not target (tests run the kernel
+in interpret mode as well, differentially against the jnp form;
+``chip_smoke.py`` compiles it on the chip against the same reference).
 """
 
 from __future__ import annotations
@@ -122,9 +123,12 @@ def resolve_positions_reference(
 def resolve_positions_blocked(
     lens: jnp.ndarray, positions: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Backend-dispatching entry: the Pallas kernel on TPU (2.2x the jnp
-    form at 256 queries x 262k segments, and O(Q*BLOCK) VMEM instead of an
-    [Q, S] HBM intermediate), the jnp form elsewhere (CPU test meshes)."""
+    """Backend-dispatching entry: the Pallas kernel on TPU (O(Q*BLOCK) VMEM
+    instead of an [Q, S] HBM intermediate), the jnp form elsewhere (CPU
+    test meshes; Mosaic has no CPU target).  On a v5e the kernel compiles
+    and matches the jnp form at 256 queries x 262,144 segments, but was
+    not faster there: 2.4-2.6 ms against 1.6-2.1 ms per call, input upload
+    included (chip run, PR 21 — PERF.md)."""
     if jax.default_backend() == "tpu":
         return resolve_positions_pallas(lens, positions)
     return resolve_positions_reference(lens, positions)

@@ -38,7 +38,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability.flight_recorder import span
@@ -114,13 +113,20 @@ def make_sharded_ops(mesh: Mesh, state: DocState, axis: str = "segs"):
     document layout, each shard_map-jitted over the segment axis."""
     specs = _specs_for(state, axis)
 
-    @partial(shard_map, mesh=mesh, in_specs=(specs, P(), P()), out_specs=P())
+    # check_vma=False throughout: every output is a psum (replicated by
+    # construction) or per-shard state, and the TPU form of _resolve calls
+    # a Pallas kernel, which carries no varying-axes annotation.
+    @partial(
+        jax.shard_map, mesh=mesh, in_specs=(specs, P(), P()), out_specs=P(),
+        check_vma=False,
+    )
     def _visible_length(s: DocState, ref_seq, client):
         return jax.lax.psum(jnp.sum(_local_vis_lens(s, ref_seq, client, axis)), axis)
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, P(), P(), P()), out_specs=(P(), P()),
+        check_vma=False,
     )
     def _resolve(s: DocState, positions, ref_seq, client):
         """positions[Q] (replicated, in perspective-visible coordinates) ->
@@ -147,9 +153,10 @@ def make_sharded_ops(mesh: Mesh, state: DocState, axis: str = "segs"):
         )
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, P(), P(), P(), P(), P(), P()),
         out_specs=specs,
+        check_vma=False,
     )
     def _mark_range(s: DocState, p1, p2, op_key, op_client, ref_seq, client):
         """Remove [p1, p2) under the op's perspective as a purely-local mask

@@ -44,7 +44,6 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -212,17 +211,21 @@ def mesh_seg_program(step_fn, mesh: Mesh, state_specs,
 
     ``donate`` defaults OFF, deliberately: with donation, an executable
     for this program RELOADED from the persistent XLA compile cache
-    returns permuted/garbage output buffers whenever the obliterate
-    branch executes (jax 0.4.37, CPU; freshly-compiled executables are
-    always correct, and tests/test_segment_parallel.py guards the
-    byte-identity contract that caught it).  Re-enable only with the
-    persistent cache off or after the upstream aliasing bug is fixed."""
-    mapped = shard_map(
+    returned permuted/garbage output buffers whenever the obliterate
+    branch executed.  That was seen on jax 0.4.37, CPU backend (freshly
+    compiled executables were always correct, and
+    tests/test_segment_parallel.py guards the byte-identity contract that
+    caught it).  On the installed jax 0.9.0 a two-process retry (three
+    seeds, donated, reloaded twice from the cache, CPU) stayed
+    byte-identical, which is a non-reproduction, not a proof: turning
+    donation on is its own change, with this program in the on-chip
+    smoke's warm leg first."""
+    mapped = jax.shard_map(
         step_fn,
         mesh=mesh,
         in_specs=(state_specs,) + tuple(arg_specs),
         out_specs=state_specs,
-        check_rep=False,  # replicated leaves are replicated by construction
+        check_vma=False,  # replicated leaves are replicated by construction
     )
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 
@@ -249,12 +252,12 @@ def mesh_fleet_program(step_fn, mesh: Mesh, state_specs,
     of PartitionSpec) and ``arg_specs`` the specs of the non-state args
     (default: a [K, D, B, *] megastep op ring pair), so the program cache
     is shared by every engine instance serving the same mesh."""
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step_fn,
         mesh=mesh,
         in_specs=(state_specs,) + tuple(arg_specs),
         out_specs=state_specs,
-        check_rep=False,  # per-doc program: nothing is replicated to check
+        check_vma=False,  # per-doc program: nothing is replicated to check
     )
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 

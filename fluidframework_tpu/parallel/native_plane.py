@@ -86,6 +86,11 @@ def _native_compact(state, min_seqs):
     return _wrap(megastep_native.fleet_compact(state, min_seqs))
 
 
+# The native programs never compile: a constant executable-cache size lets
+# the engines' RecompileWatchdog register them like any jitted program.
+_native_megastep._cache_size = _native_compact._cache_size = lambda: 0
+
+
 def mesh_fleet_program(step_fn, mesh, state_specs, arg_specs=None,
                        donate=True):
     """The plane's program factory.  The two fleet hot-path bodies map to

@@ -15,6 +15,12 @@ megastep dispatch; composes with --megastep-k), ``--spare-slots``/
 Emits one JSON status line per --status-every seconds (rows applied,
 bytes consumed, per-doc error flags) for process supervisors.
 ``--exit-after-rows`` bounds the run (tests / draining restarts).
+
+The process owns its accelerator: JAX picks the platform (``JAX_PLATFORMS``
+in the environment is how tests ask for the CPU), the readiness line says
+which device the engine state actually lives on and how many bytes each
+device holds, and the persistent compile cache is always on
+(``utils/compile_cache.py`` decides where).
 """
 
 from __future__ import annotations
@@ -65,6 +71,20 @@ def status_snapshot(eng, doc_ids, rows=0, bytes_consumed=0, **extra) -> dict:
         sharded = seg()
         if sharded:
             out["segmentSharded"] = sharded
+    return out
+
+
+def resident_bytes_per_device(state) -> dict[str, int]:
+    """Bytes of engine state each device holds, keyed by device id (from
+    the state arrays' addressable shards — where the data IS, not where a
+    sharding spec says it should be)."""
+    import jax
+
+    out: dict[str, int] = {}
+    for leaf in jax.tree.leaves(state):
+        for shard in leaf.addressable_shards:
+            key = str(shard.device.id)
+            out[key] = out.get(key, 0) + int(shard.data.nbytes)
     return out
 
 
@@ -177,9 +197,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--seg-rebalance-every", type=int, default=0,
                    help="ops applied on a segment lane between segment "
                         "re-blocks (0 = manual)")
-    p.add_argument("--platform", default=None,
-                   help="force a jax platform (e.g. cpu); overrides the "
-                        "image default and the FFTPU_PLATFORM env var")
     p.add_argument("--metrics-port", type=int, default=None,
                    help="serve Prometheus /metrics + JSON /status on this "
                         "port (0 = ephemeral, reported in the readiness "
@@ -196,16 +213,24 @@ def main(argv: list[str] | None = None) -> int:
                         "events overwrite; the dump reports drops)")
     args = p.parse_args(argv)
 
-    # Platform pinning must land before any backend initializes (some
-    # images force their platform list AFTER env-var processing, so
-    # JAX_PLATFORMS alone is not reliable).
     import os as _os
 
-    platform = args.platform or _os.environ.get("FFTPU_PLATFORM")
-    if platform:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", platform)
+    from ..native import ingest_native
+    from ..utils import compile_cache
+
+    compile_cache.enable()
+    compile_stats = compile_cache.CompileStats().install()
+    if not ingest_native.warm():
+        # The firehose feeds the device through native/ingest.cpp; a tier
+        # that cannot build it must not start on the per-message Python
+        # decode and look healthy.
+        print(json.dumps({
+            "error": "native ingest library failed to build",
+            "detail": ingest_native.build_error(),
+        }), flush=True)
+        return 1
 
     from .fleet_consumer import FleetConsumer
     from .ordered_log import CheckpointStore
@@ -216,14 +241,17 @@ def main(argv: list[str] | None = None) -> int:
         if args.checkpoint_dir is not None
         else None
     )
+    devices = jax.devices()
     mesh = None
     if args.mesh:
-        import jax
-
         from ..parallel.mesh import doc_mesh, docs_segs_mesh
 
-        devices = jax.devices()
-        n_dev = len(devices) if args.mesh < 0 else min(args.mesh, len(devices))
+        if args.mesh > len(devices):
+            p.error(
+                f"--mesh {args.mesh} needs {args.mesh} devices; JAX sees "
+                f"{len(devices)} ({devices[0].platform})"
+            )
+        n_dev = len(devices) if args.mesh < 0 else args.mesh
         if args.seg_shards > 1:
             mesh = docs_segs_mesh(devices[:n_dev], args.seg_shards)
         else:
@@ -368,6 +396,12 @@ def main(argv: list[str] | None = None) -> int:
         "family": args.family,
         "docs": doc_ids,
         "port": args.port,
+        # Where the engine state lives, as JAX reports it in THIS process.
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "resident_bytes_per_device": resident_bytes_per_device(eng.state),
+        "compile": compile_stats.snapshot(),
     }
     if metrics_srv is not None:
         ready["metricsPort"] = metrics_srv.port
@@ -388,6 +422,7 @@ def main(argv: list[str] | None = None) -> int:
         ).start()
 
     def status(**extra) -> None:
+        extra.setdefault("compile", compile_stats.snapshot())
         if ckpt_writer is not None:
             extra.setdefault("ckptWriter", ckpt_writer.stats())
         if heartbeat is not None:
@@ -409,7 +444,7 @@ def main(argv: list[str] | None = None) -> int:
         if args.family == "tree":
             return {"trees": {d: eng.tree_json(i)
                               for i, d in enumerate(doc_ids)}}
-        return {"texts": {d: eng.text(i) for i, d in enumerate(doc_ids)}}
+        return {"texts": dict(zip(doc_ids, eng.texts()))}
 
     drain_want: dict | None = None
     last_drain_poll = 0.0

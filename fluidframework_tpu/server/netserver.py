@@ -56,12 +56,14 @@ class _ClientSession:
     writer tier — handler threads and the broadcast path never block on a
     socket buffer, and never write the socket concurrently."""
 
-    def __init__(self, handler: "_NexusHandler", peer, plane) -> None:
-        self.handler = handler
+    def __init__(self, peer, plane) -> None:
         self.peer = peer
         self._plane = plane
         self.doc_id: str | None = None
         self.client_id: str | None = None
+        # Set by a successful ``consume`` handshake: the connection is now
+        # write-only from the front's side (see _FirehoseWatch).
+        self.firehose = False
 
     def send(self, obj: dict) -> None:
         self.send_raw((json.dumps(obj) + "\n").encode())
@@ -71,7 +73,9 @@ class _ClientSession:
 
 
 class _NexusHandler(socketserver.StreamRequestHandler):
-    """One thread per TCP client (ref: one socket.io connection).
+    """One thread per TCP client (ref: one socket.io connection) — for as
+    long as the client may speak; a quiet firehose consumer gives its
+    thread back (``_serve``).
 
     The READ half lives here (blocking in a selector, line-split in
     Python); the WRITE half lives on the shared fan-out writer thread —
@@ -85,78 +89,203 @@ class _NexusHandler(socketserver.StreamRequestHandler):
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         sock.setblocking(False)
         peer = server.fanout.new_peer(sock=sock)
-        session = _ClientSession(self, peer, server.fanout)
-        try:
-            self._read_loop(server, session, sock)
-        except OSError:
-            # Torn peer mid-read (abrupt client death, chaos torn-socket):
-            # normal teardown, counted for the overload/chaos surface —
-            # the finally broadcasts the leave via drop_session.
-            with server.lock:
-                server.torn_sockets += 1
-        finally:
-            server.drop_session(session)
+        _serve(server, _ClientSession(peer, server.fanout), sock)
 
-    def _read_loop(self, server: "NetworkServer", session, sock) -> None:
-        sel = selectors.DefaultSelector()
-        sel.register(sock, selectors.EVENT_READ)
-        buf = b""
-        try:
+
+def _serve(server: "NetworkServer", session: _ClientSession, sock,
+           buf: bytes = b"") -> None:
+    """Serve one connection from this thread until the peer leaves — or,
+    for a firehose consumer that has nothing more to say, until the socket
+    is parked with the firehose watch (the thread ends, the connection
+    lives on; the watch calls back in here if the peer ever speaks)."""
+    parked = False
+    try:
+        parked = _read_loop(server, session, sock, buf)
+    except OSError:
+        # Torn peer mid-read (abrupt client death, chaos torn-socket):
+        # normal teardown, counted for the overload/chaos surface —
+        # drop_session below broadcasts the leave.
+        with server.lock:
+            server.torn_sockets += 1
+    finally:
+        if parked:
+            server.firehose_watch.park(sock, session)
+        else:
+            server.drop_session(session)
+            server.firehose_watch.release(sock)
+
+
+def _read_loop(server: "NetworkServer", session: _ClientSession, sock,
+               buf: bytes) -> bool:
+    """Dispatch requests line by line; True when the connection should be
+    parked (a firehose consumer between requests), False when it ended."""
+    # poll, not epoll: an epoll instance is a descriptor of its own, and
+    # one per connection halves the sockets a front can hold under
+    # RLIMIT_NOFILE (a 10k-document fleet is 10k firehose sockets).
+    sel = selectors.PollSelector()
+    sel.register(sock, selectors.EVENT_READ)
+    try:
+        while True:
             while True:
-                sel.select()
+                cut = buf.find(b"\n")
+                if cut < 0:
+                    break
+                line, buf = buf[:cut].strip(), buf[cut + 1:]
+                if line and not _dispatch(server, session, line):
+                    return False
+            if session.firehose and not buf:
+                return True
+            sel.select()
+            try:
+                data = sock.recv(1 << 16)
+            except (BlockingIOError, InterruptedError):
+                continue
+            if not data:
+                return False  # orderly EOF
+            buf += data
+    finally:
+        sel.close()
+
+
+def _dispatch(server: "NetworkServer", session: _ClientSession,
+              line: bytes) -> bool:
+    """One protocol request; False ends the session (disconnect)."""
+    try:
+        req = json.loads(line)
+    except json.JSONDecodeError:
+        session.send({"t": "error", "reason": "bad json", "canRetry": False})
+        return True
+    kind = req.get("t")
+    if kind == "connect":
+        server.handle_connect(session, req)
+    elif kind == "consume":
+        server.handle_consume(session, req)
+    elif kind == "submit":
+        server.handle_submit(session, req)
+    elif kind == "signal":
+        server.handle_signal(session, req)
+    elif kind == "interests":
+        server.handle_interests(session, req)
+    elif kind == "sync":
+        # Echo AFTER everything already broadcast on this socket: the
+        # echo rides the peer queue behind every frame already
+        # published for the session's document (direct-watermark
+        # ordering) — the client's deterministic quiescence marker.
+        session.send({"t": "sync", "n": req.get("n", 0)})
+    elif kind == "disconnect":
+        # Graceful goodbye: everything already queued for this socket
+        # (a pipelined sync echo, the tail of the broadcast) must reach
+        # the wire before drop_session clears the peer's queues — the
+        # old synchronous write loop guaranteed exactly this.
+        server.flush_peer(session.peer)
+        return False
+    else:
+        session.send(
+            {"t": "error", "reason": f"unknown op {kind!r}", "canRetry": False}
+        )
+    return True
+
+
+class _FirehoseWatch:
+    """One thread for every quiet firehose socket of a front.
+
+    A firehose consumer (``{"t": "consume"}``) says nothing after its
+    handshake: the connection is write-only from the front's side, fed by
+    the fan-out writer tier.  A handler thread would sit in a selector for
+    the connection's whole life — one thread per DOCUMENT of every device
+    fleet (10,000 for the config-3 fleet), and a sandboxed host kills a
+    process near 4,096 threads.  So the handler parks the socket here and
+    ends; this thread only waits for the peer to go away (EOF or a torn
+    socket: the session is dropped and the socket closed) or, should a
+    consumer ever speak again, hands the connection to a fresh serving
+    thread with the bytes it read."""
+
+    def __init__(self, server: "NetworkServer") -> None:
+        self._server = server
+        self._sel = selectors.DefaultSelector()
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel.register(self._wake_r, selectors.EVENT_READ, None)
+        self._lock = threading.Lock()
+        self._incoming: list = []    # (sock, session) awaiting registration
+        # Sockets this watch has taken over from socketserver, which must
+        # then NOT close them when their handler thread returns.
+        self._owned: set = set()
+        self._stopped = False
+        self._thread = threading.Thread(
+            target=self._run, name="firehose-watch", daemon=True
+        )
+        self._thread.start()
+
+    def park(self, sock, session: _ClientSession) -> None:
+        with self._lock:
+            self._owned.add(sock)
+            self._incoming.append((sock, session))
+        with contextlib.suppress(BlockingIOError, OSError):
+            self._wake_w.send(b"x")
+
+    def owns(self, sock) -> bool:
+        with self._lock:
+            return sock in self._owned
+
+    def release(self, sock) -> None:
+        """The connection ended on a serving thread: close the socket if it
+        was ever taken over (socketserver closes the ones it still owns)."""
+        with self._lock:
+            owned = sock in self._owned
+            self._owned.discard(sock)
+        if owned:
+            with contextlib.suppress(OSError):
+                sock.close()
+
+    def stop(self) -> None:
+        with self._lock:
+            self._stopped = True
+        with contextlib.suppress(OSError):
+            self._wake_w.send(b"x")
+        self._thread.join(timeout=5)
+        for s in (self._wake_r, self._wake_w):
+            with contextlib.suppress(OSError):
+                s.close()
+        with contextlib.suppress(OSError, RuntimeError):
+            self._sel.close()
+
+    def _run(self) -> None:
+        while True:
+            ready = self._sel.select(timeout=1.0)
+            with self._lock:
+                if self._stopped:
+                    return
+                incoming, self._incoming = self._incoming, []
+            for sock, session in incoming:
+                self._sel.register(sock, selectors.EVENT_READ, session)
+            for key, _events in ready:
+                if key.data is None:  # wake channel
+                    with contextlib.suppress(BlockingIOError, OSError):
+                        while self._wake_r.recv(4096):
+                            pass
+                    continue
+                sock, session = key.fileobj, key.data
                 try:
                     data = sock.recv(1 << 16)
                 except (BlockingIOError, InterruptedError):
                     continue
-                if not data:
-                    return  # orderly EOF
-                buf += data
-                while True:
-                    cut = buf.find(b"\n")
-                    if cut < 0:
-                        break
-                    line, buf = buf[:cut].strip(), buf[cut + 1:]
-                    if line and not self._dispatch(server, session, line):
-                        return
-        finally:
-            sel.close()
-
-    def _dispatch(self, server: "NetworkServer", session, line: bytes) -> bool:
-        """One protocol request; False ends the session (disconnect)."""
-        try:
-            req = json.loads(line)
-        except json.JSONDecodeError:
-            session.send({"t": "error", "reason": "bad json", "canRetry": False})
-            return True
-        kind = req.get("t")
-        if kind == "connect":
-            server.handle_connect(session, req)
-        elif kind == "consume":
-            server.handle_consume(session, req)
-        elif kind == "submit":
-            server.handle_submit(session, req)
-        elif kind == "signal":
-            server.handle_signal(session, req)
-        elif kind == "interests":
-            server.handle_interests(session, req)
-        elif kind == "sync":
-            # Echo AFTER everything already broadcast on this socket: the
-            # echo rides the peer queue behind every frame already
-            # published for the session's document (direct-watermark
-            # ordering) — the client's deterministic quiescence marker.
-            session.send({"t": "sync", "n": req.get("n", 0)})
-        elif kind == "disconnect":
-            # Graceful goodbye: everything already queued for this socket
-            # (a pipelined sync echo, the tail of the broadcast) must reach
-            # the wire before drop_session clears the peer's queues — the
-            # old synchronous write loop guaranteed exactly this.
-            server.flush_peer(session.peer)
-            return False
-        else:
-            session.send(
-                {"t": "error", "reason": f"unknown op {kind!r}", "canRetry": False}
-            )
-        return True
+                except OSError:
+                    data = None  # torn
+                self._sel.unregister(sock)
+                if data:
+                    # The consumer spoke: serve it from a thread again.
+                    threading.Thread(
+                        target=_serve, daemon=True,
+                        args=(self._server, session, sock, data),
+                    ).start()
+                    continue
+                if data is None:
+                    with self._server.lock:
+                        self._server.torn_sockets += 1
+                self._server.drop_session(session)
+                self.release(sock)
 
 
 class NetworkServer:
@@ -194,9 +323,16 @@ class NetworkServer:
         # counter, surfaced through service_stats.
         self.torn_sockets = 0
 
+        self.firehose_watch = _FirehoseWatch(self)
+
         class _Srv(socketserver.ThreadingTCPServer):
             allow_reuse_address = True
             daemon_threads = True
+
+            def shutdown_request(self, request) -> None:
+                # A parked firehose socket outlives its handler thread.
+                if not self.owner.firehose_watch.owns(request):
+                    super().shutdown_request(request)
 
         self._tcp = _Srv(("127.0.0.1", port), _NexusHandler)
         self._tcp.owner = self  # type: ignore[attr-defined]
@@ -210,6 +346,7 @@ class NetworkServer:
     def stop(self) -> None:
         self._tcp.shutdown()
         self._tcp.server_close()
+        self.firehose_watch.stop()
         self.fanout_writer.stop()
 
     # --------------------------------------------------------- fanout wiring
@@ -392,6 +529,7 @@ class NetworkServer:
             consumer_id = f"__consumer__{id(session)}"
             session.doc_id = doc_id
             session.client_id = consumer_id
+            session.firehose = True
             log = doc.sequencer.log
             delivered = len(log) - doc.pending_count
             delivered_seq = log[delivered - 1].seq if delivered else 0

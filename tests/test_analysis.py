@@ -1412,7 +1412,7 @@ MESH_HEADER = (
     "import jax\n"
     "import numpy as np\n"
     "from jax.sharding import Mesh, PartitionSpec as P\n"
-    "from jax.experimental.shard_map import shard_map\n"
+    "from jax import shard_map\n"
     "mesh = Mesh(np.array([]), ('docs',))\n"
 )
 
@@ -1530,7 +1530,7 @@ def test_mesh_donate_replicated_out_literal(tmp_path):
 
 DECLARED_PROG = (
     "import jax\n"
-    "from jax.experimental.shard_map import shard_map\n"
+    "from jax import shard_map\n"
     "def seg_prog(fn, mesh, specs, donate=False):\n"
     "    m = shard_map(fn, mesh=mesh, in_specs=(specs,), out_specs=specs)\n"
     "    return jax.jit(m, donate_argnums=(0,) if donate else ())\n"

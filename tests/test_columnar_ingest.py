@@ -493,6 +493,54 @@ def test_msn_compaction_rides_summary_ack():
         srv.stop()
 
 
+def test_compact_applies_staged_rows_before_shrinking_the_window():
+    """A consumer that fell behind reads ops AND the summary ack that
+    follows them in one pass: the ack's MSN is newer than the ref-seq of
+    the rows still staged.  ``compact()`` must apply those rows before
+    zamboni runs — evicting first drops the tombstone a concurrent insert
+    still resolves its position against, and the insert lands in the wrong
+    place with no error bit (found by the on-chip smoke, where one fleet
+    step takes seconds and every ack rides in behind a backlog)."""
+    from fluidframework_tpu.dds.shared_string import SharedString
+    from fluidframework_tpu.loadgen.coordinator import oracle_text
+    from fluidframework_tpu.server.local_service import LocalService
+
+    doc = LocalService().document("d")
+    a, b = SharedString(client_id="a"), SharedString(client_id="b")
+    for c in (a, b):
+        doc.connect(c.client_id, c.process)
+    doc.process_all()
+
+    def flush(*clients):
+        for c in clients:
+            for m in c.take_outbox():
+                doc.submit(m)
+        doc.process_all()
+
+    a.insert_text(0, "abcdefgh")
+    flush(a)
+    b.remove_range(2, 4)     # sequenced first ...
+    a.insert_text(5, "Z")    # ... concurrent: a has not seen the remove
+    flush(b, a)
+    for _ in range(2):       # both ref-seqs (so the MSN) pass the remove
+        a.insert_text(0, "1")
+        b.insert_text(0, "2")
+        flush(a, b)
+    log = doc.sequencer.log
+    assert doc.sequencer.min_seq > 4 and a.text == b.text == oracle_text(log)
+    cut = 1 + next(
+        i for i, m in enumerate(log)
+        if m.type == MessageType.OP and m.contents["type"] == 1
+    )
+    eng = _mk(1)
+    eng.ingest_lines(0, b"".join(m.wire_line() for m in log[:cut]))
+    eng.step()               # the remove is applied; its tombstone is live
+    eng.ingest_lines(0, b"".join(m.wire_line() for m in log[cut:]))
+    eng.compact()            # what FleetConsumer.pump does on an ack
+    eng.step()
+    assert eng.text(0) == a.text
+
+
 def test_tree_ingest_batch_wrapper_matches():
     n_docs = 3
     svc, expected = drive_tree_docs(n_docs, seed=1, steps=15)

@@ -72,8 +72,7 @@ def test_compose_topology_smoke():
                  # One op row per doc: exit only after EVERY doc's firehose
                  # catch-up landed (exiting at 1 races the other doc's
                  # in-flight catch-up bytes).
-                 "--exit-after-rows", str(len(by_shard[si])),
-                 "--platform", "cpu"],
+                 "--exit-after-rows", str(len(by_shard[si]))],
                 stdout=subprocess.PIPE, text=True, cwd=REPO, env=ENV,
             ))
         for si, proc in enumerate(fleets):

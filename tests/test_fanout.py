@@ -822,3 +822,67 @@ def test_delta_connection_surfaces_boot_marker():
             conn.disconnect()
     finally:
         plane.stop()
+
+
+def test_quiet_firehose_consumers_hold_no_thread():
+    """A firehose consumer says nothing after its handshake, so the front
+    parks its socket with one watch thread instead of keeping a handler
+    thread per DOCUMENT of a device fleet (a sandboxed host kills a
+    process near 4,096 threads; config 3 is 10,000 documents).  The
+    stream still flows, a consumer that speaks again is served again, and
+    a consumer that goes away is dropped."""
+    import threading
+    import time
+
+    from fluidframework_tpu.dds.shared_string import SharedString
+    from fluidframework_tpu.server.netserver import NetworkServer
+
+    srv = NetworkServer().start()
+    socks = []
+    try:
+        before = threading.active_count()
+        files = []
+        for i in range(48):
+            c = socket.create_connection(("127.0.0.1", srv.port))
+            socks.append(c)
+            c.sendall(json.dumps({"t": "consume", "doc": f"d{i}"}).encode()
+                      + b"\n")
+            f = c.makefile("rb")
+            assert b"consuming" in f.readline()
+            files.append(f)
+
+        def settle(cond):
+            deadline = time.monotonic() + 10
+            while not cond() and time.monotonic() < deadline:
+                time.sleep(0.01)
+            return cond()
+
+        assert settle(lambda: threading.active_count() <= before + 1), (
+            f"{threading.active_count() - before} threads for 48 consumers"
+        )
+        assert srv.fanout.stats()["peers"] == 48
+
+        with srv.lock:  # the stream still flows to a parked socket
+            doc = srv.service.document("d0")
+            w = SharedString(client_id="w")
+            doc.connect(w.client_id, w.process)
+            doc.process_all()
+        w.insert_text(0, "parked")
+        with srv.lock:
+            for m in w.take_outbox():
+                doc.submit(m)
+            doc.process_all()
+        while b'"seg":"parked"' not in files[0].readline():
+            pass
+
+        socks[1].sendall(b'{"t": "sync", "n": 7}\n')  # it speaks again
+        assert json.loads(files[1].readline()) == {"t": "sync", "n": 7}
+        assert settle(lambda: threading.active_count() <= before + 1)
+
+        socks[2].close()                               # it goes away
+        files[2].close()
+        assert settle(lambda: srv.fanout.stats()["peers"] == 47)
+    finally:
+        for s in socks:
+            s.close()
+        srv.stop()

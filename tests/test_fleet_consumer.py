@@ -142,18 +142,42 @@ def test_fleet_main_entry_cross_process(server):
 
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
-        [sys.executable, "-c",
-         "import jax; jax.config.update('jax_platforms', 'cpu');"
-         "from fluidframework_tpu.server.fleet_main import main;"
-         f"raise SystemExit(main(['--port', '{server.port}',"
-         f" '--docs', 'dm', '--exit-after-rows', '{rows}']))"],
-        capture_output=True, text=True, timeout=180, env=dict(os.environ),
-        cwd=repo_root,
+        [sys.executable, "-m", "fluidframework_tpu.server.fleet_main",
+         "--port", str(server.port), "--docs", "dm",
+         "--exit-after-rows", str(rows)],
+        capture_output=True, text=True, timeout=180,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=repo_root,
     )
     assert out.returncode == 0, out.stderr[-500:]
-    status = json.loads(out.stdout.strip().splitlines()[-1])
+    lines = [json.loads(ln) for ln in out.stdout.strip().splitlines()]
+    # The readiness line says where the engine state lives, as JAX
+    # reported it in the fleet process.
+    ready = next(ln for ln in lines if ln.get("ready"))
+    assert ready["platform"] == "cpu" and ready["device_count"] >= 1
+    assert sum(ready["resident_bytes_per_device"].values()) > 0
+    status = lines[-1]
     assert status["done"] and status["errors"] == 0
     assert status["texts"]["dm"] == "compose"
+    assert status["health"]["ingest_plane"] == "native"
+
+
+def test_fleet_main_refuses_mesh_wider_than_devices(server):
+    """``--mesh N`` on fewer than N devices is an error, never a silently
+    narrower fleet (one CPU device here; XLA_FLAGS stripped)."""
+    import os
+    import subprocess
+    import sys
+
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-m", "fluidframework_tpu.server.fleet_main",
+         "--port", str(server.port), "--docs", "dm", "--mesh", "4"],
+        capture_output=True, text=True, timeout=180, env=env, cwd=repo_root,
+    )
+    assert out.returncode != 0
+    assert "--mesh 4 needs 4 devices; JAX sees 1 (cpu)" in out.stderr
 
 
 def test_fleet_consumer_boots_from_scribe_summary(server, tmp_path):

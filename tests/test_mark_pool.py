@@ -337,9 +337,9 @@ def test_engine_pooled_vs_oracle_device_identity(seed):
 
 
 def _native_available():
-    from fluidframework_tpu.native.ingest_native import tree_decode_available
+    from fluidframework_tpu.native.ingest_native import available
 
-    return tree_decode_available()
+    return available()
 
 
 @pytest.mark.parametrize("seed", [0, 5])

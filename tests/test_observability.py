@@ -193,13 +193,14 @@ class TestRecompileWatchdog:
         import jax
 
         fn = jax.jit(lambda x: x + 1)
-        if not hasattr(fn, "_cache_size"):
-            pytest.skip("jax has no _cache_size probe")
         rec = install(FlightRecorder())
         wd = RecompileWatchdog()
         wd.register("probe", fn)
         wd.register("probe", fn)  # idempotent
-        wd.register("not_jitted", lambda x: x)  # ignored
+        with pytest.raises(AttributeError):
+            # An untrackable program must not register silently and then
+            # report zero recompiles forever.
+            wd.register("not_jitted", lambda x: x)
         assert wd.poll() == 0
         fn(np.zeros((2,), np.float32))
         first = wd.poll()

@@ -381,11 +381,12 @@ def test_tree_engine_rebalance_makes_real_move():
 
 
 def test_mesh_seg_program_defaults_donation_off():
-    """Regression pin for the jax 0.4.37 persistent-cache aliasing bug: a
-    DONATED ``mesh_seg_program`` executable reloaded from the persistent
-    XLA compile cache returns permuted/garbage outputs whenever the
-    obliterate branch runs (two-process repro — the byte-identity fuzz
-    caught it live; see the repro note in ``parallel/mesh.py``).
+    """Regression pin for the persistent-cache aliasing bug seen on jax
+    0.4.37: a DONATED ``mesh_seg_program`` executable reloaded from the
+    persistent XLA compile cache returned permuted/garbage outputs
+    whenever the obliterate branch ran (two-process repro — the
+    byte-identity fuzz caught it live; see the note in
+    ``parallel/mesh.py``, which also records the jax 0.9.0 retry).
 
     Donation must stay OFF by default until the upstream bug is fixed.
     A well-meaning "re-enable donation" PR now trips THIS named test and
@@ -397,7 +398,7 @@ def test_mesh_seg_program_defaults_donation_off():
     sig = inspect.signature(pm.mesh_seg_program.__wrapped__)
     assert sig.parameters["donate"].default is False, (
         "mesh_seg_program must default donate=False: donated "
-        "replicated-output executables corrupt on persistent-cache "
-        "reload (jax 0.4.37). Re-enable only with the cache off or "
-        "after the upstream aliasing fix — see parallel/mesh.py."
+        "replicated-output executables corrupted on persistent-cache "
+        "reload (seen on jax 0.4.37). Turning it on is its own change "
+        "— see parallel/mesh.py."
     )
