@@ -701,7 +701,6 @@ def _engine_round_driver(n_docs: int, megastep_k: int, seed: int = 0):
     H = type(eng.op_latency)
     eng.op_latency = H()
     eng._shard_latency = [H() for _ in eng._shard_latency]
-    eng._doc_latency.clear()
 
     def run(n_rounds: int) -> float:
         t0 = time.perf_counter()

@@ -219,7 +219,11 @@ _fleet_compact = functools.partial(jax.jit, donate_argnums=(0,))(
 
 _lane_apply_jit = jax.jit(mk.apply_ops)
 _lane_compact_jit = jax.jit(lambda s, m: mk.compact(mk.set_min_seq(s, m)))
-_gather_cohort_jit = jax.jit(lambda st, idx: jax.tree.map(lambda x: x[idx], st))
+
+
+@jax.jit
+def _gather_cohort_jit(st, idx):
+    return jax.tree.map(lambda x: x[idx], st)
 
 
 @jax.jit
@@ -407,7 +411,6 @@ class DocBatchEngine:
         # error readback proves the dispatches that drained them retired).
         self.latency_sample_every = max(1, latency_sample_every)
         self.op_latency = Histogram()
-        self._doc_latency: dict[int, Histogram] = {}
         self._lat_tick = 0
         self._lat_pending: list[tuple[float, int]] = []
 
@@ -1120,7 +1123,7 @@ class DocBatchEngine:
     def _lat_flush(self) -> None:
         """Resolve pending latency samples at the applied-on-device
         boundary (end of step(), after the error-latch readback proved the
-        dispatches retired) into the per-doc and per-shard histograms."""
+        dispatches retired) into the fleet and per-shard histograms."""
         if not self._lat_pending:
             return
         now = time.time()
@@ -1129,10 +1132,6 @@ class DocBatchEngine:
             self.op_latency.record(lat)
             if 0 <= d < self.n_docs:
                 self._shard_latency[self.shard_of(d)].record(lat)
-                h = self._doc_latency.get(d)
-                if h is None:
-                    h = self._doc_latency[d] = Histogram()
-                h.record(lat)
         self._lat_pending.clear()
 
     def latency_histograms(self) -> dict[str, Histogram]:
@@ -1148,9 +1147,6 @@ class DocBatchEngine:
             for s, h in enumerate(self._shard_latency):
                 out[f"op_latency_shard{s}"] = h
         return out
-
-    def doc_latency(self, doc_idx: int) -> Histogram | None:
-        return self._doc_latency.get(doc_idx)
 
     def flush_telemetry(self) -> None:
         """Drain residual sampled-telemetry buckets (status snapshot /
@@ -1288,26 +1284,28 @@ class DocBatchEngine:
         the staging ring (slice k+1 packs while the upload/dispatch of the
         previous megastep is still in flight) and apply them as one
         donated program; returns the slices applied."""
-        K = self._select_k(busy, cohort=False)
-        stage = self._staging()
-        ops, payloads = stage.acquire(K, self.capacity)
-        # Pack by doc PLACEMENT: doc d's ops land in row slot(d), so each
-        # shard's slice of the staging buffer holds exactly its own docs
-        # and the shard-layout upload splits per chip with no reshuffle.
-        rows = [int(s) for s in self._slot[busy]]
-        for k in range(K):
-            stage.mark(
-                k,
-                self._drain_into(
-                    busy, ops[k], payloads[k], rows=rows, slots=True
-                ),
-            )
-            if k + 1 < K:
-                pairs = [
-                    (d, r) for d, r in zip(busy, rows) if d in self._busy
-                ]
-                busy = [d for d, _ in pairs]
-                rows = [r for _, r in pairs]
+        with span("pack", kind="full", docs=len(busy)):
+            K = self._select_k(busy, cohort=False)
+            stage = self._staging()
+            ops, payloads = stage.acquire(K, self.capacity)
+            # Pack by doc PLACEMENT: doc d's ops land in row slot(d), so
+            # each shard's slice of the staging buffer holds exactly its
+            # own docs and the shard-layout upload splits per chip with no
+            # reshuffle.
+            rows = [int(s) for s in self._slot[busy]]
+            for k in range(K):
+                stage.mark(
+                    k,
+                    self._drain_into(
+                        busy, ops[k], payloads[k], rows=rows, slots=True
+                    ),
+                )
+                if k + 1 < K:
+                    pairs = [
+                        (d, r) for d, r in zip(busy, rows) if d in self._busy
+                    ]
+                    busy = [d for d, _ in pairs]
+                    rows = [r for _, r in pairs]
         if self.mesh is None and K == 1:
             dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
             with span("dispatch", kind="full", k=K):
@@ -1355,7 +1353,8 @@ class DocBatchEngine:
         # Work staged by a racing ingest meanwhile is skipped by the
         # sweep's staged-but-unapplied guard, exactly as a background
         # sweep would skip it.
-        self.maybe_checkpoint()
+        with span("housekeeping", kind="checkpoint"):
+            self.maybe_checkpoint()
         return steps
 
     def _has_staged_rows(self) -> bool:
@@ -1392,10 +1391,11 @@ class DocBatchEngine:
         # Sync boundary housekeeping (host-side, O(programs + samples)):
         # resolve e2e latency samples, poll for mid-serve recompiles, and
         # feed the sampled step timing when a telemetry sink is attached.
-        self._lat_flush()
-        self.recompile_watchdog.poll()
-        if self.sampled is not None:
-            self.sampled.record(time.perf_counter() - t0, "step")
+        with span("housekeeping"):
+            self._lat_flush()
+            self.recompile_watchdog.poll()
+            if self.sampled is not None:
+                self.sampled.record(time.perf_counter() - t0, "step")
         return steps
 
     def _maybe_readmit(self) -> None:
@@ -1423,26 +1423,29 @@ class DocBatchEngine:
         cohort's state rows once, apply up to K fused [Kc, B] slices, and
         masked-scatter the rows back — K > 1 amortizes the gather/scatter
         pair as well as the dispatch.  Returns the slices applied."""
-        K = self._select_k(busy, cohort=True)
-        Kc = max(1, 1 << (len(busy) - 1).bit_length())  # pow2 ladder
-        idx = np.full((Kc,), busy[-1], np.int32)  # gather pad: harmless dup
-        idx[: len(busy)] = busy
-        valid = np.zeros((Kc,), bool)
-        valid[: len(busy)] = True
-        stage = self._staging()
-        ops, payloads = stage.acquire(K, Kc)
-        row_of = {d: j for j, d in enumerate(busy)}
-        cur = busy
-        for k in range(K):
-            stage.mark(
-                k,
-                self._drain_into(
-                    cur, ops[k], payloads[k], rows=[row_of[d] for d in cur]
-                ),
-            )
-            if k + 1 < K:
-                cur = [d for d in cur if d in self._busy]
-        sub = self._gather_cohort(self.state, jnp.asarray(idx))
+        with span("pack", kind="cohort", docs=len(busy)):
+            K = self._select_k(busy, cohort=True)
+            Kc = max(1, 1 << (len(busy) - 1).bit_length())  # pow2 ladder
+            idx = np.full((Kc,), busy[-1], np.int32)  # gather pad: dup
+            idx[: len(busy)] = busy
+            valid = np.zeros((Kc,), bool)
+            valid[: len(busy)] = True
+            stage = self._staging()
+            ops, payloads = stage.acquire(K, Kc)
+            row_of = {d: j for j, d in enumerate(busy)}
+            cur = busy
+            for k in range(K):
+                stage.mark(
+                    k,
+                    self._drain_into(
+                        cur, ops[k], payloads[k],
+                        rows=[row_of[d] for d in cur],
+                    ),
+                )
+                if k + 1 < K:
+                    cur = [d for d in cur if d in self._busy]
+        with span("gather", lanes=Kc):
+            sub = self._gather_cohort(self.state, jnp.asarray(idx))
         if K == 1:
             dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
             with span("dispatch", kind="cohort", k=K, lanes=Kc):
@@ -1451,9 +1454,10 @@ class DocBatchEngine:
             dev_ops, dev_payloads = stage.upload(ops, payloads)
             with span("dispatch", kind="cohort", k=K, lanes=Kc):
                 sub = self._megastep(sub, dev_ops, dev_payloads)
-        self.state = self._scatter_cohort(
-            self.state, sub, jnp.asarray(idx), jnp.asarray(valid)
-        )
+        with span("scatter", lanes=Kc):
+            self.state = self._scatter_cohort(
+                self.state, sub, jnp.asarray(idx), jnp.asarray(valid)
+            )
         self.cohort_steps += K
         self.cohort_lanes += K * Kc
         self.counters.bump("megastep_dispatches")
@@ -1739,47 +1743,52 @@ class DocBatchEngine:
         if not batch_clean:
             with span("readback", kind="error_vector"):
                 err = np.asarray(self.state.error)
-            for d in range(self.n_docs):
-                slot = int(self._slot[d])
-                if (
-                    d not in self.overflow
-                    and d not in self.oracles
-                    and d not in self.quarantine
-                    and err[slot]
-                ):
-                    bits = int(err[slot])
+        # The host part: walk the vector, then the lanes' own latches.
+        with span("recover"):
+            if not batch_clean:
+                for d in range(self.n_docs):
+                    slot = int(self._slot[d])
+                    if (
+                        d not in self.overflow
+                        and d not in self.oracles
+                        and d not in self.quarantine
+                        and err[slot]
+                    ):
+                        bits = int(err[slot])
+                        if mk.is_capacity_error(bits):
+                            self._recover_doc(d, bits, growths=0)
+                        else:  # poison: ERR_POS_RANGE with no capacity bit
+                            self._quarantine_doc(d, f"error bits {bits:#x}")
+                        # Retire the batch slot: clear the latched bits so the
+                        # slot never re-triggers (its queue is empty and future
+                        # ops route to the lane).
+                        self.state = self.state._replace(
+                            error=self.state.error.at[slot].set(0)
+                        )
+                        recovered.append(d)
+            for d, lane in list(self.overflow.items()):
+                bits = int(lane.state.error)
+                if bits:
+                    if mk.is_capacity_error(bits):
+                        self._recover_doc(d, bits, growths=lane.growths)
+                    else:
+                        self._quarantine_doc(d, f"error bits {bits:#x}")
+                    recovered.append(d)
+            for d, lane in list(self.seg_lanes.items()):
+                bits = int(np.asarray(lane.state.error))
+                if bits:
+                    # A latched segment lane leaves the seg path entirely: the
+                    # retained log replays into a standard overflow lane (grow)
+                    # or quarantine — staged lane rows ride the log, so nothing
+                    # is lost.  Re-promotion is the supervisor's call.
+                    self.seg_lanes.pop(d)
                     if mk.is_capacity_error(bits):
                         self._recover_doc(d, bits, growths=0)
-                    else:  # poison: ERR_POS_RANGE with no capacity bit
-                        self._quarantine_doc(d, f"error bits {bits:#x}")
-                    # Retire the batch slot: clear the latched bits so the
-                    # slot never re-triggers (its queue is empty and future
-                    # ops route to the lane).
-                    self.state = self.state._replace(
-                        error=self.state.error.at[slot].set(0)
-                    )
+                    else:
+                        self._quarantine_doc(
+                            d, f"error bits {bits:#x} (seg lane)"
+                        )
                     recovered.append(d)
-        for d, lane in list(self.overflow.items()):
-            bits = int(lane.state.error)
-            if bits:
-                if mk.is_capacity_error(bits):
-                    self._recover_doc(d, bits, growths=lane.growths)
-                else:
-                    self._quarantine_doc(d, f"error bits {bits:#x}")
-                recovered.append(d)
-        for d, lane in list(self.seg_lanes.items()):
-            bits = int(np.asarray(lane.state.error))
-            if bits:
-                # A latched segment lane leaves the seg path entirely: the
-                # retained log replays into a standard overflow lane (grow)
-                # or quarantine — staged lane rows ride the log, so nothing
-                # is lost.  Re-promotion is the supervisor's call.
-                self.seg_lanes.pop(d)
-                if mk.is_capacity_error(bits):
-                    self._recover_doc(d, bits, growths=0)
-                else:
-                    self._quarantine_doc(d, f"error bits {bits:#x} (seg lane)")
-                recovered.append(d)
         if recovered:
             # One structured health event per recovery action (no-op
             # without a telemetry logger).

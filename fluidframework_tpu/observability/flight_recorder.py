@@ -12,7 +12,10 @@ counts what fell off) and export to Chrome trace-event JSON ("X" complete
 events + "i" instants), which Perfetto and chrome://tracing load
 directly.  Timestamps are ``time.perf_counter_ns()`` (monotonic, one
 clock for every thread of the process), so span nesting is exact within a
-thread and cross-thread ordering is meaningful within the process.
+thread and cross-thread ordering is meaningful within the process.  While a
+recorder is installed every span is also a ``jax.profiler.TraceAnnotation``
+of the same name and labels: a profiler session, whoever started it, then
+holds the host spans on its own clock beside the device's events.
 
 A ``RecompileWatchdog`` registers named jitted programs and polls their
 executable-cache sizes (``_cache_size``): growth after the first dispatch
@@ -38,10 +41,20 @@ class TraceEvent(NamedTuple):
     args: dict[str, Any] | None
 
 
-class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+# ``jax.profiler.TraceAnnotation`` once a recorder is installed (``install``
+# imports it, so this module still loads in a process without JAX): every
+# span is then mirrored into whatever profiler session is running, and lands
+# on the thread's line of the xplane's host plane, on the profiler's clock,
+# beside the device planes.  With no session running an annotation costs
+# one flag read.
+_ANNOTATION = None
 
-    __slots__ = ("_rec", "_name", "_args", "_t0")
+
+class _Span:
+    """Context manager recording one complete ("X") event on exit, and the
+    same span as a profiler annotation."""
+
+    __slots__ = ("_rec", "_name", "_args", "_t0", "_note")
 
     def __init__(self, rec: "FlightRecorder", name: str, args) -> None:
         self._rec = rec
@@ -49,15 +62,29 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        note = self._note = (
+            _ANNOTATION(self._name, **(self._args or {}))
+            if _ANNOTATION is not None else None
+        )
+        if note is not None:
+            note.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
-    def __exit__(self, *_exc) -> None:
+    def set(self, **labels: Any) -> None:
+        """Add labels known only once the work is done (bytes a pump read)."""
+        self._args = {**(self._args or {}), **labels}
+        if self._note is not None:
+            self._note.set_metadata(**labels)
+
+    def __exit__(self, *exc) -> None:
         t0 = self._t0
         self._rec._push(TraceEvent(
             self._name, "X", t0, time.perf_counter_ns() - t0,
             threading.get_ident(), self._args,
         ))
+        if self._note is not None:
+            self._note.__exit__(*exc)
 
 
 class _NullSpan:
@@ -67,6 +94,9 @@ class _NullSpan:
 
     def __enter__(self) -> "_NullSpan":
         return self
+
+    def set(self, **_labels: Any) -> None:
+        pass
 
     def __exit__(self, *_exc) -> None:
         pass
@@ -173,7 +203,11 @@ def install(rec: FlightRecorder | None = None) -> FlightRecorder:
     """Install (and return) the process-global recorder.  Instrumented
     code starts recording immediately; pass None to install a fresh
     default-capacity ring."""
-    global _RECORDER
+    global _RECORDER, _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
     _RECORDER = rec if rec is not None else FlightRecorder()
     return _RECORDER
 

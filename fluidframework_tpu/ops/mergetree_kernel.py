@@ -354,6 +354,7 @@ class _NewSeg(NamedTuple):
     prop_vals: tuple
 
 
+@jax.named_scope("open_slot")
 def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
     """Conditionally (``do``) shift all per-segment arrays right at ``k`` and
     write the new segment's values there.  Capacity overflow sets error."""
@@ -380,6 +381,7 @@ def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
     )
 
 
+@jax.named_scope("ensure_boundary")
 def _ensure_boundary(s: DocState, pos, ref_seq, client) -> DocState:
     """Split the segment containing ``pos`` strictly inside it, if any.
 
@@ -588,6 +590,7 @@ def _do_insert(s: DocState, op, payload, ob_flag) -> DocState:
     )
 
 
+@jax.named_scope("mark_range")
 def _mark_range(s: DocState, op) -> tuple[DocState, jnp.ndarray]:
     """Split at both boundaries; return mask of visible segments inside."""
     pos1, pos2, client, ref_seq = op[4], op[5], op[2], op[3]
@@ -772,6 +775,25 @@ def _do_ack(s: DocState, op, payload) -> DocState:
     )
 
 
+# One ``jax.named_scope`` per op kind, in ``OpKind`` order after NOOP.  The
+# scope is the first component of every instruction's ``op_name`` that a
+# branch lowers to, and the device trace groups a step's time by it
+# (benchmark/host_plane.py; the ``kernel_*_share`` metrics).  What a step runs
+# outside any branch (the switch's merge select, the scan's carry) stays
+# unscoped.  The persistent compile cache has to key on this metadata
+# (utils/compile_cache.py), or a cached executable keeps its old names.
+BRANCH_SCOPES = ("insert", "remove", "annotate", "ack", "obliterate")
+
+
+def _scoped_branches(*fns) -> list:
+    """``lax.switch`` branches by ``OpKind``: NOOP, then ``fns`` each under
+    its scope of ``BRANCH_SCOPES``."""
+    return [lambda s, op, p: s] + [
+        jax.named_scope(name)(fn)
+        for name, fn in zip(BRANCH_SCOPES, fns, strict=True)
+    ]
+
+
 def apply_op(
     s: DocState, op: jnp.ndarray, payload: jnp.ndarray, ob_flag=None
 ) -> DocState:
@@ -800,14 +822,13 @@ def apply_op(
         ob_branch = lambda s, op, p: jax.lax.cond(  # noqa: E731
             ob_flag, lambda st: _do_obliterate(st, op, p), lambda st: st, s
         )
-    branches = [
-        lambda s, op, p: s,  # NOOP
+    branches = _scoped_branches(
         lambda s, op, p: _do_insert(s, op, p, ob_flag),
         _do_remove,
         _do_annotate,
         _do_ack,
         ob_branch,
-    ]
+    )
     s = jax.lax.switch(kind, branches, s, op, payload)
     return s
 
@@ -1245,8 +1266,7 @@ def apply_op_seg(
     the single-lane branch verbatim (purely element-wise over local arrays
     plus replicated ob-table rewrites)."""
     kind = op[0]
-    branches = [
-        lambda s, op, p: s,  # NOOP
+    branches = _scoped_branches(
         lambda s, op, p: _do_insert_seg(s, op, p, ob_flag, axis),
         lambda s, op, p: _do_remove_seg(s, op, p, axis),
         lambda s, op, p: _do_annotate_seg(s, op, p, axis),
@@ -1254,7 +1274,7 @@ def apply_op_seg(
         (lambda s, op, p: _do_obliterate_seg(s, op, p, axis))
         if ob_flag
         else (lambda s, op, p: s),
-    ]
+    )
     return jax.lax.switch(kind, branches, s, op, payload)
 
 
@@ -1529,6 +1549,7 @@ def _gather_keep(s: DocState, keep: jnp.ndarray) -> DocState:
     )
 
 
+@jax.named_scope("compact")
 def compact(s: DocState, ob_flag=None) -> DocState:
     """Evict segments whose winning remove is acked at or below min_seq.
 

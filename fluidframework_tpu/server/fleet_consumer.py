@@ -35,6 +35,7 @@ import socket
 
 from ..fanout.plane import RESYNC_BOOT_MARKER
 from ..models.doc_batch_engine import DocBatchEngine
+from ..observability.flight_recorder import span
 
 _BOOT_MARKER = RESYNC_BOOT_MARKER.rstrip(b"\n")
 
@@ -99,6 +100,8 @@ class FleetConsumer:
         self.paused_socks: set[int] = set()
         self.pump_pauses = 0
         self.pump_resumes = 0
+        # Sockets the last pump's select found ready (0: it read nothing).
+        self.last_ready = 0
         self._sel = selectors.DefaultSelector()  # epoll: no FD_SETSIZE cap
         try:
             for doc_id in doc_ids:
@@ -163,22 +166,46 @@ class FleetConsumer:
         raise err if err is not None else OSError(f"no addresses for {host}")
 
     # ------------------------------------------------------------ data plane
-    def pump(self, wait_s: float = 0.02) -> int:
+    def pump(self, wait_s: float = 0.02, idle=None) -> int:
         """Drain every READY socket once; returns op rows staged this pass.
 
         One ``select`` readiness wait covers the whole socket set — an
         idle socket costs nothing (the old per-socket recv-timeout walk
         stalled the drain up to 50ms per quiet socket per pass, which was
-        most of the measured wire-ingest gap)."""
-        staged = 0
-        acked = False
+        most of the measured wire-ingest gap).
+
+        ``idle`` is the caller's open ``idle`` span (``fleet_main``), if it
+        has one: the wait in ``select`` then belongs to that span, which
+        ends here the moment a socket is ready (``last_ready`` tells the
+        caller), and a pump that found nothing records no span at all, so
+        an idle fleet leaves the flight recorder's ring alone."""
+        self.last_ready = 0
         if len(self.dead_socks) == len(self._socks):
             return 0
-        # Resume first: queues drained by step() between pumps may have
-        # fallen below the low watermark — re-register those sockets so
-        # this very select sees their backlog.
+        if idle is None:
+            with span("pump") as sp:
+                # Resume first: queues drained by step() between pumps may
+                # have fallen below the low watermark — re-register those
+                # sockets so this very select sees their backlog.
+                self._apply_flow_control()
+                with span("pump.select"):
+                    ready = self._sel.select(wait_s)
+                return self._drain_ready(ready, sp)
         self._apply_flow_control()
         ready = self._sel.select(wait_s)
+        if not ready:
+            return 0
+        idle.__exit__(None, None, None)
+        with span("pump") as sp:
+            return self._drain_ready(ready, sp)
+
+    def _drain_ready(self, ready, sp) -> int:
+        """``pump``'s work once ``select`` has returned: read, peel and
+        ingest every ready socket; labels the pump's span ``sp``."""
+        staged = 0
+        acked = False
+        bytes_before = self.bytes_consumed
+        self.last_ready = len(ready)
         for key, _events in ready:
             idx, sock = key.data, key.fileobj
             if idx in self.dead_socks:
@@ -233,6 +260,8 @@ class FleetConsumer:
             # min_seq was refreshed by the ack message itself.
             self.engine.compact()
             self.engine.counters.bump("msn_compactions")
+        sp.set(ready=len(ready), bytes=self.bytes_consumed - bytes_before,
+               staged=staged)
         return staged
 
     def _handle_boot_marker(self, idx: int, feed: bytes) -> int:
@@ -337,7 +366,13 @@ class FleetConsumer:
         """Apply everything staged as one batched device step (the engine
         runs its own recovery, watchdog cadence, and checkpoint cadence
         inside ``step`` when configured)."""
-        return self.engine.step()
+        eng = self.engine
+        with span("step", docs=len(eng._busy)) as sp:
+            dispatches = eng.counters.get("megastep_dispatches")
+            slices = eng.step()
+            sp.set(slices=slices, dispatches=eng.counters.get(
+                "megastep_dispatches") - dispatches)
+        return slices
 
     def health(self) -> dict:
         """Engine health counters + this consumer's transport state."""

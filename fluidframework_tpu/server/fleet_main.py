@@ -27,8 +27,11 @@ from __future__ import annotations
 
 import argparse
 import json
+import signal
 import sys
 import time
+
+from ..observability.flight_recorder import span
 
 
 def status_snapshot(eng, doc_ids, rows=0, bytes_consumed=0, **extra) -> dict:
@@ -37,13 +40,15 @@ def status_snapshot(eng, doc_ids, rows=0, bytes_consumed=0, **extra) -> dict:
     including the megastep pipeline surface (``megastep_k``,
     ``steps_per_dispatch``, ``staging_overlap_packs``).  Module-level so
     tests and tools can assert on the exact shape ``main`` emits."""
-    errs = eng.errors()
+    with span("status.errors"):
+        errs = eng.errors()
     # Status is a drain point: flush residual sampled-telemetry buckets so
     # tail samples below sample_every reach the sink with the snapshot.
     flush = getattr(eng, "flush_telemetry", None)
     if flush is not None:
         flush()
-    health = eng.health()
+    with span("status.health"):
+        health = eng.health()
     out = {
         "rows": rows,
         "bytes": bytes_consumed,
@@ -421,23 +426,40 @@ def main(argv: list[str] | None = None) -> int:
             interval_s=args.ckpt_sweep_interval,
         ).start()
 
+    # The open ``idle`` span: consecutive idle iterations are ONE span, from
+    # the first sleep until a pump's select finds a socket ready (the pump
+    # ends it) or a status line is due, so an idle fleet does not fill the
+    # ring.  ``pump``, ``step``, ``status`` and ``idle`` never overlap, and
+    # together they cover the loop.
+    idle = None
+
+    def end_idle() -> None:
+        nonlocal idle
+        if idle is not None:
+            idle.__exit__(None, None, None)
+            idle = None
+
     def status(**extra) -> None:
-        extra.setdefault("compile", compile_stats.snapshot())
-        if ckpt_writer is not None:
-            extra.setdefault("ckptWriter", ckpt_writer.stats())
-        if heartbeat is not None:
-            extra.setdefault("lease", heartbeat.stats())
-        print(json.dumps(status_snapshot(
-            eng, doc_ids, rows=fc.rows_staged,
-            bytes_consumed=fc.bytes_consumed,
-            # Consumer-side flow control (the engine's overload gauges
-            # ride inside health): which partitions are paused right now
-            # and how often the gate cycled.
-            paused_docs=len(fc.paused_socks),
-            pump_pauses=fc.pump_pauses,
-            pump_resumes=fc.pump_resumes,
-            **extra,
-        )), flush=True)
+        end_idle()
+        with span("status", rows=fc.rows_staged):
+            extra.setdefault("compile", compile_stats.snapshot())
+            if ckpt_writer is not None:
+                extra.setdefault("ckptWriter", ckpt_writer.stats())
+            if heartbeat is not None:
+                extra.setdefault("lease", heartbeat.stats())
+            snap = status_snapshot(
+                eng, doc_ids, rows=fc.rows_staged,
+                bytes_consumed=fc.bytes_consumed,
+                # Consumer-side flow control (the engine's overload gauges
+                # ride inside health): which partitions are paused right
+                # now and how often the gate cycled.
+                paused_docs=len(fc.paused_socks),
+                pump_pauses=fc.pump_pauses,
+                pump_resumes=fc.pump_resumes,
+                **extra,
+            )
+            with span("status.emit"):
+                print(json.dumps(snap), flush=True)
 
     def final_state() -> dict:
         """The per-family identity surface for the done=True status line."""
@@ -446,13 +468,21 @@ def main(argv: list[str] | None = None) -> int:
                               for i, d in enumerate(doc_ids)}}
         return {"texts": dict(zip(doc_ids, eng.texts()))}
 
+    def on_sigterm(_signum, _frame) -> None:
+        # Leave the loop through the ``finally`` below, so that a run
+        # stopped from outside still writes its flight recorder.
+        raise KeyboardInterrupt
+
+    signal.signal(signal.SIGTERM, on_sigterm)
     drain_want: dict | None = None
     last_drain_poll = 0.0
     last_status = time.monotonic()
     last_rebalance = time.monotonic()
     try:
         while True:
-            staged = fc.pump()
+            staged = fc.pump(idle=idle)
+            if fc.last_ready:
+                idle = None
             if (
                 args.rebalance_every
                 and mesh is not None
@@ -495,8 +525,11 @@ def main(argv: list[str] | None = None) -> int:
                 # Paused partitions mean staged backlog over the watermark:
                 # keep stepping so the gate can re-arm those sockets, even
                 # when this pump read nothing (flow control, not idleness).
+                end_idle()  # paused partitions, nothing ready: work too
                 fc.step()
             else:
+                if idle is None:
+                    idle = span("idle").__enter__()
                 time.sleep(args.idle_sleep)
             now = time.monotonic()
             if now - last_status >= args.status_every:
@@ -529,6 +562,7 @@ def main(argv: list[str] | None = None) -> int:
         eng.maybe_checkpoint(force=True)
         return 0
     finally:
+        end_idle()
         fc.close()
         if ckpt_writer is not None:
             ckpt_writer.stop()
