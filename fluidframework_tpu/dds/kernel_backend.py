@@ -428,7 +428,7 @@ class KernelMergeTree:
             self._step(op, payload)
             if int(self.state.error) & fail_bits == 0:
                 # The new segment's uid is always the last allocation of the
-                # chunk's apply (_do_insert allocates the boundary-split uid
+                # chunk's apply (the op body allocates the boundary-split uid
                 # first, the new segment's uid last).
                 uids.append(int(self.state.uid_next) - 1)
         return uids

@@ -552,6 +552,13 @@ class DocBatchEngine:
         # Single-chip optimization: under a mesh the doc axis is sharded
         # evenly and arbitrary-index gathers would cross shards.
         self.bucketing = self.mesh is None
+        # Leaving the fleet-wide regime takes two small busy sets in a row:
+        # the first one after a fleet-wide step runs fleet-wide once more
+        # (one slice).  A busy set that hovers around the threshold would
+        # otherwise alternate between programs, and the tail of a wide burst
+        # (its last partial loop, a drain) would pay the first trace of a
+        # cohort size it may never see again.
+        self._wide_regime = False
         self.full_steps = 0     # fleet-wide steps taken
         self.cohort_steps = 0   # bucketed steps taken
         self.cohort_lanes = 0   # sum of cohort sizes (work proxy)
@@ -1279,13 +1286,15 @@ class DocBatchEngine:
                 need = int(depths.max())
         return min(self.megastep_k, self._pow2_floor(need))
 
-    def _full_step(self, busy: list[int]) -> int:
+    def _full_step(self, busy: list[int], k_max: int | None = None) -> int:
         """One fleet-wide megastep: pack up to K [capacity, B] slices into
         the staging ring (slice k+1 packs while the upload/dispatch of the
         previous megastep is still in flight) and apply them as one
         donated program; returns the slices applied."""
         with span("pack", kind="full", docs=len(busy)):
             K = self._select_k(busy, cohort=False)
+            if k_max is not None:
+                K = min(K, k_max)
             stage = self._staging()
             ops, payloads = stage.acquire(K, self.capacity)
             # Pack by doc PLACEMENT: doc d's ops land in row slot(d), so
@@ -1370,10 +1379,14 @@ class DocBatchEngine:
         steps = 0
         while self._busy:
             busy = sorted(self._busy)
-            if self.bucketing and len(busy) <= self.capacity // 4:
-                steps += self._cohort_step(busy)
-            else:
+            if not (self.bucketing and len(busy) <= self.capacity // 4):
+                self._wide_regime = self.bucketing
                 steps += self._full_step(busy)
+            elif self._wide_regime:
+                self._wide_regime = False
+                steps += self._full_step(busy, k_max=1)
+            else:
+                steps += self._cohort_step(busy)
         self._step_lanes()
         self._step_seg_lanes()
         self._step_count += 1

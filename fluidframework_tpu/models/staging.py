@@ -344,15 +344,17 @@ class StagingRing:
             (buf.payloads.ctypes.data, buf.payloads.nbytes),
         ]
         for a in arrs:
-            probe = getattr(a, "unsafe_buffer_pointer", None)
-            if probe is None:
-                continue
-            try:
-                p = int(probe())
-            except Exception:  # noqa: BLE001 — probe failure = assume no alias
-                continue
-            if any(base <= p < base + n for base, n in spans):
-                return True
+            # Shard by shard: a sharded array has no pointer of its own
+            # (the probe raises), yet under a mesh each shard of a K = 1
+            # upload is a contiguous slice of the staging buffer and
+            # aliases it as a whole array would.
+            for shard in getattr(a, "addressable_shards", ()):
+                try:
+                    p = int(shard.data.unsafe_buffer_pointer())
+                except Exception:  # noqa: BLE001 — probe failure = assume no alias
+                    continue
+                if any(base <= p < base + n for base, n in spans):
+                    return True
         return False
 
 

@@ -105,7 +105,7 @@ def _perm_insert(perm: mk.DocState, next_handle, op):
          jnp.zeros((), I32), count, jnp.zeros((), I32)]
     )
     # Permutation vectors never carry obliterates: ob machinery stays off.
-    new_perm = mk._do_insert(perm, ins_op, payload, jnp.zeros((), bool))
+    new_perm = mk.apply_op(perm, ins_op, payload, False)
     return new_perm, next_handle + count
 
 
@@ -114,7 +114,7 @@ def _perm_remove(perm: mk.DocState, op):
         [jnp.asarray(mk.OpKind.REMOVE, I32), op[1], op[2], op[3], op[4],
          op[4] + op[5], jnp.zeros((), I32), jnp.zeros((), I32)]
     )
-    return mk._do_remove(perm, rem_op, jnp.zeros((1,), I32))
+    return mk.apply_op(perm, rem_op, jnp.zeros((1,), I32), False)
 
 
 def apply_op(s: MatrixState, op: jnp.ndarray) -> MatrixState:

@@ -337,7 +337,9 @@ def _first_true(mask: jnp.ndarray, default: jnp.ndarray) -> jnp.ndarray:
 def _shift_right(arr, k, newval):
     """arr with a slot opened at k: [0..k-1] keep, [k]=newval, [k+1..] shifted."""
     idx = jnp.arange(arr.shape[0], dtype=I32)
-    prev = arr[jnp.maximum(idx - 1, 0)]
+    # The neighbour to the left, as a static shift: indexing with idx - 1
+    # lowers to a gather per column, which the TPU runs far slower.
+    prev = jnp.concatenate([arr[:1], arr[:-1]])
     return jnp.where(idx < k, arr, jnp.where(idx == k, newval, prev))
 
 
@@ -381,9 +383,18 @@ def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
     )
 
 
+def _geometry(s: DocState, ref_seq, client):
+    """(vis, vlen, excl) of ``s`` from one perspective: the visibility mask,
+    the visible lengths and their exclusive prefix sum."""
+    vis = _visible(s, ref_seq, client)
+    vlen, excl = _vis_lengths(s, vis)
+    return vis, vlen, excl
+
+
 @jax.named_scope("ensure_boundary")
-def _ensure_boundary(s: DocState, pos, ref_seq, client) -> DocState:
-    """Split the segment containing ``pos`` strictly inside it, if any.
+def _ensure_boundary(s: DocState, geom, pos, gate) -> DocState:
+    """Under ``gate``, split the segment containing ``pos`` strictly inside
+    it, if any.  ``geom`` is ``_geometry`` of ``s`` from the op's perspective.
 
     Mirrors the reference's split-on-walk (ensureIntervalBoundary /
     insertingWalk split path): after this, ``pos`` falls on a segment
@@ -391,11 +402,10 @@ def _ensure_boundary(s: DocState, pos, ref_seq, client) -> DocState:
     split segment follow the half holding their endpoint char: Before sides
     keep the left half's uid, After sides move to the right half.
     """
-    vis = _visible(s, ref_seq, client)
-    vlen, excl = _vis_lengths(s, vis)
+    vis, vlen, excl = geom
     mid = vis & (excl < pos) & (pos < excl + vlen)
     k = _first_true(mid, jnp.asarray(0, I32))  # default unused when ~do
-    do = jnp.any(mid)
+    do = gate & jnp.any(mid)
     off = pos - excl[k]
     old_uid = s.seg_uid[k]
     right_uid = s.uid_next
@@ -412,12 +422,13 @@ def _ensure_boundary(s: DocState, pos, ref_seq, client) -> DocState:
         prop_vals=tuple(a[k] for a in s.prop_vals),
     )
     s2 = _open_slot(s, k + 1, do, right)
-    # Trim the left half (only when the split actually happened).
-    new_len = jnp.where(do, off, s2.seg_len[k])
+    # Trim the left half (only when the split actually happened).  A masked
+    # write, not ``.at[k].set``: one element per document is a scatter.
+    at_k = jnp.arange(s2.seg_len.shape[0], dtype=I32) == k
     moved_start = do & (s2.ob_start_uid == old_uid) & (s2.ob_start_side == SIDE_AFTER)
     moved_end = do & (s2.ob_end_uid == old_uid) & (s2.ob_end_side == SIDE_AFTER)
     return s2._replace(
-        seg_len=s2.seg_len.at[k].set(new_len),
+        seg_len=jnp.where(do & at_k, off, s2.seg_len),
         uid_next=s2.uid_next + do.astype(I32),
         ob_start_uid=jnp.where(moved_start, right_uid, s2.ob_start_uid),
         ob_end_uid=jnp.where(moved_end, right_uid, s2.ob_end_uid),
@@ -425,7 +436,7 @@ def _ensure_boundary(s: DocState, pos, ref_seq, client) -> DocState:
 
 
 # --------------------------------------------------------------------------
-# Op branches
+# The parts of the op body
 # --------------------------------------------------------------------------
 
 def _tiebreak(s: DocState, op_key) -> jnp.ndarray:
@@ -506,7 +517,7 @@ def _obliterate_swallow(s: DocState, anchors, k, key, client, ref_seq):
         kk = ckeys[i]
         rem_k.append(kk)
         rem_c.append(jnp.where(kk < NO_REMOVE, s.ob_client[i], -1))
-        ckeys = ckeys.at[i].set(NO_REMOVE)
+        ckeys = jnp.where(jnp.arange(OB, dtype=I32) == i, NO_REMOVE, ckeys)
     overflow = jnp.any(ckeys < NO_REMOVE)
     obpre = jnp.where(any_conc, newest_key, -1)
     return tuple(rem_k), tuple(rem_c), obpre, overflow
@@ -526,84 +537,6 @@ def _no_obliterate_swallow(s: DocState):
     )
 
 
-def _do_insert(s: DocState, op, payload, ob_flag) -> DocState:
-    pos, key, client, ref_seq = op[4], op[1], op[2], op[3]
-    text_len = op[6]
-    s = _ensure_boundary(s, pos, ref_seq, client)
-    vis = _visible(s, ref_seq, client)
-    vlen, excl = _vis_lengths(s, vis)
-    total = jnp.sum(vlen)
-    # Boundary walk: insert before the first segment at/after pos that is
-    # visible or wins the tie-break; else append at nseg.
-    stop = _alive(s) & (excl >= pos) & ((vlen > 0) | _tiebreak(s, key))
-    k = _first_true(stop, s.nseg)
-
-    # Copy payload into the text pool (masked scatter, OOB indices dropped).
-    T = s.text.shape[0]
-    tpos = jnp.arange(payload.shape[0], dtype=I32)
-    text_over = s.text_end + text_len > T
-    dst = jnp.where((tpos < text_len) & ~text_over, s.text_end + tpos, T)
-    text = s.text.at[dst].set(payload, mode="drop")
-
-    # The [OB,S] swallow analysis only runs when an obliterate can exist.
-    # A PYTHON-bool ob_flag specializes the trace outright (no cond at all
-    # — apply_ops hoists the runtime branch to whole-scan level so the op
-    # body stays one fused kernel); a traced scalar falls back to lax.cond
-    # (scalar, so it stays a real branch under vmap).
-    if isinstance(ob_flag, bool):
-        new_rem_k, new_rem_c, obpre, rem_over = (
-            _obliterate_new_segment(s, k, key, client, ref_seq)
-            if ob_flag
-            else _no_obliterate_swallow(s)
-        )
-    else:
-        new_rem_k, new_rem_c, obpre, rem_over = jax.lax.cond(
-            ob_flag,
-            lambda s: _obliterate_new_segment(s, k, key, client, ref_seq),
-            _no_obliterate_swallow,
-            s,
-        )
-    P = len(s.prop_keys)
-    zero = jnp.zeros((), I32)
-    new = _NewSeg(
-        seg_start=s.text_end,
-        seg_len=text_len,
-        ins_key=key,
-        ins_client=client,
-        seg_uid=s.uid_next,
-        seg_obpre=obpre,
-        rem_keys=new_rem_k,
-        rem_clients=new_rem_c,
-        prop_keys=tuple(jnp.full((), -1, I32) for _ in range(P)),
-        prop_vals=tuple(zero for _ in range(P)),
-    )
-    ok = ~text_over & (pos <= total)
-    s = _open_slot(s, k, ok, new)
-    return s._replace(
-        text=jnp.where(text_over, s.text, text),
-        text_end=s.text_end + jnp.where(ok, text_len, 0),
-        uid_next=s.uid_next + ok.astype(I32),
-        error=s.error
-        | jnp.where(text_over, ERR_TEXT_OVERFLOW, 0)
-        | jnp.where(pos > total, ERR_POS_RANGE, 0)
-        | jnp.where(ok & rem_over, ERR_REM_OVERFLOW, 0),
-    )
-
-
-@jax.named_scope("mark_range")
-def _mark_range(s: DocState, op) -> tuple[DocState, jnp.ndarray]:
-    """Split at both boundaries; return mask of visible segments inside."""
-    pos1, pos2, client, ref_seq = op[4], op[5], op[2], op[3]
-    s = _ensure_boundary(s, pos1, ref_seq, client)
-    s = _ensure_boundary(s, pos2, ref_seq, client)
-    vis = _visible(s, ref_seq, client)
-    vlen, excl = _vis_lengths(s, vis)
-    total = jnp.sum(vlen)
-    mark = vis & (excl >= pos1) & (excl + vlen <= pos2) & (vlen > 0)
-    s = s._replace(error=s.error | jnp.where(pos2 > total, ERR_POS_RANGE, 0))
-    return s, mark
-
-
 def _splice_remove_stamp(s: DocState, mark, key, client):
     """Place a remove stamp into the first free slot of every marked
     segment; returns (rem_keys, rem_clients, overflow)."""
@@ -616,17 +549,6 @@ def _splice_remove_stamp(s: DocState, mark, key, client):
         rem_clients[r] = jnp.where(sel, client, rem_clients[r])
         placed = placed | sel
     return tuple(rem_keys), tuple(rem_clients), jnp.any(mark & ~placed)
-
-
-def _do_remove(s: DocState, op, payload) -> DocState:
-    key, client = op[1], op[2]
-    s, mark = _mark_range(s, op)
-    rem_keys, rem_clients, overflow = _splice_remove_stamp(s, mark, key, client)
-    return s._replace(
-        rem_keys=rem_keys,
-        rem_clients=rem_clients,
-        error=s.error | jnp.where(overflow, ERR_REM_OVERFLOW, 0),
-    )
 
 
 def _annotate_marked(s: DocState, mark, op) -> DocState:
@@ -642,11 +564,6 @@ def _annotate_marked(s: DocState, mark, op) -> DocState:
         prop_keys[p] = jnp.where(win, key, prop_keys[p])
         prop_vals[p] = jnp.where(win, value, prop_vals[p])
     return s._replace(prop_keys=tuple(prop_keys), prop_vals=tuple(prop_vals))
-
-
-def _do_annotate(s: DocState, op, payload) -> DocState:
-    s, mark = _mark_range(s, op)
-    return _annotate_marked(s, mark, op)
 
 
 def _obliterate_visit(s: DocState, vis, key, client, ref_seq):
@@ -688,110 +605,271 @@ def _obliterate_visit(s: DocState, vis, key, client, ref_seq):
     return visit, skip
 
 
-def _do_obliterate(s: DocState, op, payload) -> DocState:
-    """Sided obliterate (ref mergeTree.ts obliterateRangeSided:2083): mark
-    every not-yet-removed segment in the anchor window — concurrent inserts
-    included — and record the obliterate for insert-time swallowing.
-
-    pos1/pos2 are the endpoint CHARACTER positions in the op's perspective;
-    op[6]/op[7] carry the sides (plain {pos1,pos2} ops encode as
-    (pos1, Before) .. (pos2-1, After))."""
-    key, client, ref_seq = op[1], op[2], op[3]
-    pos1, pos2, side1, side2 = op[4], op[5], op[6], op[7]
-    start_pos = pos1 + side1
-    end_pos = pos2 + side2
-    vis = _visible(s, ref_seq, client)
-    vlen, _excl = _vis_lengths(s, vis)
-    total = jnp.sum(vlen)
-    valid = (0 <= pos1) & (pos1 <= pos2) & (pos2 < total) & (start_pos <= end_pos)
-    s = _ensure_boundary(s, jnp.where(valid, start_pos, 0), ref_seq, client)
-    s = _ensure_boundary(s, jnp.where(valid, end_pos, 0), ref_seq, client)
-    vis = _visible(s, ref_seq, client)
-    vlen, excl = _vis_lengths(s, vis)
-    # Anchor segments: the visible segments containing the endpoint chars.
-    cont_s = vis & (excl <= pos1) & (pos1 < excl + vlen)
-    cont_e = vis & (excl <= pos2) & (pos2 < excl + vlen)
-    s_idx = _first_true(cont_s, s.nseg)
-    e_idx = _first_true(cont_e, s.nseg)
-    lo = s_idx + (side1 == SIDE_AFTER).astype(I32)
-    hi = e_idx - (side2 == SIDE_BEFORE).astype(I32)
-    idx = jnp.arange(s.seg_len.shape[0], dtype=I32)
-    visit, skip = _obliterate_visit(s, vis, key, client, ref_seq)
-    mark = valid & _alive(s) & (idx >= lo) & (idx <= hi) & visit & ~skip
-    # Splice the stamp into the first free remove slot (segments covered by
-    # earlier removes already occupy lower slots).
-    rem_keys, rem_clients, rem_over = _splice_remove_stamp(s, mark, key, client)
-    # Record in the obliterate window table.
-    free = s.ob_key < 0
-    slot = _first_true(free, jnp.asarray(0, I32))
-    has_free = jnp.any(free)
-    rec = valid & has_free
-
-    def put(arr, val):
-        return arr.at[slot].set(jnp.where(rec, val, arr[slot]))
-
-    return s._replace(
-        rem_keys=rem_keys,
-        rem_clients=rem_clients,
-        ob_key=put(s.ob_key, key),
-        ob_client=put(s.ob_client, client),
-        ob_start_uid=put(s.ob_start_uid, s.seg_uid[s_idx]),
-        ob_end_uid=put(s.ob_end_uid, s.seg_uid[e_idx]),
-        ob_start_side=put(s.ob_start_side, side1),
-        ob_end_side=put(s.ob_end_side, side2),
-        ob_ref_seq=put(s.ob_ref_seq, ref_seq),
-        error=s.error
-        | jnp.where(~valid, ERR_POS_RANGE, 0)
-        | jnp.where(valid & ~has_free, ERR_OB_OVERFLOW, 0)
-        | jnp.where(rem_over, ERR_REM_OVERFLOW, 0),
-    )
-
-
-def _do_ack(s: DocState, op, payload) -> DocState:
-    """Convert pending stamps (localSeq) to the acked seq; optionally
-    re-stamp the client id (op[2] >= 0) and the obliterate's recorded refSeq
-    (op[3] >= 0) — channel-hosted replicas stamp local pending ops with a
-    sentinel client and learn their short id / wire refSeq only at ack
-    (mirrors mergetree_ref.RefMergeTree.ack)."""
+def _restamp_acked(s: DocState, op, gate) -> DocState:
+    """Under ``gate``, convert pending stamps (localSeq) to the acked seq;
+    optionally re-stamp the client id (op[2] >= 0) and the obliterate's
+    recorded refSeq (op[3] >= 0) — channel-hosted replicas stamp local
+    pending ops with a sentinel client and learn their short id / wire
+    refSeq only at ack (mirrors mergetree_ref.RefMergeTree.ack)."""
     local_seq, seq = op[6], op[7]
     new_client, new_ref = op[2], op[3]
     local_key = LOCAL_BASE + local_seq
-    ins_hit = s.ins_key == local_key
-    ob_hit = s.ob_key == local_key
+
+    def hit(keys):
+        return gate & (keys == local_key)
+
+    ins_hit = hit(s.ins_key)
+    ob_hit = hit(s.ob_key)
     rw_c = new_client >= 0
     return s._replace(
         ins_key=jnp.where(ins_hit, seq, s.ins_key),
         ins_client=jnp.where(ins_hit & rw_c, new_client, s.ins_client),
-        rem_keys=tuple(jnp.where(a == local_key, seq, a) for a in s.rem_keys),
+        rem_keys=tuple(jnp.where(hit(a), seq, a) for a in s.rem_keys),
         rem_clients=tuple(
-            jnp.where((k == local_key) & rw_c, new_client, c)
+            jnp.where(hit(k) & rw_c, new_client, c)
             for k, c in zip(s.rem_keys, s.rem_clients)
         ),
-        prop_keys=tuple(jnp.where(a == local_key, seq, a) for a in s.prop_keys),
+        prop_keys=tuple(jnp.where(hit(a), seq, a) for a in s.prop_keys),
         ob_key=jnp.where(ob_hit, seq, s.ob_key),
         ob_client=jnp.where(ob_hit & rw_c, new_client, s.ob_client),
         ob_ref_seq=jnp.where(ob_hit & (new_ref >= 0), new_ref, s.ob_ref_seq),
-        seg_obpre=jnp.where(s.seg_obpre == local_key, seq, s.seg_obpre),
+        seg_obpre=jnp.where(hit(s.seg_obpre), seq, s.seg_obpre),
     )
+
+
+def _do_ack(s: DocState, op, payload) -> DocState:
+    return _restamp_acked(s, op, True)
 
 
 # One ``jax.named_scope`` per op kind, in ``OpKind`` order after NOOP.  The
 # scope is the first component of every instruction's ``op_name`` that a
-# branch lowers to, and the device trace groups a step's time by it
-# (benchmark/host_plane.py; the ``kernel_*_share`` metrics).  What a step runs
-# outside any branch (the switch's merge select, the scan's carry) stays
-# unscoped.  The persistent compile cache has to key on this metadata
-# (utils/compile_cache.py), or a cached executable keeps its old names.
+# kind's own part of the op body lowers to, and the device trace groups a
+# step's time by it (benchmark/host_plane.py; the ``kernel_*_share`` metrics).
+# What every row runs whatever its kind (the perspective's geometry, the two
+# boundary splits, the range mask) is under ``SHARED_SCOPE``, which names no
+# kind; the scan's carry has no scope at all.  The persistent compile cache
+# has to key on this metadata (utils/compile_cache.py), or a cached executable
+# keeps its old names.
 BRANCH_SCOPES = ("insert", "remove", "annotate", "ack", "obliterate")
+SHARED_SCOPE = "shared"
 
 
 def _scoped_branches(*fns) -> list:
-    """``lax.switch`` branches by ``OpKind``: NOOP, then ``fns`` each under
-    its scope of ``BRANCH_SCOPES``."""
+    """``lax.switch`` branches by ``OpKind`` for ``apply_op_seg``: NOOP, then
+    ``fns`` each under its scope of ``BRANCH_SCOPES``."""
     return [lambda s, op, p: s] + [
         jax.named_scope(name)(fn)
         for name, fn in zip(BRANCH_SCOPES, fns, strict=True)
     ]
+
+
+class _TextWrite(NamedTuple):
+    """What one row writes to the text pool: ``count`` payload elements from
+    ``start`` on (count 0: nothing)."""
+
+    start: jnp.ndarray
+    count: jnp.ndarray
+
+
+def _text_write_indices(writes: _TextWrite, width: int, capacity: int):
+    """[B, width] pool indices of a batch of rows' text writes, with the
+    result of applying them one after another and NO index twice.  Only a
+    rejected insert (position out of range) writes without advancing
+    ``text_end``, and a later row then writes over it: such elements go to
+    ``capacity`` (dropped), so a scatter's unspecified order cannot show."""
+    B = writes.start.shape[0]
+    tpos = jnp.arange(width, dtype=I32)
+    pos = writes.start[:, None] + tpos[None, :]                    # [B, L]
+    live = tpos[None, :] < writes.count[:, None]                   # [B, L]
+    # covered[i, j]: some later row i' writes position pos[i, j] too.
+    later = jnp.arange(B)[:, None] < jnp.arange(B)[None, :]        # [B, B']
+    lo = writes.start[None, None, :]                               # [1, 1, B']
+    hi = lo + writes.count[None, None, :]
+    hit = (lo <= pos[:, :, None]) & (pos[:, :, None] < hi)         # [B, L, B']
+    covered = jnp.any(hit & later[:, None, :], axis=-1)
+    return jnp.where(live & ~covered, pos, capacity)
+
+
+def _write_text(text, writes: _TextWrite, payloads):
+    """Apply the text writes of a batch of rows ([B] starts and counts,
+    [B, L] payloads) to the pool in ONE scatter.  The pool is append-only
+    and nothing in the op body reads it, so the scan need not carry it: a
+    scatter into [D, T] per row costs the TPU three passes over the whole
+    pool (it is relaid out for the scatter and back)."""
+    dst = _text_write_indices(writes, payloads.shape[1], text.shape[0])
+    # The barrier keeps the [B, L] -> [B * L] reshapes out of the scatter's
+    # fusion: fused, the fleet-wide program compiles in 70 s, not 17.
+    dst, vals = jax.lax.optimization_barrier(
+        (dst.reshape(-1), payloads.reshape(-1))
+    )
+    return text.at[dst].set(vals, mode="drop")
+
+
+def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
+    """The op body: one straight-line program for every kind.  Returns the
+    new state and the row's ``_TextWrite``; ``s.text`` is never looked at
+    (``apply_ops`` keeps the pool out of the scan's carry).
+
+    Under ``vmap`` a ``lax.switch`` on the row's kind runs every branch for
+    every row and selects between whole ``DocState``s, text pool included.
+    All kinds read the document from the same perspective (``ref_seq``,
+    ``client`` of the row), so here the shared work runs once (two gated
+    boundary splits, one geometry after them) and the kinds differ only in
+    the masks their writes go under.  ``flag`` is the Python bool of
+    ``apply_op``: with False the obliterate parts trace to nothing.
+    """
+    kind, key, client, ref_seq = op[0], op[1], op[2], op[3]
+    pos1, pos2, a, b = op[4], op[5], op[6], op[7]
+    is_insert = kind == OpKind.INSERT
+    is_remove = kind == OpKind.REMOVE
+    is_annotate = kind == OpKind.ANNOTATE
+    is_ack = kind == OpKind.ACK
+    is_range = is_remove | is_annotate
+    # Out-of-range kinds keep what ``lax.switch`` made of them by clamping
+    # (native/megastep.cpp mirrors it): below NOOP nothing, above OBLITERATE
+    # an obliterate.
+    is_ob = (kind >= OpKind.OBLITERATE) if flag else False
+
+    with jax.named_scope(SHARED_SCOPE):
+        geom = _geometry(s, ref_seq, client)
+        # A split moves no visible length: this total serves every range
+        # check below.
+        total = jnp.sum(geom[1])
+        # The row's two boundaries: insert (pos1, none), remove/annotate
+        # (pos1, pos2), a valid obliterate its sided endpoints, else none.
+        cut1, cut2 = pos1, pos2
+        do_cut1, do_cut2 = is_insert | is_range, is_range
+        if flag:
+            start_pos, end_pos = pos1 + a, pos2 + b
+            valid = (
+                (0 <= pos1) & (pos1 <= pos2) & (pos2 < total)
+                & (start_pos <= end_pos)
+            )
+            ob_ok = is_ob & valid
+            cut1 = jnp.where(is_ob, start_pos, cut1)
+            cut2 = jnp.where(is_ob, end_pos, cut2)
+            do_cut1, do_cut2 = do_cut1 | ob_ok, do_cut2 | ob_ok
+        s = _ensure_boundary(s, geom, cut1, do_cut1)
+        s = _ensure_boundary(s, _geometry(s, ref_seq, client), cut2, do_cut2)
+        vis, vlen, excl = _geometry(s, ref_seq, client)
+        alive = _alive(s)
+        with jax.named_scope("mark_range"):
+            in_range = (
+                vis & (excl >= pos1) & (excl + vlen <= pos2) & (vlen > 0)
+            )
+        error = s.error | jnp.where(is_range & (pos2 > total), ERR_POS_RANGE, 0)
+
+    with jax.named_scope("insert"):
+        text_len = a
+        # Boundary walk: insert before the first segment at/after pos that
+        # is visible or wins the tie-break; else append at nseg.
+        stop = alive & (excl >= pos1) & ((vlen > 0) | _tiebreak(s, key))
+        k = _first_true(stop, s.nseg)
+        # The payload goes into the text pool at ``text_end`` whenever it
+        # fits, whether or not the position is in range.  Only this write
+        # ever touches the pool (``_write_text``): a row that is no insert,
+        # or overflows it, writes nothing, so no select over the pool
+        # exists on any path.
+        text_over = is_insert & (s.text_end + text_len > text_capacity)
+        fits = is_insert & ~text_over
+        write = _TextWrite(
+            s.text_end,
+            jnp.where(fits, jnp.clip(text_len, 0, payload.shape[0]), 0),
+        )
+        # The [OB,S] swallow analysis only traces when an obliterate can
+        # exist (apply_ops hoists the runtime branch to whole-scan level).
+        new_rem_k, new_rem_c, obpre, swallow_over = (
+            _obliterate_new_segment(s, k, key, client, ref_seq)
+            if flag
+            else _no_obliterate_swallow(s)
+        )
+        P = len(s.prop_keys)
+        zero = jnp.zeros((), I32)
+        new = _NewSeg(
+            seg_start=s.text_end,
+            seg_len=text_len,
+            ins_key=key,
+            ins_client=client,
+            seg_uid=s.uid_next,
+            seg_obpre=obpre,
+            rem_keys=new_rem_k,
+            rem_clients=new_rem_c,
+            prop_keys=tuple(jnp.full((), -1, I32) for _ in range(P)),
+            prop_vals=tuple(zero for _ in range(P)),
+        )
+        ok = fits & (pos1 <= total)
+        error = (
+            error
+            | jnp.where(text_over, ERR_TEXT_OVERFLOW, 0)
+            | jnp.where(is_insert & (pos1 > total), ERR_POS_RANGE, 0)
+            | jnp.where(ok & swallow_over, ERR_REM_OVERFLOW, 0)
+        )
+
+    stamp = in_range & is_remove
+    if flag:
+        with jax.named_scope("obliterate"):
+            # Sided obliterate (ref mergeTree.ts obliterateRangeSided:2083):
+            # mark every not-yet-removed segment in the anchor window —
+            # concurrent inserts included — and record the obliterate for
+            # insert-time swallowing.  pos1/pos2 are the endpoint CHARACTER
+            # positions in the op's perspective, a/b the sides.  Anchor
+            # segments: the visible segments containing the endpoint chars.
+            cont_s = vis & (excl <= pos1) & (pos1 < excl + vlen)
+            cont_e = vis & (excl <= pos2) & (pos2 < excl + vlen)
+            s_idx = _first_true(cont_s, s.nseg)
+            e_idx = _first_true(cont_e, s.nseg)
+            lo = s_idx + (a == SIDE_AFTER).astype(I32)
+            hi = e_idx - (b == SIDE_BEFORE).astype(I32)
+            idx = jnp.arange(s.seg_len.shape[0], dtype=I32)
+            visit, skip = _obliterate_visit(s, vis, key, client, ref_seq)
+            window = ob_ok & alive & (idx >= lo) & (idx <= hi) & visit & ~skip
+            stamp = stamp | window
+            # Record in the obliterate window table.
+            free = s.ob_key < 0
+            slot = _first_true(free, jnp.asarray(0, I32))
+            has_free = jnp.any(free)
+            rec = ob_ok & has_free
+
+            at_slot = rec & (jnp.arange(s.ob_key.shape[0], dtype=I32) == slot)
+
+            def put(arr, val):
+                return jnp.where(at_slot, val, arr)
+
+            s = s._replace(
+                ob_key=put(s.ob_key, key),
+                ob_client=put(s.ob_client, client),
+                ob_start_uid=put(s.ob_start_uid, s.seg_uid[s_idx]),
+                ob_end_uid=put(s.ob_end_uid, s.seg_uid[e_idx]),
+                ob_start_side=put(s.ob_start_side, a),
+                ob_end_side=put(s.ob_end_side, b),
+                ob_ref_seq=put(s.ob_ref_seq, ref_seq),
+            )
+            error = (
+                error
+                | jnp.where(is_ob & ~valid, ERR_POS_RANGE, 0)
+                | jnp.where(ob_ok & ~has_free, ERR_OB_OVERFLOW, 0)
+            )
+
+    with jax.named_scope("remove"):
+        # One splice serves remove's range and obliterate's window (segments
+        # covered by earlier removes already occupy lower slots).
+        rem_keys, rem_clients, stamp_over = _splice_remove_stamp(
+            s, stamp, key, client
+        )
+        s = s._replace(rem_keys=rem_keys, rem_clients=rem_clients)
+        error = error | jnp.where(stamp_over, ERR_REM_OVERFLOW, 0)
+    with jax.named_scope("annotate"):
+        s = _annotate_marked(s, in_range & is_annotate, op)
+    with jax.named_scope("ack"):
+        s = _restamp_acked(s, op, is_ack)
+    with jax.named_scope("insert"):
+        # The kinds exclude one another: on an insert row nothing above has
+        # written, so ``k`` still indexes this state.
+        s = _open_slot(s._replace(error=error), k, ok, new)
+        return s._replace(
+            text_end=s.text_end + jnp.where(ok, text_len, 0),
+            uid_next=s.uid_next + ok.astype(I32),
+        ), write
 
 
 def apply_op(
@@ -801,36 +879,15 @@ def apply_op(
 
     ``ob_flag`` gates the obliterate machinery off the hot path: it must be
     True whenever the ob table may be nonempty or this op may be an
-    OBLITERATE (default: computed per doc).  Batched callers MUST pass a
-    scalar flag computed OUTSIDE vmap (any doc's table nonempty | any op in
-    the batch is OBLITERATE): an unbatched predicate keeps lax.cond a real
-    branch under vmap, a batched one degrades it to select-of-both-branches.
+    OBLITERATE (default: computed per doc).  A PYTHON bool specializes the
+    trace outright (``apply_ops`` hoists the runtime branch to whole-scan
+    level, so the op body stays one program with no interior cond); a traced
+    flag picks between the two traces with one ``lax.cond`` and so must be a
+    scalar computed OUTSIDE any vmap (any doc's table nonempty | any op in
+    the batch is OBLITERATE): a batched predicate would degrade the cond to
+    select-of-both.
     """
-    if ob_flag is None:
-        ob_flag = jnp.any(s.ob_key >= 0) | (op[0] == OpKind.OBLITERATE)
-    kind = op[0]
-    if isinstance(ob_flag, bool):
-        # Specialized trace (see _do_insert): with False the obliterate
-        # branch is unreachable by the flag's contract, so it traces to
-        # identity and the whole op body fuses with no interior cond.
-        ob_branch = (
-            (lambda s, op, p: _do_obliterate(s, op, p))
-            if ob_flag
-            else (lambda s, op, p: s)
-        )
-    else:
-        ob_branch = lambda s, op, p: jax.lax.cond(  # noqa: E731
-            ob_flag, lambda st: _do_obliterate(st, op, p), lambda st: st, s
-        )
-    branches = _scoped_branches(
-        lambda s, op, p: _do_insert(s, op, p, ob_flag),
-        _do_remove,
-        _do_annotate,
-        _do_ack,
-        ob_branch,
-    )
-    s = jax.lax.switch(kind, branches, s, op, payload)
-    return s
+    return apply_ops(s, op[None], payload[None], ob_flag)
 
 
 def apply_ops(
@@ -847,12 +904,16 @@ def apply_ops(
         ob_flag = jnp.any(s.ob_key >= 0) | jnp.any(ops[:, 0] == OpKind.OBLITERATE)
 
     def scan_spec(st: DocState, flag: bool) -> DocState:
+        T = st.text.shape[0]
+
         def step(carry, xs):
             op, payload = xs
-            return apply_op(carry, op, payload, flag), None
+            return _apply_row(carry, op, payload, flag, T)
 
-        out, _ = jax.lax.scan(step, st, (ops, payloads))
-        return out
+        out, writes = jax.lax.scan(
+            step, st._replace(text=jnp.zeros((0,), I32)), (ops, payloads)
+        )
+        return out._replace(text=_write_text(st.text, writes, payloads))
 
     if isinstance(ob_flag, bool):
         return scan_spec(s, ob_flag)
@@ -1165,7 +1226,7 @@ def _do_insert_seg(s: DocState, op, payload, ob_flag: bool, axis: str) -> DocSta
 
 
 def _mark_range_seg(s: DocState, op, axis: str):
-    """Distributed ``_mark_range``: split at both boundaries, then the
+    """The distributed range mark: split at both boundaries, then the
     in-range mask is a purely-local comparison against the global prefix."""
     pos1, pos2, client, ref_seq = op[4], op[5], op[2], op[3]
     s = _ensure_boundary_seg(s, pos1, ref_seq, client, axis)
@@ -1195,7 +1256,7 @@ def _do_annotate_seg(s: DocState, op, payload, axis: str) -> DocState:
 
 
 def _do_obliterate_seg(s: DocState, op, payload, axis: str) -> DocState:
-    """Distributed ``_do_obliterate``: anchors resolve with the two hops,
+    """The distributed obliterate: anchors resolve with the two hops,
     the visit/skip masks and the remove-stamp splice are local, and the
     obliterate window record replays identically on every shard from the
     psum-broadcast anchor uids."""

@@ -1,0 +1,400 @@
+"""The merge-tree op body (``mk.apply_op``): one straight-line program per
+row, held to the host oracle and to its own structure.
+
+(a) Row streams recorded from real multi-client farms (every row a replica's
+kernel backend applied: remote ops, local pending ops, their acks, sided
+obliterates), with illegal rows spliced in (positions out of range, inserts
+that overflow the text pool, invalid obliterates), are replayed through the
+batched kernel — ``vmap(apply_ops)`` slice by slice, and K > 1 through
+``apply_megastep`` — and through ``dds/mergetree_ref.RefMergeTree``.  Every
+leaf's live content must agree: segment boundaries, text, stamps, remove
+sets, props, obliterate records, and the error latch bit for bit.  (Raw
+byte identity of the padding too is ``test_dispatch_backends``' job, against
+``native/megastep.cpp``.)
+
+(b) The jaxpr of the vmapped scan keeps the shape the body was written for:
+at most three cumulative sums a row, no select over the text pool (which the
+scan does not even carry: one scatter after it is the pool's only write), no
+``cond``/``switch`` on a batched predicate.
+"""
+
+import functools
+import random
+
+import jax
+import jax.numpy as jnp
+from jax.extend import core as jex_core
+import numpy as np
+import pytest
+
+from fluidframework_tpu.dds.kernel_backend import (
+    KernelMergeTree,
+    pull_obliterates,
+    pull_segments,
+)
+from fluidframework_tpu.dds.mergetree_ref import RefMergeTree
+from fluidframework_tpu.dds.shared_string import SharedString
+from fluidframework_tpu.models import doc_batch_engine as dbe
+from fluidframework_tpu.ops import mergetree_kernel as mk
+from fluidframework_tpu.server.local_service import LocalDocument
+
+from test_mergetree_oracle import draw_op, issue_op, pump
+
+S, R, P, T, OB, L, B = 192, 4, 4, 512, 16, 8, 8
+K = mk.OpKind
+
+
+class _Recording(KernelMergeTree):
+    """A kernel backend that keeps every row it applied.  The collab window
+    never moves (no compaction), so the rows alone rebuild the state."""
+
+    def __init__(self):
+        super().__init__(max_segments=S, remove_slots=R, prop_slots=P,
+                         text_capacity=T, max_insert_len=L, ob_slots=OB)
+        self.rows = []
+
+    def _step(self, op, payload=None):
+        p = self._empty_payload if payload is None else payload
+        self.rows.append((np.array(op, np.int32), np.array(p, np.int32)))
+        super()._step(op, payload)
+
+    def update_min_seq(self, min_seq):
+        pass
+
+
+def _farm(seed, kinds):
+    """Rows of every replica of one seeded farm that issues only ``kinds``
+    (acks and the rows of remote ops come with them)."""
+    rng = random.Random(seed)
+    doc = LocalDocument("d")
+    clients = [SharedString(client_id=f"c{i}", backend=_Recording())
+               for i in range(3)]
+    for c in clients:
+        doc.connect(c.client_id, c.process)
+    doc.process_all()
+    for _round in range(6):
+        for c in clients:
+            for _ in range(rng.randint(0, 2)):
+                op = draw_op(rng, len(c.text))
+                while op[0] not in kinds:
+                    op = draw_op(rng, len(c.text))
+                issue_op(c, op)
+            if rng.random() < 0.7:
+                for m in c.take_outbox():
+                    doc.submit(m)
+        doc.process_some(rng.randint(0, doc.pending_count))
+    pump(doc, clients)
+    assert len({c.text for c in clients}) == 1
+    for c in clients:
+        assert c.backend.check_errors() == 0
+    return [c.backend.rows for c in clients]
+
+
+def _row(kind, key, client, ref, pos1=0, pos2=0, a=0, b=0):
+    return (np.array([kind, key, client, ref, pos1, pos2, a, b], np.int32),
+            np.zeros((L,), np.int32))
+
+
+def _oracle_with_illegal_rows(rows, rng, flavour):
+    """Replay ``rows`` through the host oracle, splicing in rows the stream
+    itself never carries.  Returns (the rows to feed the kernel, the oracle,
+    the error bits the kernel has to latch).  ``flavour`` 0 splices only rows
+    that must latch nothing, 1 only pool overflows, 2 everything."""
+    tree = RefMergeTree()
+    out, bits, seq = [], 0, 0
+    # The text pool's fill, as the kernel counts it (every applied insert).
+    pool = 0
+
+    def noise():
+        # Fields a kind does not read hold what would trip another kind's
+        # check: nothing may latch, nothing may change.
+        total = tree.visible_length(seq, 0)
+        pick = rng.randrange(4)
+        if pick == 0:
+            out.append(_row(K.NOOP, seq, 0, seq, total + 9, total + 9, T + 1, 7))
+        elif pick == 1:
+            out.append(_row(-3, seq, 0, seq, total + 9, 0, T + 1, 0))
+        elif pick == 2:
+            # An ack of a local seq nobody holds, as large as a text length
+            # the pool could not take.
+            out.append(_row(K.ACK, 0, -1, -1, total + 9, total + 9, T + 1, seq))
+        else:
+            # An empty remove still cuts its boundary.
+            p = rng.randint(0, total)
+            out.append(_row(K.REMOVE, seq, 0, seq, p, p, T + 1, -5))
+            tree.apply_remove(p, p, seq, 0, seq)
+
+    def overflow():
+        # An insert the pool cannot take: the boundary is still cut.
+        nonlocal bits
+        pos = rng.randint(0, tree.visible_length(seq, 0))
+        out.append(_row(K.INSERT, seq, 0, seq, pos, a=T - pool + 1))
+        tree._split_at(pos, seq, 0)
+        bits |= mk.ERR_TEXT_OVERFLOW
+
+    def out_of_range():
+        nonlocal bits
+        total = tree.visible_length(seq, 0)
+        pick = rng.randrange(3)
+        if pick == 0:
+            # Insert past the end: rejected whole.
+            out.append(_row(K.INSERT, seq, 0, seq, total + rng.randint(1, 3),
+                            a=2))
+        elif pick == 1 or total < 2:
+            # An obliterate whose end character does not exist.
+            out.append(_row(K.OBLITERATE, seq, 0, seq, rng.randint(0, total),
+                            total + rng.randint(0, 2),
+                            rng.randint(0, 1), rng.randint(0, 1)))
+        else:
+            # Inverted places on characters that do exist.
+            out.append(_row(K.OBLITERATE, seq, 0, seq, 1, 0, 0, 1))
+        bits |= mk.ERR_POS_RANGE
+
+    splices = ([noise], [noise, overflow],
+               [noise, overflow, out_of_range])[flavour]
+    for op, payload in rows:
+        if rng.random() < 0.25:
+            rng.choice(splices)()
+        kind, key, client, ref, pos1, pos2, a, b = (int(v) for v in op)
+        if kind == K.INSERT:
+            text = "".join(chr(c) for c in payload[:a])
+            tree.apply_insert(pos1, text, key, client, ref)
+            pool += a
+        elif kind == K.REMOVE:
+            tree.apply_remove(pos1, pos2, key, client, ref)
+        elif kind == K.ANNOTATE:
+            tree.apply_annotate(pos1, pos2, a, b, key, client, ref)
+        elif kind == K.OBLITERATE:
+            tree.apply_obliterate(pos1, a, pos2, b, key, client, ref)
+        elif kind == K.ACK:
+            tree.ack(a, b, client if client >= 0 else None,
+                     ref if ref >= 0 else None)
+            seq = max(seq, b)
+        if key < mk.LOCAL_BASE and kind != K.ACK:
+            seq = max(seq, key)
+        out.append((op, payload))
+    if flavour == 2:
+        # Last row: a remove that runs past the end marks what exists.
+        total = tree.visible_length(seq, 0)
+        p1 = rng.randint(0, total)
+        out.append(_row(K.REMOVE, seq + 1, 0, seq, p1, total + 2))
+        if p1 < total:
+            tree.apply_remove(p1, total, seq + 1, 0, seq)
+        bits |= mk.ERR_POS_RANGE
+    return out, tree, bits
+
+
+def _oracle_content(tree):
+    index = {id(s): i for i, s in enumerate(tree.segments)}
+    segs = [
+        (s.text, s.ins_key, s.ins_client, sorted(s.removes),
+         {p: tuple(v) for p, v in s.props.items()},
+         -1 if s.ob_preceding is None else s.ob_preceding.key)
+        for s in tree.segments
+    ]
+    obs = [
+        (o.key, o.client, index[id(o.start_seg)], o.start_side,
+         index[id(o.end_seg)], o.end_side, o.ref_seq)
+        for o in tree.obliterates
+    ]
+    return segs, obs
+
+
+def _kernel_content(state):
+    pulled = pull_segments(state, with_text=True)
+    index = {s.uid: i for i, s in enumerate(pulled)}
+    segs = [
+        (s.text, s.ins_key, s.ins_client, s.removes, s.props, s.obpre)
+        for s in pulled
+    ]
+    obs = [
+        (o.key, o.client, index[o.start_uid], o.start_side,
+         index[o.end_uid], o.end_side, o.ref_seq)
+        for o in pull_obliterates(state)
+    ]
+    return segs, obs
+
+
+def _fleet(n_docs):
+    proto = mk.init_state(S, R, P, T, OB)
+    return jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (n_docs,) + x.shape), proto)
+
+
+
+
+def _run_batched(streams, k):
+    """Apply one stream per document, B rows a slice, NOOP-padded, through
+    the engine's own step programs: ``_fleet_step`` slice by slice, or ``k``
+    slices a dispatch through ``_fleet_megastep`` when k > 1."""
+    n_docs = len(streams)
+    n_slices = max(-(-len(s) // B) for s in streams)
+    n_slices = -(-n_slices // k) * k
+    ops = np.zeros((n_slices, n_docs, B, mk.OP_FIELDS), np.int32)
+    pays = np.zeros((n_slices, n_docs, B, L), np.int32)
+    for d, stream in enumerate(streams):
+        for i, (op, payload) in enumerate(stream):
+            ops[i // B, d, i % B] = op
+            pays[i // B, d, i % B] = payload
+    state = _fleet(n_docs)
+    if k == 1:
+        for i in range(n_slices):
+            state = dbe._fleet_step(state, jnp.asarray(ops[i]),
+                                    jnp.asarray(pays[i]))
+    else:
+        for i in range(0, n_slices, k):
+            state = dbe._fleet_megastep(state, jnp.asarray(ops[i:i + k]),
+                                        jnp.asarray(pays[i:i + k]))
+    return state
+
+
+KIND_SETS = {
+    "insert": ("insert",),
+    "remove": ("insert", "remove"),
+    "annotate": ("insert", "annotate"),
+    "obliterate": ("insert", "obliterate", "obliterate_sided"),
+    "all": ("insert", "remove", "annotate", "obliterate", "obliterate_sided"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _case(name):
+    """(kernel rows, oracle, expected error bits) per document of a case.
+    ``mixed`` lays the replicas of every other case side by side, so each
+    scan step runs different kinds on different rows of the batch."""
+    if name == "mixed":
+        return [doc for other in KIND_SETS for doc in _case(other)]
+    seed = 4100 + sorted(KIND_SETS).index(name)
+    rng = random.Random(seed)
+    return [_oracle_with_illegal_rows(rows, rng, flavour)
+            for flavour, rows in enumerate(_farm(seed, KIND_SETS[name]))]
+
+
+@pytest.mark.parametrize("k", [1, 3], ids=["vmap", "megastep_k3"])
+@pytest.mark.parametrize("case", [*KIND_SETS, "mixed"])
+def test_op_body_matches_host_oracle(case, k):
+    docs = _case(case)
+    state = _run_batched([rows for rows, _tree, _bits in docs], k)
+    kinds = {int(op[0]) for rows, _t, _b in docs for op, _p in rows}
+    assert {K.INSERT, K.ACK} <= kinds
+    if case in ("all", "mixed"):
+        assert kinds >= {K.NOOP, K.INSERT, K.REMOVE, K.ANNOTATE, K.ACK,
+                         K.OBLITERATE}
+    assert {bits for _r, _t, bits in docs} >= {
+        0, mk.ERR_TEXT_OVERFLOW, mk.ERR_TEXT_OVERFLOW | mk.ERR_POS_RANGE}
+    for d, (_rows, tree, bits) in enumerate(docs):
+        one = jax.tree.map(lambda x, d=d: np.asarray(x[d]), state)
+        assert int(one.error) == bits, (case, d, int(one.error), bits)
+        assert _kernel_content(one) == _oracle_content(tree), (case, d)
+
+
+# ------------------------------------------------- the body's structure
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, jex_core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jex_core.Jaxpr):
+                yield x
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub)
+
+
+def _step_program(flag, n_docs=3):
+    """(every equation of the vmapped ``apply_ops``, those of its scan's
+    body, the scan itself, documents in the batch)."""
+    fleet = _fleet(n_docs)
+    ops = jnp.zeros((n_docs, B, mk.OP_FIELDS), jnp.int32)
+    pays = jnp.zeros((n_docs, B, L), jnp.int32)
+    step = jax.vmap(functools.partial(mk.apply_ops, ob_flag=flag))
+    every = list(_eqns(jax.make_jaxpr(step)(fleet, ops, pays).jaxpr))
+    scans = [e for e in every if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    return every, list(_eqns(scans[0].params["jaxpr"].jaxpr)), scans[0], n_docs
+
+
+@pytest.mark.parametrize("flag", [False, True], ids=["no_ob", "ob"])
+@pytest.mark.parametrize("guard", ["cumsums", "text_pool", "branches"])
+def test_vmapped_scan_body_structure(guard, flag):
+    every, body, scan, n_docs = _step_program(flag)
+    names = [e.primitive.name for e in body]
+    if guard == "cumsums":
+        # One geometry before each of the two splits and one after them.
+        assert names.count("cumsum") <= 3, names.count("cumsum")
+        assert names.count("cumsum") >= 1
+    elif guard == "text_pool":
+        pool = (n_docs, T)
+
+        def touches(e):
+            return any(tuple(getattr(v.aval, "shape", ())) == pool
+                       for v in (*e.invars, *e.outvars))
+
+        # Nothing selects between pools, anywhere in the program ...
+        assert not [e for e in every
+                    if e.primitive.name == "select_n" and touches(e)]
+        # ... the scan neither carries nor touches the pool ...
+        assert not touches(scan)
+        assert not [e for e in body if touches(e)]
+        # ... and one scatter after it is the only write.
+        writes = [e for e in every if touches(e)
+                  and tuple(e.outvars[0].aval.shape) == pool]
+        assert [e.primitive.name for e in writes] == ["scatter"], writes
+    else:
+        # A switch on the row's kind is a ``cond`` in a jaxpr, and one on a
+        # batched predicate would have been turned into selects by vmap: the
+        # program has neither to begin with.
+        assert "cond" not in [e.primitive.name for e in every]
+        assert "switch" not in names
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_one_text_scatter_equals_the_writes_in_order(seed):
+    """``_write_text`` against the rows' writes applied one after another:
+    overlapping starts (a rejected insert leaves ``text_end`` where it was
+    and the next row writes over it), empty rows, rows at the pool's end."""
+    rng = np.random.default_rng(seed)
+    n, width, cap = 24, 8, 96
+    counts = rng.integers(0, width + 1, n)
+    counts[rng.random(n) < 0.3] = 0
+    starts, at = [], 0
+    for c in counts:
+        starts.append(at)
+        if rng.random() < 0.6:          # committed: text_end moves on
+            at += int(c)
+        at = min(at, cap - width)
+    payloads = rng.integers(1, 1000, (n, width)).astype(np.int32)
+    want = np.zeros((cap,), np.int32)
+    for st, c, row in zip(starts, counts, payloads):
+        want[st:st + c] = row[:c]
+    writes = mk._TextWrite(jnp.asarray(starts, jnp.int32),
+                           jnp.asarray(counts, jnp.int32))
+    got = mk._write_text(jnp.zeros((cap,), jnp.int32), writes,
+                         jnp.asarray(payloads))
+    assert np.array_equal(np.asarray(got), want)
+    # No index twice: a scatter may apply its updates in any order.
+    dst = np.asarray(mk._text_write_indices(writes, width, cap)).reshape(-1)
+    kept = dst[dst < cap]
+    assert len(kept) == len(set(kept.tolist()))
+    backwards = np.zeros((cap,), np.int32)
+    for i in reversed(range(dst.size)):
+        if dst[i] < cap:
+            backwards[dst[i]] = payloads.reshape(-1)[i]
+    assert np.array_equal(backwards, want)
+
+
+def test_apply_op_traced_flag_is_one_scalar_cond():
+    """Unbatched callers (host lanes, ``dds/kernel_backend``) pass no flag:
+    the obliterate gate is then one ``lax.cond`` on a scalar around the
+    body, never a switch on the kind."""
+    proto = mk.init_state(16, 2, 2, 64, 2)
+    op = jnp.zeros((mk.OP_FIELDS,), jnp.int32)
+    jaxpr = jax.make_jaxpr(mk.apply_op)(proto, op, jnp.zeros((4,), jnp.int32))
+    conds = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    assert len(conds[0].params["branches"]) == 2
+    assert conds[0].invars[0].aval.shape == ()

@@ -139,3 +139,56 @@ def test_zipf_bucketing_cuts_full_fleet_steps():
     assert bucketed.cohort_lanes <= bucketed.cohort_steps * 4, (
         "cohorts must stay far below fleet width"
     )
+
+
+def test_leaving_the_fleet_wide_regime_takes_two_small_loops():
+    """The first small busy set after a fleet-wide step runs fleet-wide once
+    more, one slice; the second runs as a cohort.  So the tail of a wide
+    burst (its last partial loop) reaches no cohort program it would have
+    to trace, and a fleet that never went wide never pays a wide step."""
+    n_docs = 16
+    svc, expected = drive_docs(n_docs, seed=3)
+    logs = [list(svc.document(f"doc{d}").sequencer.log) for d in range(n_docs)]
+
+    def engine():
+        return DocBatchEngine(
+            n_docs, max_segments=256, text_capacity=4096, max_insert_len=8,
+            ops_per_step=64, use_mesh=False, megastep_k=1,
+        )
+
+    def feed(eng, d, msgs):
+        for msg in msgs:
+            eng.ingest(d, msg)
+        return d in eng._busy
+
+    # Narrow from the start: cohorts only.
+    eng = engine()
+    assert feed(eng, 0, logs[0])
+    eng.step()
+    assert (eng.full_steps, eng.cohort_steps) == (0, 1)
+
+    # Wide, then loops of one busy document each.
+    eng = engine()
+    cut = [len(log) // 2 for log in logs]
+    probe = engine()
+    for d in range(n_docs):
+        feed(probe, d, logs[d][:cut[d]])
+    probe.step()
+    tails = [d for d in range(n_docs) if feed(probe, d, logs[d][cut[d]:])]
+    first, second = tails[:2]
+    assert sum(feed(eng, d, logs[d][:cut[d]]) for d in range(n_docs)) > 4
+    eng.step()
+    assert (eng.full_steps, eng.cohort_steps) == (1, 0)
+    assert feed(eng, first, logs[first][cut[first]:])
+    eng.step()
+    assert (eng.full_steps, eng.cohort_steps) == (2, 0)
+    assert feed(eng, second, logs[second][cut[second]:])
+    eng.step()
+    assert (eng.full_steps, eng.cohort_steps) == (2, 1)
+    for d in range(n_docs):
+        if d not in (first, second):
+            feed(eng, d, logs[d][cut[d]:])
+    eng.step()
+    assert not eng.errors().any()
+    for d in range(n_docs):
+        assert eng.text(d) == expected[d]

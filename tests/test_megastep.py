@@ -285,3 +285,47 @@ def test_megastep_counters_in_health_and_fleet_status():
     th = TreeBatchEngine(2, megastep_k=4).health()
     assert th["megastep_k"] == 4 and "steps_per_dispatch" in th
     assert "staging_aliased_swaps" in h and "staging_aliased_swaps" in th
+
+
+# ------------------------------------------------------------ staging ring
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["one_device", "mesh"])
+def test_staging_ring_never_reuses_memory_an_upload_aliases(meshed):
+    """On a zero-copy backend (the CPU, for a host buffer that happens to be
+    64-byte aligned) an uploaded array reads the staging buffer for as long
+    as it lives, so the ring must hand that memory over instead of refilling
+    it.  Under a mesh the upload is sharded, a sharded array has no pointer
+    of its own, and each shard of a K = 1 upload is a contiguous slice of
+    the buffer: the probe has to look shard by shard (it did not, and
+    ``test_engine_matches_oracle_fleet`` lost ops whenever numpy's allocator
+    handed out such a buffer and the machine was busy: a pending dispatch
+    read a buffer that was being refilled)."""
+    import jax
+    import numpy as np
+
+    from fluidframework_tpu.models.staging import StagingRing
+    from fluidframework_tpu.parallel.mesh import doc_mesh
+
+    def aligned_zeros(shape):
+        n = int(np.prod(shape)) * 4
+        raw = np.zeros(n + 64, np.uint8)
+        start = (-raw.ctypes.data) % 64
+        return raw[start:start + n].view(np.int32).reshape(shape)
+
+    rows = 2 * len(jax.devices())
+    ring = StagingRing(4, rows, 4, 8, 8, depth=1,
+                       mesh=doc_mesh() if meshed else None)
+    buf = ring._bufs[0]
+    buf.ops, buf.payloads = (aligned_zeros(buf.ops.shape),
+                             aligned_zeros(buf.payloads.shape))
+    ops, pays = ring.acquire(1, rows)
+    ops[...] = 7
+    dev = ring.upload(ops, pays)
+    assert all(
+        ops.ctypes.data <= s.data.unsafe_buffer_pointer()
+        < ops.ctypes.data + ops.nbytes
+        for s in dev[0].addressable_shards), "this backend copies: no case"
+    ops2, _pays2 = ring.acquire(1, rows)          # same slot: depth is 1
+    ops2[...] = 9
+    assert ring.aliased_swaps == 1
+    assert np.asarray(dev[0]).min() == 7, "the upload's memory was refilled"

@@ -311,6 +311,7 @@ def _lowered_step_text() -> str:
 
 
 def test_every_apply_op_branch_is_scoped_in_the_lowered_step():
+    # (since PR 25 the "branches" are the kind-specific parts of one body)
     from fluidframework_tpu.models import doc_batch_engine as dbe
     from fluidframework_tpu.ops import mergetree_kernel as mk
 
@@ -320,10 +321,17 @@ def test_every_apply_op_branch_is_scoped_in_the_lowered_step():
     # MLIR carries the scope path as the name of each op's location (inside
     # the scan body's closed call relative to it); XLA joins the parts into
     # the instruction's op_name.
-    for path in (*mk.BRANCH_SCOPES, "insert/ensure_boundary/open_slot",
-                 "remove/mark_range", "annotate/mark_range",
-                 "obliterate/ensure_boundary"):
+    # What every row runs whatever its kind is under its own scope, with the
+    # helpers nested as before; the kinds keep theirs for what only they do.
+    assert mk.SHARED_SCOPE == "shared"
+    for path in (*mk.BRANCH_SCOPES, "shared",
+                 "shared/ensure_boundary/open_slot", "shared/mark_range",
+                 "insert/open_slot"):
         assert re.search(rf'loc\("(?:[^"]*/)?{path}(?:/[^"]*)?"', text), path
+    # No kind's scope encloses the shared phase: a trace reads it as no
+    # kind's time, not as the first kind's that happens to call a helper.
+    for kind in mk.BRANCH_SCOPES:
+        assert not re.search(rf'loc\("(?:[^"]*/)?{kind}/ensure_boundary', text)
     # The gather is a named program, not jit__lambda.
     assert dbe._gather_cohort_jit.__name__ == "_gather_cohort_jit"
 
