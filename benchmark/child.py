@@ -1,6 +1,6 @@
 """The process half of a run, the same for every family: the child that owns
-the chip, with every stdout line stamped on arrival (the apply-lag clock), and
-the gated burst.
+the chip, with every stdout line stamped on arrival (a status line's arrival
+is the proof of the step stamps it carries, ``lag.py``), and the gated burst.
 
 ``Child`` and ``Gate`` are copies of ``chip_smoke.py``'s (PR 21, sound on the
 chip): the program may change later, the yardstick may not.  The writers, the

@@ -2,7 +2,9 @@
 BENCHMARK.json.  A reader declares NAME, UNIT, LAYER, MOVES and READS, and
 ``read(ctx)`` returns the value, or None where there is nothing to read (the
 harness then leaves the metric out of the line).  ``ctx`` is what run.py
-gathered: ``status``/``parsed`` (stamped status lines), ``groups`` (flushes),
+gathered: ``stamps`` (``(t_applied, rows, t_seen)`` per step whose status line
+arrived), ``status`` (the same as ``(time, rows)``), ``parsed`` (the window's
+status lines by arrival), ``groups`` (flushes),
 ``late``, ``w0``/``w1`` (the window, perf_counter seconds), ``ready``, and
 ``traced`` (traces.reduce_run: ``flight`` spans, ``modules``, ``busy_s`` ...).
 """

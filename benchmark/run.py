@@ -17,6 +17,11 @@ chip: ``benchmark/fleet_child.py``, which calls
 3. After the window (untimed): send nothing, wait for the child's ``done``
    line, compare device texts, writers and the host oracle.
 
+Apply lag is measured to the stamp the child takes when the step that applied
+an op returns, and takes the status line that carried the stamp as its proof
+(``lag.py``); the same runs reduced by the lines' arrival times are kept under
+the result's ``bench`` key, as evidence and never as a metric.
+
 The last line of stdout is the result; README.md has the layout and PERF.md
 the reasons.  ``--rehearse-cpu`` is the toy-size CPU rehearsal (platform
 ``cpu``, never a result); ``--sweep`` cuts the window into one segment per
@@ -137,6 +142,13 @@ def _rlimit(n_docs: int) -> None:
 
 def run(args) -> tuple[int, dict | None]:
     t_start = time.perf_counter()
+    # The child stamps with perf_counter too; a stamp and an arrival compare
+    # only where that is one clock for every process of the machine.
+    clock = time.get_clock_info("perf_counter")
+    if not lagmod.clock_is_shared(clock):
+        raise BenchFailure(
+            f"perf_counter is {clock.implementation}, not CLOCK_MONOTONIC: "
+            "the child's stamps and this process's clock do not compare")
     spec = load_cell(args.workload)
     cell, config, params = spec["cell"], spec["config"], dict(spec["params"])
     own = spec["own"]
@@ -219,6 +231,11 @@ def run(args) -> tuple[int, dict | None]:
             os.makedirs(profile_dir)
             cmd += ["--profile-dir", profile_dir,
                     "--profile-seconds", str(trace_s)]
+        if args.plant_fault:
+            # The control: one op altered half way through the window, where
+            # the child feeds it to the engine.
+            cmd += ["--plant-fault",
+                    f"{args.plant_fault}:{planned - planned_win // 2}"]
         cmd += ["--", "--port", str(plant.port),
                 "--docs", ",".join(plant.doc_ids),
                 "--exit-after-rows", str(planned),
@@ -319,34 +336,53 @@ def run(args) -> tuple[int, dict | None]:
         plant.stop()
 
     # ---------------------------------------------------------------- reduce
-    status = []          # (arrival, rows) of every status line
+    lines = []           # (arrival, rows, applied, dropped) per status line
     parsed = []          # (arrival, dict) of those inside the window
     for t, line in child.lines:
         r = status_rows(line)
         if r is None:
             continue
-        status.append((t, r))
+        obj = json.loads(line)
+        lines.append((t, r, obj.get("applied"), obj.get("applied_dropped")))
         if w0 - 1.0 <= t <= w1 + 1.0:
-            parsed.append((t, json.loads(line)))
+            parsed.append((t, obj))
+    try:
+        stamps = lagmod.stamps_of(lines)
+    except lagmod.StampError as e:
+        raise BenchFailure(f"the status stream's stamps: {e}") from None
+    status = [(t, r) for t, r, _seen in stamps]      # the proofs, by stamp
+    by_line = [(t, r) for t, r, _a, _d in lines]     # the old reduction
     win_groups = stream.groups[n_warm_groups:]
-    lags, unapplied = lagmod.match_lags(
-        [(g[0], g[2], g[3]) for g in win_groups], status, give_up)
+    op_groups = [(g[0], g[2], g[3]) for g in win_groups]
+    lags, unapplied = lagmod.match_lags(op_groups, status, give_up)
+    line_lags, _ = lagmod.match_lags(op_groups, by_line, give_up)
     failed = plant.nacks + unapplied
     late = [g[1] - g[0] for g in win_groups for _ in range(g[3])]
     setup_s = w0 - t_start
 
     correct, why = False, "no done line"
     check: dict = {}
+    lanes: dict = {}
     if final is not None:
         h = final["health"]
         lanes = {k: h.get(k, 0) for k in (
             "quarantined_docs", "oracle_docs", "overflow_docs",
             "ingest_fallback_msgs")}
+        # Compared first: every document offered its cap; the comparison is
+        # a sample past those, so a control looks where its fault is.
+        first = [d for d in range(n_docs)
+                 if params.get("cap_ops_per_s")
+                 and rates[d] >= float(params["cap_ops_per_s"]) - 1e-9]
+        if args.plant_fault:
+            said = [json.loads(line) for _t, line in child.lines
+                    if line.startswith('{"planted"')]
+            if not said:
+                raise BenchFailure("the control's fault was never planted")
+            if said[0]["doc"] not in first:
+                first.insert(0, said[0]["doc"])
         check = plant.verify(
             final, [plant.doc_ids[d] for d in touched],
-            [plant.doc_ids[d] for d in range(n_docs)
-             if params.get("cap_ops_per_s")
-             and rates[d] >= float(params["cap_ops_per_s"]) - 1e-9],
+            [plant.doc_ids[d] for d in first],
             args.seed, VERIFY_BUDGET_S, VERIFY_MIN_SAMPLE)
         problems = [f"{k} = {v}" for k, v in lanes.items() if v]
         if not check["ok"]:
@@ -373,7 +409,8 @@ def run(args) -> tuple[int, dict | None]:
 
     ctx = {
         "spec": spec, "n_docs": n_docs, "w0": w0, "w1": w1,
-        "status": status, "parsed": parsed, "groups": win_groups,
+        "status": status, "stamps": stamps, "parsed": parsed,
+        "groups": win_groups,
         "late": late, "ready": ready, "final": final, "tail": tail,
         "flight_path": fr_path if args.trace else None,
         "profile_dir": profile_dir if args.trace else None,
@@ -411,6 +448,32 @@ def run(args) -> tuple[int, dict | None]:
                           for m in spec["end_to_end"]
                           if m["name"] in values}
     out["device"] = device
+    in_win = [(t, a) for t, _r, a, _d in lines if w0 <= t <= w1]
+    out["bench"] = {
+        "lag_by_line_p50_ms": (lagmod.percentile(line_lags, 0.5) * 1e3
+                               if line_lags else None),
+        "lag_by_line_p95_ms": (lagmod.percentile(line_lags, 0.95) * 1e3
+                               if line_lags else None),
+        "lines_in_window": len(in_win),
+        "stamps_in_window": sum(len(a) for _t, a in in_win),
+        # Where in the generator's tick the lines that carried a proof
+        # arrived (circular mean): the old reduction's level follows it.
+        "line_phase_in_tick_ms": lagmod.phase_in_period(
+            [t - t0 for t, a in in_win if a], tick_s),
+    }
+    if args.rehearse_cpu:
+        out["rehearsal"] = True
+    if args.plant_fault:
+        out["planted_fault"] = args.plant_fault     # a control, not a result
+    # Each number `correct` compared, beside its limit; last in the line.
+    out["checks"] = {k: [v, 0] for k, v in {
+        "docs_differ": 0 if check.get("ok") else 1,
+        "host_lane_docs": sum(lanes.values()),
+        "device_errors": (final or {}).get("errors", 0),
+        "nacks": plant.nacks,
+        "unapplied_ops": unapplied,
+        "rows_short": planned - (final or {}).get("rows", 0),
+    }.items()}
 
     report = {
         "workload": args.workload, "seed": args.seed, "trace": args.trace,
@@ -434,6 +497,13 @@ def run(args) -> tuple[int, dict | None]:
         "health_at_end": (final or {}).get("health"),
         "traced": {k: v for k, v in ctx.get("traced", {}).items()
                    if k not in ("gaps", "flight", "span_stats")},
+        # Seconds since the window opened: what a look at one tick needs.
+        "window_stamps": [[seen - w0, t - w0, r] for t, r, seen in stamps
+                          if w0 - 1.0 <= t <= w1 + 1.0],
+        "window_lines": [[t - w0, r] for t, r in by_line
+                         if w0 - 1.0 <= t <= w1 + 1.0],
+        "window_flushes": [[g[0] - w0, g[1] - w0, g[2], g[3]]
+                           for g in win_groups],
         "result": out,
     }
     if args.report:
@@ -445,8 +515,6 @@ def run(args) -> tuple[int, dict | None]:
         "end_to_end", "per_layer", "segments", "setup_split_s",
         "compile_at_ready", "compile_at_end", "lag_samples")},
         default=float))
-    if args.rehearse_cpu:
-        out["rehearsal"] = True
     return 0, out
 
 
@@ -473,7 +541,7 @@ def _segments(ctx, seg_rates, seg_s, win_groups, lags, groups, base):
                 shapes[key] = shapes.get(key, 0) + 1
         h0 = inside[0]["health"] if inside else {}
         h1 = inside[-1]["health"] if inside else {}
-        gaps = lagmod.advancing_gaps(ctx["status"], a, b)
+        loops = lagmod.seen_to_applied(ctx["stamps"], a, b)
         rows.append({
             "rate_ops_per_s": rate, "ops": len(sel),
             "lag_p50_ms": lagmod.percentile(seg_lags, 0.5) * 1e3,
@@ -483,8 +551,8 @@ def _segments(ctx, seg_rates, seg_s, win_groups, lags, groups, base):
             "backlog_slope_ops_per_s": _slope(backlog),
             "backlog_at_end": backlog[-1][1] if backlog else None,
             "applied_ops_per_s": lagmod.applied_rate(ctx["status"], a, b),
-            "loop_ms_p50": (lagmod.percentile(gaps, 0.5) * 1e3
-                            if gaps else None),
+            "loop_ms_p50": (lagmod.percentile(loops, 0.5) * 1e3
+                            if loops else None),
             "cohort_steps": h1.get("cohort_steps", 0) - h0.get(
                 "cohort_steps", 0),
             "full_steps": h1.get("full_steps", 0) - h0.get("full_steps", 0),
@@ -527,6 +595,10 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--sweep", default=None,
                    help="knee sweep: comma-separated rates; the window is "
                         "one segment of --seconds per rate, in this order")
+    p.add_argument("--plant-fault", choices=("alter_op",), default=None,
+                   help="the control of `correct`: the child alters one op "
+                        "where it feeds it to the engine; the run has to "
+                        "read correct: false (never a result)")
     p.add_argument("--report", default=None,
                    help="write everything the run learned to this file")
     args = p.parse_args(argv)
@@ -538,6 +610,9 @@ def main(argv: list[str] | None = None) -> int:
         print(f"benchmark: FAILED - {e}", file=sys.stderr)
         return 1
     assert "jax" not in sys.modules, "the benchmark's parent imported JAX"
+    for name, (value, limit) in out["checks"].items():
+        print(f"check {name} = {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return rc
 
