@@ -783,6 +783,7 @@ def apply_marks(nodes: list[Node], marks: list[Mark]) -> None:
         return
     out: list = []
     registers: dict[int, dict[int, Node]] = {}  # id -> {original offset: node}
+    moved_in = False
     pos = 0
     for m in marks:
         if isinstance(m, Skip):
@@ -802,12 +803,18 @@ def apply_marks(nodes: list[Node], marks: list[Mark]) -> None:
             pos += m.count
         elif isinstance(m, MoveIn):
             out.append(_MoveRegister(m.id, m.count, m.offset))
+            moved_in = True
         else:
             apply_node_change(nodes[pos], m.change)
             out.append(nodes[pos])
             pos += 1
     assert pos <= len(nodes), "marks walk past end of field"
     out.extend(nodes[pos:])
+    if not moved_in:
+        # No placeholder to patch: the output is the field (an insert or a
+        # remove in a field of n nodes costs slices, not a walk over n).
+        nodes[:] = out
+        return
     resolved: list[Node] = []
     for item in out:
         if isinstance(item, _MoveRegister):

@@ -212,7 +212,7 @@ _POOLED_VKINDS = tuple(int(p) for p in tk._POOLED)
 # instances (keyed by input shapes), instead of per-instance jit closures.
 
 _tree_step_jit = functools.partial(jax.jit, donate_argnums=(0,))(
-    jax.vmap(tk.apply_nested_ops)
+    tk.apply_nested_fleet
 )
 _tree_megastep_jit = functools.partial(jax.jit, donate_argnums=(0,))(
     tk.apply_nested_megastep
@@ -324,7 +324,10 @@ class TreeBatchEngine:
         from ..native import ingest_native as _ingest_native
 
         _ingest_native.warm()
-        self.counters = HealthCounters(telemetry)
+        # tree_compactions is in every health line, 0 included: its
+        # window delta is a metric (fleet-wide compacts on the serving
+        # thread).
+        self.counters = HealthCounters(telemetry, tree_compactions=0)
         # Interning tables shared by the fleet; ROOT_FIELD must be id 0
         # (the virtual root's field in the kernel's materializer).
         self._fields: dict[str, int] = {ROOT_FIELD: 0}
@@ -480,7 +483,7 @@ class TreeBatchEngine:
         raises through the Python decode (which owns error semantics) —
         per-document isolation, other docs' feeds are untouched.  Returns
         op rows staged (applied edits for fallback-routed docs)."""
-        with self.ckpt_lock:
+        with self.ckpt_lock, span("ingest", doc=doc_idx, bytes=len(data)):
             return self._ingest_lines(doc_idx, data)
 
     def _ingest_lines(self, doc_idx: int, data: bytes) -> int:
@@ -954,6 +957,36 @@ class TreeBatchEngine:
             written.append(r)
         return written
 
+    def _compact_fleet(self) -> None:
+        """One fleet-wide ``tree_compact`` on the serving thread, then the
+        one readback that re-syncs the host's row and pool-word upper
+        bounds to the true live counts."""
+        with span("compact", docs=self.n_docs):
+            self.counters.bump("tree_compactions")
+            self.state = self._compact(self.state)
+            # Resync = live rows/words (applied) + the counts still in
+            # each doc's queue (unapplied) — dropping the queued part
+            # would let a long churn stream overflow mid-step without
+            # ever re-triggering compaction.
+            queued_pairs = [self._queued_upper(h) for h in self.hosts]
+            queued = np.array([q for q, _w in queued_pairs], np.int64)
+            queued_words = np.array([w for _q, w in queued_pairs], np.int64)
+            # Fallback docs keep stale live rows on device (nothing
+            # compacts them away); excluding them here keeps the reset
+            # in _route_to_fallback effective — otherwise one resync
+            # resurrects an above-threshold watermark that no
+            # compaction can ever lower, and the fleet compacts on
+            # every batch forever.
+            active = np.array(
+                [d not in self.fallbacks for d in range(self.n_docs)]
+            )
+            nrow = np.asarray(self.state.nrow)[self._slot].astype(np.int64)
+            words = np.asarray(self.state.pool_end)[self._slot].astype(
+                np.int64
+            )
+            self._rows_upper = np.where(active, nrow + queued, 0)
+            self._pool_upper = np.where(active, words + queued_words, 0)
+
     def step(self) -> int:
         """Apply everything staged as batched device megasteps.  Holds
         ``ckpt_lock`` end to end (the background checkpoint writer only
@@ -967,7 +1000,8 @@ class TreeBatchEngine:
         # Cadence checkpoints after the serving lock releases (same
         # contract as DocBatchEngine.step): the durable fsyncs must not
         # run while every ingest contender queues on ckpt_lock.
-        self.maybe_checkpoint()
+        with span("housekeeping", kind="checkpoint"):
+            self.maybe_checkpoint()
         return steps
 
     def _step_fleet(self) -> int:
@@ -983,49 +1017,18 @@ class TreeBatchEngine:
                 or self._pool_upper.max()
                 > self.pool_capacity * self.COMPACT_FRACTION
             ):
-                self.state = self._compact(self.state)
-                # Resync = live rows/words (applied) + the counts still in
-                # each doc's queue (unapplied) — dropping the queued part
-                # would let a long churn stream overflow mid-step without
-                # ever re-triggering compaction.
-                queued_pairs = [self._queued_upper(h) for h in self.hosts]
-                queued = np.array([q for q, _w in queued_pairs], np.int64)
-                queued_words = np.array(
-                    [w for _q, w in queued_pairs], np.int64
-                )
-                # Fallback docs keep stale live rows on device (nothing
-                # compacts them away); excluding them here keeps the reset
-                # in _route_to_fallback effective — otherwise one resync
-                # resurrects an above-threshold watermark that no
-                # compaction can ever lower, and the fleet compacts on
-                # every batch forever.
-                active = np.array(
-                    [d not in self.fallbacks for d in range(self.n_docs)]
-                )
-                self._rows_upper = np.where(
-                    active,
-                    np.asarray(self.state.nrow)[self._slot].astype(
-                        np.int64
-                    )
-                    + queued,
-                    0,
-                )
-                self._pool_upper = np.where(
-                    active,
-                    np.asarray(self.state.pool_end)[self._slot].astype(
-                        np.int64
-                    )
-                    + queued_words,
-                    0,
-                )
+                self._compact_fleet()
             busy = sorted(self._busy)
-            K = self._select_k(busy)
-            stage = self._staging()
-            ops, payloads = stage.acquire(K, self.fleet_capacity)
-            for k in range(K):
-                stage.mark(k, self._drain_into(busy, ops[k], payloads[k]))
-                if k + 1 < K:
-                    busy = [d for d in busy if d in self._busy]
+            with span("pack", kind="tree", docs=len(busy)):
+                K = self._select_k(busy)
+                stage = self._staging()
+                ops, payloads = stage.acquire(K, self.fleet_capacity)
+                for k in range(K):
+                    stage.mark(
+                        k, self._drain_into(busy, ops[k], payloads[k])
+                    )
+                    if k + 1 < K:
+                        busy = [d for d in busy if d in self._busy]
             if self.mesh is None and K == 1:
                 dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
                 with span("dispatch", kind="tree", k=K):
@@ -1045,7 +1048,8 @@ class TreeBatchEngine:
             steps += K
             self.counters.bump("megastep_dispatches")
             self.counters.bump("megastep_slices", K)
-        self.recompile_watchdog.poll()
+        with span("housekeeping"):
+            self.recompile_watchdog.poll()
         if self.mesh is not None:
             # Per-shard latch reduce: one scalar readback instead of a
             # cross-mesh [D] error gather on every step.
@@ -1055,15 +1059,17 @@ class TreeBatchEngine:
                 return steps
         with span("readback", kind="error_vector"):
             err = np.asarray(self.state.error)
-        for d in range(self.n_docs):
-            s = int(self._slot[d])
-            if err[s] and d not in self.fallbacks:
-                # Capacity/range overflow on device: replay on the host.
-                self._route_to_fallback(d)
-                self.counters.bump("fallback_routes")
-                self.state = self.state._replace(
-                    error=self.state.error.at[s].set(0)
-                )
+        # The host part: walk the vector.
+        with span("recover"):
+            for d in range(self.n_docs):
+                s = int(self._slot[d])
+                if err[s] and d not in self.fallbacks:
+                    # Capacity/range overflow on device: replay on the host.
+                    self._route_to_fallback(d)
+                    self.counters.bump("fallback_routes")
+                    self.state = self.state._replace(
+                        error=self.state.error.at[s].set(0)
+                    )
         return steps
 
     # ------------------------------------------------------------- checkpoint
@@ -1438,11 +1444,22 @@ class TreeBatchEngine:
 
     def tree_json(self, doc_idx: int) -> list[dict]:
         """The document's root field as forest JSON (Node.to_json shape)."""
+        return self._doc_json(self.state, doc_idx, *self._name_tables())
+
+    def trees_json(self) -> list[list[dict]]:
+        """Every document's root field as forest JSON, in doc order: ONE
+        readback of the fleet's columns and a host walk per document (the
+        drain line's surface; ``tree_json`` slices one document on the
+        device, thirteen small programs and transfers a document)."""
+        host = jax.device_get(self.state)
+        names = self._name_tables()
+        return [self._doc_json(host, d, *names) for d in range(self.n_docs)]
+
+    def _doc_json(self, state, doc_idx: int, field_names, type_names):
         if doc_idx in self.fallbacks:
             return [n.to_json() for n in self.fallbacks[doc_idx].root_field]
         slot = int(self._slot[doc_idx])
-        st = jax.tree.map(lambda x: x[slot], self.state)
-        field_names, type_names = self._name_tables()
+        st = jax.tree.map(lambda x: x[slot], state)
         return tk.nested_to_json(st, field_names, type_names)
 
     def values(self, doc_idx: int) -> list:

@@ -454,18 +454,31 @@ def _sibling_mask(s: NestedForestState, parent, fld):
     return (s.alive == 1) & (s.parent == parent) & (s.field_id == fld)
 
 
-def _kill_with_descendants(s: NestedForestState, target) -> jnp.ndarray:
-    """Alive column with ``target`` rows dead and death propagated down
-    the parent chain.  Tree depth through this kernel is bounded by
+def _close_deaths(parent: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
+    """Death propagated down the parent chain of ONE document: a row whose
+    parent is dead dies.  Tree depth through this kernel is bounded by
     MAX_PATH + 1 (the deepest addressable field), so a static unroll
-    covers every level."""
-    N = s.parent.shape[0]
-    alive = jnp.where(target, 0, s.alive)
+    covers every level.  Each level is an element-wise gather over the
+    rows, which the TPU runs one element at a time (43 ms for 256 x 16,384
+    rows on a v5e): the fleet step runs it for the documents that killed
+    rows and for no others (``_propagate_deaths``)."""
+    N = parent.shape[0]
+    pk = jnp.clip(parent, 0, N - 1)
     for _ in range(MAX_PATH + 1):
-        pk = jnp.clip(s.parent, 0, N - 1)
-        parent_dead = (s.parent >= 0) & (alive[pk] == 0)
+        parent_dead = (parent >= 0) & (alive[pk] == 0)
         alive = jnp.where(parent_dead, 0, alive)
     return alive
+
+
+@jax.named_scope("kill_descendants")
+def _kill_with_descendants(
+    s: NestedForestState, target, propagate: bool = True
+) -> jnp.ndarray:
+    """Alive column with ``target`` rows dead and, with ``propagate``,
+    their descendants too.  Without it the caller owes the document a
+    ``_close_deaths`` before the next op reads ``alive``."""
+    alive = jnp.where(target, 0, s.alive)
+    return _close_deaths(s.parent, alive) if propagate else alive
 
 
 def _fresh_run(
@@ -481,7 +494,16 @@ def _fresh_run(
     idx = jnp.arange(N, dtype=I32)
     fresh = (idx >= s.nrow) & (idx < s.nrow + count)
     j = idx - s.nrow
-    pay = payload[jnp.clip(j, 0, payload.shape[0] - 1)]
+    # payload[clip(j)] as compares over the payload's lanes: a gather over
+    # the rows runs one element at a time on the TPU, this is one fused
+    # pass (L compares a row; the reason to keep --max-insert-len small).
+    L = payload.shape[0]
+    jc = jnp.clip(j, 0, L - 1)
+    pay = jnp.sum(
+        jnp.where(jc[:, None] == jnp.arange(L, dtype=I32)[None, :],
+                  payload[None, :], 0),
+        axis=1, dtype=I32,
+    )
     pooled = _is_pooled(vkind)
     inline = (vkind == VKIND_INT) | (vkind == VKIND_BOOL)
     row_val = jnp.where(pooled, s.pool_end, jnp.where(inline, pay, 0))
@@ -501,17 +523,32 @@ def _fresh_run(
     )
 
 
+# One ``jax.named_scope`` per part of the nested op body, so that a device
+# trace says where a step's time goes (the scopes change no instruction).
+# ``resolve`` is what every row runs before its kind matters: the path walk
+# to the parent and the sibling mask.  ``pool_write`` nests under the kind
+# that appends words, ``kill_descendants`` under remove and replace_field.
+NESTED_SCOPES = (
+    "resolve", "insert", "remove", "set_value", "move", "replace_field",
+    "pool_write", "kill_descendants",
+)
+
+
 def apply_nested_op(
-    s: NestedForestState, op: jnp.ndarray, payload: jnp.ndarray
+    s: NestedForestState, op: jnp.ndarray, payload: jnp.ndarray,
+    propagate: bool = True,
 ) -> NestedForestState:
+    """One op on one document.  ``propagate=False`` kills a remove's (or a
+    replaced field's) own rows and leaves their descendants to the caller
+    (``apply_nested_fleet`` closes them per document)."""
     kind, seq = op[0], op[1]
     fld, pos, count, dst = op[_TGT], op[_TGT + 1], op[_TGT + 2], op[_TGT + 3]
     value, vkind, ntype = op[_TGT + 4], op[_TGT + 5], op[_TGT + 6]
     N = s.parent.shape[0]
-    idx = jnp.arange(N, dtype=I32)
-    parent, okp = _resolve_parent(s, op)
-    sib = _sibling_mask(s, parent, fld)
-    n_sib = jnp.sum(sib.astype(I32))
+    with jax.named_scope("resolve"):
+        parent, okp = _resolve_parent(s, op)
+        sib = _sibling_mask(s, parent, fld)
+        n_sib = jnp.sum(sib.astype(I32))
 
     def fail(s, over, bad, pool_over=False):
         return s._replace(
@@ -528,6 +565,7 @@ def apply_nested_op(
     P = s.pool.shape[0]
     W = payload.shape[0]
 
+    @jax.named_scope("pool_write")
     def _pool_append(s):
         """Append payload[:wlen] to the pool; returns (pool, over)."""
         over = s.pool_end + wlen > P
@@ -538,6 +576,7 @@ def apply_nested_op(
     def do_noop(s):
         return s
 
+    @jax.named_scope("insert")
     def do_insert(s):
         over = s.nrow + count > N
         bad = ~okp | (pos > n_sib)
@@ -556,16 +595,18 @@ def apply_nested_op(
             None,
         )
 
+    @jax.named_scope("remove")
     def do_remove(s):
         bad = ~okp | (pos + count > n_sib)
         target = sib & (s.index >= pos) & (s.index < pos + count)
-        alive = _kill_with_descendants(s, target)
+        alive = _kill_with_descendants(s, target, propagate)
         closed = jnp.where(sib & (s.index >= pos + count), s.index - count, s.index)
         out = s._replace(alive=alive, index=closed)
         return jax.lax.cond(
             ~bad, lambda _: out, lambda _: fail(s, False, bad), None
         )
 
+    @jax.named_scope("set_value")
     def do_set(s):
         hit = sib & (s.index == pos)
         bad = ~okp | ~jnp.any(hit)
@@ -586,6 +627,7 @@ def apply_nested_op(
             None,
         )
 
+    @jax.named_scope("replace_field")
     def do_replace_field(s):
         # The optional-kind whole-content set: clear the field (subtree
         # kill like REMOVE over every sibling), then insert the fresh run
@@ -593,7 +635,7 @@ def apply_nested_op(
         over = s.nrow + count > N
         bad = ~okp
         pool, pool_over = _pool_append(s)
-        alive = _kill_with_descendants(s, sib)
+        alive = _kill_with_descendants(s, sib, propagate)
         out = _fresh_run(
             s, count=count, parent=parent, fld=fld,
             indices=lambda j: j, seq=seq, vkind=vkind, ntype=ntype,
@@ -607,6 +649,7 @@ def apply_nested_op(
             None,
         )
 
+    @jax.named_scope("move")
     def do_move(s):
         # Contiguous same-field block [pos, pos+count) to boundary dst,
         # both in input coordinates: pure sibling-index rewrites.
@@ -645,11 +688,63 @@ def apply_nested_ops(
     return out
 
 
+@jax.named_scope("kill_descendants")
+def _propagate_deaths(s: NestedForestState, killed: jnp.ndarray):
+    """``_close_deaths`` for the documents of a [D, ...] batch that
+    ``killed`` [D] names, one after the other, and for no others: a slot's
+    removes are a document or two of a fleet, and the gathers cost by the
+    row."""
+    order = jnp.argsort(~killed, stable=True)      # those documents first
+
+    def one(i, alive_all):
+        d = order[i]
+        parent = jax.lax.dynamic_index_in_dim(s.parent, d, keepdims=False)
+        alive = jax.lax.dynamic_index_in_dim(alive_all, d, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            alive_all, _close_deaths(parent, alive), d, 0)
+
+    alive = jax.lax.fori_loop(
+        0, jnp.sum(killed.astype(I32)), one, s.alive)
+    return s._replace(alive=alive)
+
+
+def apply_nested_fleet(
+    s: NestedForestState, ops: jnp.ndarray, payloads: jnp.ndarray
+) -> NestedForestState:
+    """Apply a [D, B] op batch to a [D, ...] forest batch: op slot by op
+    slot, every document's op of a slot at once.  Bit-identical to
+    ``vmap(apply_nested_ops)`` (documents do not see each other), and it
+    lets two decisions be taken for the fleet instead of computed for every
+    row: a slot in which no document has an op is skipped (a steady step
+    fills a slot or two of B), and the descendants of killed rows are
+    looked up for the documents that killed some."""
+    step = jax.vmap(lambda st, o, p: apply_nested_op(st, o, p, False))
+
+    def slot(st: NestedForestState, xs):
+        o, p = xs                                   # [D, F], [D, L]
+        kind = o[:, 0]
+
+        def live(st):
+            out = step(st, o, p)
+            killed = (kind == NestedOpKind.REMOVE) | (
+                kind == NestedOpKind.REPLACE_FIELD)
+            return jax.lax.cond(
+                jnp.any(killed),
+                lambda x: _propagate_deaths(x, killed), lambda x: x, out)
+
+        busy = jnp.any(kind != NestedOpKind.NOOP)
+        return jax.lax.cond(busy, live, lambda x: x, st), None
+
+    out, _ = jax.lax.scan(
+        slot, s, (jnp.swapaxes(ops, 0, 1), jnp.swapaxes(payloads, 0, 1)))
+    return out
+
+
 def apply_nested_megastep(
     s: NestedForestState, ops: jnp.ndarray, payloads: jnp.ndarray
 ) -> NestedForestState:
     """Apply a [K, D, B] op ring to a [D, ...] forest batch in ONE fused
-    program (``lax.scan`` over K slices of ``vmap(apply_nested_ops)``) —
+    program (``lax.scan`` over K slices of ``apply_nested_fleet``) —
     the tree engine's megastep dispatch amortizer.  Bit-identical to K
     sequential batched dispatches: slices apply in order against the
     carried state, and error/overflow bits latch on device for a single
@@ -657,12 +752,13 @@ def apply_nested_megastep(
 
     def body(st: NestedForestState, xs):
         o, p = xs
-        return jax.vmap(apply_nested_ops)(st, o, p), None
+        return apply_nested_fleet(st, o, p), None
 
     out, _ = jax.lax.scan(body, s, (ops, payloads))
     return out
 
 
+@jax.named_scope("compact")
 def compact_nested(s: NestedForestState) -> NestedForestState:
     """Drop dead rows: stable gather of live rows to the prefix plus a
     parent-id remap — trivial BECAUSE ordering lives in the index columns,

@@ -508,8 +508,7 @@ def main(argv: list[str] | None = None) -> int:
     def final_state() -> dict:
         """The per-family identity surface for the done=True status line."""
         if args.family == "tree":
-            return {"trees": {d: eng.tree_json(i)
-                              for i, d in enumerate(doc_ids)}}
+            return {"trees": dict(zip(doc_ids, eng.trees_json()))}
         return {"texts": dict(zip(doc_ids, eng.texts()))}
 
     terminated = False
