@@ -356,6 +356,12 @@ class _NewSeg(NamedTuple):
     prop_vals: tuple
 
 
+def _columns(s: DocState) -> _NewSeg:
+    """The per-segment columns of ``s``, under the names a new segment's
+    values go by."""
+    return _NewSeg(*(getattr(s, name) for name in _NewSeg._fields))
+
+
 @jax.named_scope("open_slot")
 def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
     """Conditionally (``do``) shift all per-segment arrays right at ``k`` and
@@ -363,21 +369,12 @@ def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
     S = s.seg_len.shape[0]
     overflow = do & (s.nseg >= S)
     do = do & ~overflow
-
-    def sh(arr, newval):
-        return jnp.where(do, _shift_right(arr, k, newval), arr)
-
+    cols = jax.tree.map(
+        lambda arr, newval: jnp.where(do, _shift_right(arr, k, newval), arr),
+        _columns(s), new,
+    )
     return s._replace(
-        seg_start=sh(s.seg_start, new.seg_start),
-        seg_len=sh(s.seg_len, new.seg_len),
-        ins_key=sh(s.ins_key, new.ins_key),
-        ins_client=sh(s.ins_client, new.ins_client),
-        seg_uid=sh(s.seg_uid, new.seg_uid),
-        seg_obpre=sh(s.seg_obpre, new.seg_obpre),
-        rem_keys=tuple(sh(a, v) for a, v in zip(s.rem_keys, new.rem_keys)),
-        rem_clients=tuple(sh(a, v) for a, v in zip(s.rem_clients, new.rem_clients)),
-        prop_keys=tuple(sh(a, v) for a, v in zip(s.prop_keys, new.prop_keys)),
-        prop_vals=tuple(sh(a, v) for a, v in zip(s.prop_vals, new.prop_vals)),
+        **cols._asdict(),
         nseg=s.nseg + do.astype(I32),
         error=s.error | jnp.where(overflow, ERR_SEG_OVERFLOW, 0),
     )
@@ -392,46 +389,120 @@ def _geometry(s: DocState, ref_seq, client):
 
 
 @jax.named_scope("ensure_boundary")
-def _ensure_boundary(s: DocState, geom, pos, gate) -> DocState:
-    """Under ``gate``, split the segment containing ``pos`` strictly inside
-    it, if any.  ``geom`` is ``_geometry`` of ``s`` from the op's perspective.
+def _ensure_boundaries(
+    s: DocState, geom, cut1, gate1, cut2, gate2
+) -> DocState:
+    """Under each cut's gate, split the segment that holds it strictly
+    inside, if any: cut 1, then cut 2 in the document cut 1 leaves.  ``geom``
+    is ``_geometry`` of ``s`` from the op's perspective.  Both cuts are
+    planned from ``geom`` and their (up to two) slots opened in ONE rewrite
+    of the per-segment columns.
 
     Mirrors the reference's split-on-walk (ensureIntervalBoundary /
-    insertingWalk split path): after this, ``pos`` falls on a segment
-    boundary of the perspective-visible sequence.  Obliterate anchors on the
+    insertingWalk split path): after this, each cut falls on a segment
+    boundary of the perspective-visible sequence.  Obliterate anchors on a
     split segment follow the half holding their endpoint char: Before sides
     keep the left half's uid, After sides move to the right half.
+
+    A split that finds the document full (``nseg == S``) latches
+    ERR_SEG_OVERFLOW and opens no slot, but its left half is trimmed, its
+    uid spent and its anchors moved all the same (native/megastep.cpp
+    mirrors that; the host grows the document and replays).
     """
     vis, vlen, excl = geom
-    mid = vis & (excl < pos) & (pos < excl + vlen)
-    k = _first_true(mid, jnp.asarray(0, I32))  # default unused when ~do
-    do = gate & jnp.any(mid)
-    off = pos - excl[k]
-    old_uid = s.seg_uid[k]
-    right_uid = s.uid_next
-    right = _NewSeg(
-        seg_start=s.seg_start[k] + off,
-        seg_len=s.seg_len[k] - off,
-        ins_key=s.ins_key[k],
-        ins_client=s.ins_client[k],
-        seg_uid=right_uid,
-        seg_obpre=s.seg_obpre[k],
-        rem_keys=tuple(a[k] for a in s.rem_keys),
-        rem_clients=tuple(a[k] for a in s.rem_clients),
-        prop_keys=tuple(a[k] for a in s.prop_keys),
-        prop_vals=tuple(a[k] for a in s.prop_vals),
+    S = s.seg_len.shape[0]
+    idx = jnp.arange(S, dtype=I32)
+    cols = _columns(s)
+
+    def holder(pos):
+        mid = vis & (excl < pos) & (pos < excl + vlen)
+        return _first_true(mid, jnp.asarray(0, I32)), jnp.any(mid)
+
+    def at(k):
+        return jax.tree.map(lambda arr: arr[k], cols)
+
+    def right_half(src: _NewSeg, off, uid) -> _NewSeg:
+        return src._replace(
+            seg_start=src.seg_start + off, seg_len=src.seg_len - off, seg_uid=uid
+        )
+
+    k1, found1 = holder(cut1)  # default index unused when ~do1
+    do1 = gate1 & found1
+    split1 = do1 & (s.nseg < S)
+    src1 = at(k1)
+    off1 = cut1 - excl[k1]
+    uid1 = s.uid_next
+    right1 = right_half(src1, off1, uid1)
+
+    # A split moves no visible length and both halves keep the source's
+    # visibility, so the document cut 1 leaves has the geometry of ``geom``
+    # with segment k1 in two parts, and cut 2's holder is found in the index
+    # space of ``geom``.  Where cut 1 overflowed its right half is gone: what
+    # follows k1 then lies that half's length lower (a cut past the end
+    # stays past it or wraps below zero: nothing holds it either way).
+    lost1 = do1 & ~split1
+    c2g = cut2 + jnp.where(lost1 & (cut2 > cut1), right1.seg_len, 0)
+    k2g, found2 = holder(c2g)
+    do2 = gate2 & found2 & ~(do1 & (cut2 == cut1))  # cut 1 made it a boundary
+    same = do1 & (k2g == k1)
+    in_right = same & (cut2 > cut1)
+    src2 = at(k2g)
+    src2 = src2._replace(
+        seg_start=src2.seg_start + jnp.where(in_right, off1, 0),
+        seg_len=jnp.where(
+            same, jnp.where(in_right, right1.seg_len, off1), src2.seg_len
+        ),
+        seg_uid=jnp.where(in_right, uid1, src2.seg_uid),
     )
-    s2 = _open_slot(s, k + 1, do, right)
-    # Trim the left half (only when the split actually happened).  A masked
-    # write, not ``.at[k].set``: one element per document is a scatter.
-    at_k = jnp.arange(s2.seg_len.shape[0], dtype=I32) == k
-    moved_start = do & (s2.ob_start_uid == old_uid) & (s2.ob_start_side == SIDE_AFTER)
-    moved_end = do & (s2.ob_end_uid == old_uid) & (s2.ob_end_side == SIDE_AFTER)
-    return s2._replace(
-        seg_len=jnp.where(do & at_k, off, s2.seg_len),
-        uid_next=s2.uid_next + do.astype(I32),
-        ob_start_uid=jnp.where(moved_start, right_uid, s2.ob_start_uid),
-        ob_end_uid=jnp.where(moved_end, right_uid, s2.ob_end_uid),
+    off2 = jnp.where(in_right, cut2 - cut1, c2g - excl[k2g])
+    # Index of cut 2's holder once slot 1 is open.
+    k2 = k2g + (split1 & (k2g > k1)).astype(I32) + in_right.astype(I32)
+    split2 = do2 & (s.nseg + split1.astype(I32) < S)
+    uid2 = uid1 + do1.astype(I32)
+    right2 = right_half(src2, off2, uid2)
+
+    with jax.named_scope("open_slot"):
+        # ``shift_right(shift_right(arr, k1 + 1, v1), k2 + 1, v2)``, each
+        # under its gate, element by element: slot 2 ends up at q2, slot 1 at
+        # q1 (one higher where slot 2 opened at or below it), and every other
+        # element comes from as many indices lower as slots opened below it.
+        q2 = k2 + 1
+        q1 = k1 + 1 + (split2 & (k1 >= k2)).astype(I32)
+        at1, past1 = split1 & (idx == q1), split1 & (idx > q1)
+        at2, past2 = split2 & (idx == q2), split2 & (idx > q2)
+        by1, by2 = past1 ^ past2, past1 & past2
+
+        def rewrite(arr, v1, v2):
+            # Static shifts, as in ``_shift_right``.
+            prev1 = jnp.concatenate([arr[:1], arr[:-1]])
+            prev2 = jnp.concatenate([arr[:2], arr[:-2]])
+            moved = jnp.where(by2, prev2, jnp.where(by1, prev1, arr))
+            return jnp.where(at2, v2, jnp.where(at1, v1, moved))
+
+        out = jax.tree.map(rewrite, cols, right1, right2)
+        # Trim the left halves in the same write (masked, not ``.at[k].set``:
+        # one element per document is a scatter).  Slot 2 may have opened
+        # below cut 1's left half; cut 2's own trim comes last.
+        t1 = k1 + (split2 & (k1 > k2)).astype(I32)
+        seg_len = jnp.where(
+            do2 & (idx == k2),
+            off2,
+            jnp.where(do1 & (idx == t1), off1, out.seg_len),
+        )
+
+    def anchored(uids, sides):
+        after = sides == SIDE_AFTER
+        uids = jnp.where(do1 & after & (uids == src1.seg_uid), uid1, uids)
+        return jnp.where(do2 & after & (uids == src2.seg_uid), uid2, uids)
+
+    return s._replace(
+        **out._replace(seg_len=seg_len)._asdict(),
+        nseg=s.nseg + split1.astype(I32) + split2.astype(I32),
+        uid_next=uid2 + do2.astype(I32),
+        ob_start_uid=anchored(s.ob_start_uid, s.ob_start_side),
+        ob_end_uid=anchored(s.ob_end_uid, s.ob_end_side),
+        error=s.error
+        | jnp.where(lost1 | (do2 & ~split2), ERR_SEG_OVERFLOW, 0),
     )
 
 
@@ -713,10 +784,12 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
     Under ``vmap`` a ``lax.switch`` on the row's kind runs every branch for
     every row and selects between whole ``DocState``s, text pool included.
     All kinds read the document from the same perspective (``ref_seq``,
-    ``client`` of the row), so here the shared work runs once (two gated
-    boundary splits, one geometry after them) and the kinds differ only in
-    the masks their writes go under.  ``flag`` is the Python bool of
-    ``apply_op``: with False the obliterate parts trace to nothing.
+    ``client`` of the row), so here the shared work runs once (one geometry,
+    from which both gated boundary splits are planned and their slots opened
+    in one rewrite of the segment columns, and one geometry after them) and
+    the kinds differ only in the masks their writes go under.  ``flag`` is
+    the Python bool of ``apply_op``: with False the obliterate parts trace to
+    nothing.
     """
     kind, key, client, ref_seq = op[0], op[1], op[2], op[3]
     pos1, pos2, a, b = op[4], op[5], op[6], op[7]
@@ -749,8 +822,7 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
             cut1 = jnp.where(is_ob, start_pos, cut1)
             cut2 = jnp.where(is_ob, end_pos, cut2)
             do_cut1, do_cut2 = do_cut1 | ob_ok, do_cut2 | ob_ok
-        s = _ensure_boundary(s, geom, cut1, do_cut1)
-        s = _ensure_boundary(s, _geometry(s, ref_seq, client), cut2, do_cut2)
+        s = _ensure_boundaries(s, geom, cut1, do_cut1, cut2, do_cut2)
         vis, vlen, excl = _geometry(s, ref_seq, client)
         alive = _alive(s)
         with jax.named_scope("mark_range"):
@@ -1104,11 +1176,11 @@ def _open_slot_seg(s: DocState, k, do, new: _NewSeg, axis: str) -> DocState:
 
 
 def _ensure_boundary_seg(s: DocState, pos, ref_seq, client, axis: str) -> DocState:
-    """Distributed ``_ensure_boundary``: the containing segment (if any) is
-    strictly inside exactly one shard; that shard splits locally.  The split
-    uid allocation and obliterate anchor side-moves replay identically on
-    every shard from the replicated uid_next / ob table plus one psum
-    broadcast of the split segment's old uid."""
+    """One cut of ``_ensure_boundaries``, distributed: the containing segment
+    (if any) is strictly inside exactly one shard; that shard splits locally.
+    The split uid allocation and obliterate anchor side-moves replay
+    identically on every shard from the replicated uid_next / ob table plus
+    one psum broadcast of the split segment's old uid."""
     vis = _visible(s, ref_seq, client)
     vlen, excl, _total, char_off = _seg_prefix(s, vis, axis)
     k, hit = _seg_contains(vlen, pos - char_off, strict=True)

@@ -13,9 +13,15 @@ byte identity of the padding too is ``test_dispatch_backends``' job, against
 ``native/megastep.cpp``.)
 
 (b) The jaxpr of the vmapped scan keeps the shape the body was written for:
-at most three cumulative sums a row, no select over the text pool (which the
-scan does not even carry: one scatter after it is the pool's only write), no
-``cond``/``switch`` on a batched predicate.
+at most two cumulative sums and two rewrites of each per-segment column a
+row, no select over the text pool (which the scan does not even carry: one
+scatter after it is the pool's only write), no ``cond``/``switch`` on a
+batched predicate.
+
+(c) A row's two boundary cuts, planned from one geometry and opened in one
+pass (``mk._ensure_boundaries``), against the one-cut split applied twice,
+each time on a geometry of its own, which is what the body did before: the
+reference is kept here.
 """
 
 import functools
@@ -318,15 +324,60 @@ def _step_program(flag, n_docs=3):
     return every, list(_eqns(scans[0].params["jaxpr"].jaxpr)), scans[0], n_docs
 
 
+def _shifted_versions(scan, n_docs):
+    """Per [n_docs, S] column the scan carries: how many versions of it the
+    row body reads shifted along the segment axis (a ``concatenate`` of
+    ``slice``s of one array, which is how a slot is opened).  One version is
+    one rewrite of the column, however many slots the rewrite opens."""
+    body = scan.params["jaxpr"].jaxpr
+    col = (n_docs, S)
+
+    def is_col(v):
+        return tuple(v.aval.shape) == col and v.aval.dtype == jnp.int32
+
+    counts = []
+    for col_in in body.invars:
+        if not is_col(col_in):
+            continue
+        # Every later value of the column: what a select, or a ``jnp.where``,
+        # makes of it, and its shifted self.
+        lineage, slice_of, shifted = {col_in}, {}, set()
+        for e in body.eqns:
+            ins = [v for v in e.invars
+                   if isinstance(v, jex_core.Var) and v in lineage]
+            name, out = e.primitive.name, e.outvars[0]
+            if name == "slice" and ins:
+                slice_of[out] = ins[0]
+            elif name == "concatenate" and is_col(out):
+                sources = {slice_of[v] for v in e.invars if v in slice_of}
+                if sources:
+                    assert len(sources) == 1, e
+                    shifted |= sources
+                    lineage.add(out)
+            elif ins and is_col(out) and (
+                    name == "select_n"
+                    or (name in ("jit", "pjit")
+                        and e.params["name"] == "_where")):
+                lineage.add(out)
+        counts.append(len(shifted))
+    return counts
+
+
 @pytest.mark.parametrize("flag", [False, True], ids=["no_ob", "ob"])
-@pytest.mark.parametrize("guard", ["cumsums", "text_pool", "branches"])
+@pytest.mark.parametrize(
+    "guard", ["cumsums", "slot_passes", "text_pool", "branches"])
 def test_vmapped_scan_body_structure(guard, flag):
     every, body, scan, n_docs = _step_program(flag)
     names = [e.primitive.name for e in body]
     if guard == "cumsums":
-        # One geometry before each of the two splits and one after them.
-        assert names.count("cumsum") <= 3, names.count("cumsum")
+        # One geometry both splits are planned from and one after them.
+        assert names.count("cumsum") <= 2, names.count("cumsum")
         assert names.count("cumsum") >= 1
+    elif guard == "slot_passes":
+        # Every per-segment column is rewritten twice a row: once for both
+        # boundary cuts, once for the insert.  (The ``concatenate``s do not
+        # tell: a two-slot pass has two a column.)
+        assert _shifted_versions(scan, n_docs) == [2] * (6 + 2 * R + 2 * P)
     elif guard == "text_pool":
         pool = (n_docs, T)
 
@@ -350,6 +401,240 @@ def test_vmapped_scan_body_structure(guard, flag):
         # program has neither to begin with.
         assert "cond" not in [e.primitive.name for e in every]
         assert "switch" not in names
+
+
+# ------------------------------------------- the two-cut plan of a row
+CS, CR, CP, COB = 16, 2, 2, 8       # a small document: S - 1 and S are near
+REF_SEQ, CLIENT = 10, 1
+INT_MIN, INT_MAX = -2**31, 2**31 - 1
+
+
+def _one_cut_reference(s, geom, pos, gate):
+    """``_ensure_boundary`` as the row body ran it until the two cuts were
+    planned together (one cut; the caller takes a geometry before each)."""
+    vis, vlen, excl = geom
+    mid = vis & (excl < pos) & (pos < excl + vlen)
+    k = mk._first_true(mid, jnp.asarray(0, jnp.int32))
+    do = gate & jnp.any(mid)
+    off = pos - excl[k]
+    old_uid = s.seg_uid[k]
+    right_uid = s.uid_next
+    right = mk._NewSeg(
+        seg_start=s.seg_start[k] + off,
+        seg_len=s.seg_len[k] - off,
+        ins_key=s.ins_key[k],
+        ins_client=s.ins_client[k],
+        seg_uid=right_uid,
+        seg_obpre=s.seg_obpre[k],
+        rem_keys=tuple(a[k] for a in s.rem_keys),
+        rem_clients=tuple(a[k] for a in s.rem_clients),
+        prop_keys=tuple(a[k] for a in s.prop_keys),
+        prop_vals=tuple(a[k] for a in s.prop_vals),
+    )
+    s2 = mk._open_slot(s, k + 1, do, right)
+    at_k = jnp.arange(s2.seg_len.shape[0], dtype=jnp.int32) == k
+    moved_start = (do & (s2.ob_start_uid == old_uid)
+                   & (s2.ob_start_side == mk.SIDE_AFTER))
+    moved_end = (do & (s2.ob_end_uid == old_uid)
+                 & (s2.ob_end_side == mk.SIDE_AFTER))
+    return s2._replace(
+        seg_len=jnp.where(do & at_k, off, s2.seg_len),
+        uid_next=s2.uid_next + do.astype(jnp.int32),
+        ob_start_uid=jnp.where(moved_start, right_uid, s2.ob_start_uid),
+        ob_end_uid=jnp.where(moved_end, right_uid, s2.ob_end_uid),
+    )
+
+
+def _cuts_in_turn(s, cut1, gate1, cut2, gate2):
+    s = _one_cut_reference(s, mk._geometry(s, REF_SEQ, CLIENT), cut1, gate1)
+    return _one_cut_reference(
+        s, mk._geometry(s, REF_SEQ, CLIENT), cut2, gate2)
+
+
+def _cuts_planned(s, cut1, gate1, cut2, gate2):
+    return mk._ensure_boundaries(
+        s, mk._geometry(s, REF_SEQ, CLIENT), cut1, gate1, cut2, gate2)
+
+
+_CUT_PROGRAMS = {
+    "alone": (jax.jit(_cuts_in_turn), jax.jit(_cuts_planned)),
+    "vmap": (jax.jit(jax.vmap(_cuts_in_turn)), jax.jit(jax.vmap(_cuts_planned))),
+}
+
+# Segment 4 takes cut 1 (length 8, cut at 3: room on both sides); 1 lies
+# before it and 7 after it; 2 and 5 are invisible from (REF_SEQ, CLIENT), by
+# a later insert and by an acked remove, so an index is not a position.
+_LENS = [3, 6, 4, 2, 8, 5, 1, 7, 3, 2, 4, 3, 5, 2, 6, 4]
+_K_BEFORE, _K_CUT1, _K_AFTER, _OFF1 = 1, 4, 7, 3
+
+
+def _cut_doc(rng, nseg):
+    """One document of ``nseg`` live segments (the padding behind them holds
+    noise, as shifts leave it) whose obliterate table anchors both sides of
+    both kinds on the segments the cuts split."""
+    d = {f: [np.array(a) for a in v] if isinstance(v, tuple) else np.array(v)
+         for f, v in mk.init_state(CS, CR, CP, 64, COB)._asdict().items()}
+    d["nseg"] = np.int32(nseg)
+    d["seg_len"] = np.array(_LENS, np.int32)
+    d["seg_start"] = (np.cumsum(_LENS) - _LENS).astype(np.int32)
+    d["ins_key"] = rng.integers(1, REF_SEQ + 1, CS).astype(np.int32)
+    d["ins_client"] = rng.integers(2, 5, CS).astype(np.int32)
+    d["ins_key"][2], d["ins_client"][3] = REF_SEQ + 5, CLIENT
+    d["ins_key"][3] = mk.LOCAL_BASE + 1          # the client's own pending
+    d["seg_uid"] = (100 + rng.permutation(CS)).astype(np.int32)
+    d["seg_obpre"] = rng.integers(-1, 9, CS).astype(np.int32)
+    d["rem_keys"][0][5], d["rem_clients"][0][5] = 4, 3
+    # A remove the perspective has not seen: still visible.
+    d["rem_keys"][0][_K_AFTER], d["rem_clients"][0][_K_AFTER] = REF_SEQ + 2, 3
+    for p in range(CP):
+        d["prop_keys"][p] = rng.integers(-1, 9, CS).astype(np.int32)
+        d["prop_vals"][p] = rng.integers(0, 99, CS).astype(np.int32)
+    d["uid_next"] = np.int32(200)
+    uid = d["seg_uid"]
+    anchors = [(_K_CUT1, mk.SIDE_AFTER, _K_CUT1, mk.SIDE_AFTER),
+               (_K_CUT1, mk.SIDE_BEFORE, _K_CUT1, mk.SIDE_BEFORE),
+               (_K_BEFORE, mk.SIDE_AFTER, _K_CUT1, mk.SIDE_AFTER),
+               (_K_CUT1, mk.SIDE_BEFORE, _K_AFTER, mk.SIDE_AFTER),
+               (_K_BEFORE, mk.SIDE_BEFORE, _K_AFTER, mk.SIDE_BEFORE)]
+    for i, (ks, ss, ke, se) in enumerate(anchors):
+        d["ob_key"][i], d["ob_client"][i] = 3 + i, 2
+        d["ob_start_uid"][i], d["ob_start_side"][i] = uid[ks], ss
+        d["ob_end_uid"][i], d["ob_end_side"][i] = uid[ke], se
+    d["error"] = np.int32(rng.choice([0, mk.ERR_POS_RANGE]))
+    for f in mk._NewSeg._fields:
+        # What the padding holds is carried along bit for bit, too.
+        for a in (d[f] if isinstance(d[f], list) else [d[f]]):
+            a[nseg:] = rng.integers(-9, 99, CS - nseg)
+    return mk.DocState(**{
+        f: tuple(v) if isinstance(v, list) else v for f, v in d.items()})
+
+
+def _visible_excl(doc):
+    vis, _vlen, excl = mk._geometry(doc, REF_SEQ, CLIENT)
+    return np.asarray(vis), np.asarray(excl)
+
+
+def _cut2_at(place, doc):
+    """Cut 2 of the case ``place``, where cut 1 is ``_OFF1`` into segment
+    ``_K_CUT1``."""
+    vis, excl = _visible_excl(doc)
+    assert vis[[_K_BEFORE, _K_CUT1, _K_AFTER]].all() and not vis[[2, 5]].any()
+    total = int((np.asarray(doc.seg_len) * vis).sum())
+    cut1 = int(excl[_K_CUT1]) + _OFF1
+    return cut1, {
+        "before": int(excl[_K_BEFORE]) + 2,
+        "left_half": cut1 - 1,
+        "equal": cut1,
+        "right_half": cut1 + 2,
+        "right_half_last_char": cut1 + _LENS[_K_CUT1] - _OFF1 - 1,
+        "after": int(excl[_K_AFTER]) + 4,
+        "on_a_boundary": int(excl[_K_AFTER]),
+        "on_cut1s_segment_end": int(excl[_K_CUT1]) + _LENS[_K_CUT1],
+        "zero": 0,
+        "negative": -3,
+        "at_the_end": total,
+        "past_the_end": total + 2,
+        "int_max": INT_MAX,
+        "int_min": INT_MIN,
+    }[place]
+
+
+CUT2_PLACES = ["before", "left_half", "equal", "right_half",
+               "right_half_last_char", "after", "on_a_boundary",
+               "on_cut1s_segment_end", "zero", "negative", "at_the_end",
+               "past_the_end", "int_max", "int_min"]
+# nseg: room for both splits; for one (the second overflows); for none (the
+# first overflows, and the second meets the trimmed document).
+FILLS = {"room": CS - 3, "last_slot": CS - 1, "full": CS}
+GATES = {"both": (True, True), "first_off": (False, True),
+         "second_off": (True, False), "none": (False, False)}
+
+
+def _assert_states_equal(got, want, note):
+    for name, g, w in zip(mk.DocState._fields, got, want):
+        for i, (ga, wa) in enumerate(zip(*(
+                (x if isinstance(x, tuple) else (x,)) for x in (g, w)))):
+            assert np.array_equal(np.asarray(ga), np.asarray(wa)), (
+                note, name, i, np.asarray(ga), np.asarray(wa))
+
+
+def _run_cuts(batching, docs, cuts):
+    """Both programs over ``docs`` (one, or a batch with a row of ``cuts``
+    each): (planned, in turn)."""
+    in_turn, planned = _CUT_PROGRAMS[batching]
+    args = [jnp.asarray(c) for c in zip(*cuts)]
+    if batching == "alone":
+        (doc,), args = docs, [a[0] for a in args]
+    else:
+        doc = jax.tree.map(lambda *xs: jnp.stack(xs), *docs)
+    return planned(doc, *args), in_turn(doc, *args)
+
+
+@pytest.mark.parametrize("batching", ["alone", "vmap"])
+@pytest.mark.parametrize("fill", FILLS)
+@pytest.mark.parametrize("gates", GATES)
+@pytest.mark.parametrize("place", CUT2_PLACES)
+def test_two_cuts_planned_equal_two_cuts_in_turn(place, gates, fill, batching):
+    rng = np.random.default_rng(CUT2_PLACES.index(place))
+    doc = _cut_doc(rng, FILLS[fill])
+    cut1, cut2 = _cut2_at(place, doc)
+    g1, g2 = GATES[gates]
+    docs, cuts = [doc], [(cut1, g1, cut2, g2)]
+    if batching == "vmap":
+        # Other rows of the batch cut elsewhere, under other gates, in
+        # documents of every fill, and in the other order.
+        for i, other in enumerate(CUT2_PLACES[:6]):
+            d = _cut_doc(rng, list(FILLS.values())[i % 3])
+            c1, c2 = _cut2_at(other, d)
+            docs.append(d)
+            cuts.append((c2, i % 4 != 1, c1, i % 4 != 2))
+    got, want = _run_cuts(batching, docs, cuts)
+    _assert_states_equal(got, want, (place, gates, fill))
+    # The case is the case it says it is.
+    first = jax.tree.map(lambda x: np.asarray(x)[0], want) \
+        if batching == "vmap" else jax.tree.map(np.asarray, want)
+    splits = int(first.nseg) - FILLS[fill]
+    inside = ("before", "left_half", "right_half", "right_half_last_char",
+              "after") + (() if g1 else ("equal",))
+    asked = int(g1) + int(g2 and place in inside)
+    if fill == "full" and g1:
+        # Cut 1 overflowed: its right half is gone, and cut 2 met what
+        # followed it that much lower.
+        asked = int(first.uid_next) - 200
+        assert asked >= 1
+    assert splits == min(asked, CS - FILLS[fill])
+    assert int(first.uid_next) == 200 + asked
+    assert bool(int(first.error) & mk.ERR_SEG_OVERFLOW) == (asked > splits)
+
+
+@pytest.mark.parametrize("cut1_place", ["after", "negative", "past_the_end",
+                                        "on_a_boundary", "int_max"])
+@pytest.mark.parametrize("fill", FILLS)
+def test_two_cuts_with_cut1_elsewhere(cut1_place, fill):
+    """Cut 2 below cut 1 (the order illegal rows produce), and a cut 1 that
+    splits nothing before a cut 2 that does."""
+    doc = _cut_doc(np.random.default_rng(7), FILLS[fill])
+    cut2, cut1 = _cut2_at(cut1_place, doc)
+    got, want = _run_cuts("alone", [doc], [(cut1, True, cut2, True)])
+    _assert_states_equal(got, want, (cut1_place, fill))
+    assert int(want.uid_next) == 200 + 1 + (cut1_place == "after")
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_two_cuts_random(seed):
+    """Cuts drawn anywhere around a document, every fill and gate."""
+    rng = np.random.default_rng(900 + seed)
+    docs, cuts = [], []
+    for _ in range(64):
+        doc = _cut_doc(rng, int(rng.choice([3, 9, CS - 2, CS - 1, CS])))
+        vis, _excl = _visible_excl(doc)
+        total = int((np.asarray(doc.seg_len) * vis).sum())
+        docs.append(doc)
+        cuts.append((int(rng.integers(-2, total + 3)), bool(rng.random() < 0.8),
+                     int(rng.integers(-2, total + 3)), bool(rng.random() < 0.8)))
+    got, want = _run_cuts("vmap", docs, cuts)
+    _assert_states_equal(got, want, seed)
+    assert len({int(n) for n in np.asarray(want.nseg)}) > 3
 
 
 @pytest.mark.parametrize("seed", range(4))
