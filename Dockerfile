@@ -16,8 +16,8 @@
 FROM python:3.12-slim
 
 # g++ backs the on-demand native builds (native/*.cpp: sequencer, ingest
-# encoder, megastep dispatch plane); build-essential keeps the image able
-# to rebuild them when the sources change under a bind mount.
+# encoder); build-essential keeps the image able to rebuild them when the
+# sources change under a bind mount.
 RUN apt-get update \
     && apt-get install -y --no-install-recommends g++ \
     && rm -rf /var/lib/apt/lists/*
@@ -42,8 +42,7 @@ RUN python -m fluidframework_tpu.analysis.cli fluidframework_tpu --json
 # Pre-build the native libraries so containers start warm; failure is
 # non-fatal (the ctypes loaders rebuild on demand at first use).
 RUN (g++ -O2 -shared -fPIC -std=c++17 -o native/libtpusequencer.so native/sequencer.cpp \
-     && g++ -O2 -shared -fPIC -std=c++17 -o native/libtpuingest.so native/ingest.cpp \
-     && g++ -O2 -shared -fPIC -std=c++17 -o native/libtpumegastep.so native/megastep.cpp) \
+     && g++ -O2 -shared -fPIC -std=c++17 -o native/libtpuingest.so native/ingest.cpp) \
     || echo "native pre-build failed; loaders will build on demand"
 
 EXPOSE 7070 7071

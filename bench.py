@@ -13,7 +13,7 @@ name unchanged since r1 for comparability, with the multi-writer Zipf
 config-3 number attached as co-headline).  Every measuring process reads
 ``jax.devices()`` itself and stamps its row with platform, device_kind and
 device_count.  A run started without ``JAX_PLATFORMS=cpu`` that finds no
-accelerator fails: there is no fallback to the CPU or to the native plane,
+accelerator fails: there is no fallback to the CPU,
 and a failed config makes the whole run exit non-zero.  With
 ``JAX_PLATFORMS=cpu`` the run is an explicit CPU run at reduced scale
 (``reduced_scale`` on every row).  Explicit runs:
@@ -349,156 +349,6 @@ def _mergetree_run(args, D, gen, metric, lane_k: int | None = None):
     if errors:
         result["error_docs"] = errors
     return result
-
-
-def _xla_plane_tag() -> str:
-    """Which XLA backend this process actually dispatches to."""
-    import jax
-
-    return f"xla:{jax.devices()[0].platform}"
-
-
-def _dispatch_plane_probe(args, D, gen) -> dict:
-    """Dual-plane replay: the SAME generated trace through the jitted XLA
-    scan and through the native CPU dispatch plane (native/megastep.cpp
-    via fluidframework_tpu.native.megastep_native), in one invocation.
-
-    Both lanes replay warmup + timed halves from the same fresh fleet
-    state with the same compact cadence; the timed half is clocked on
-    each (best of up to 3 reps) and the FINAL states are byte-compared
-    over every raw column — ``native_dispatch_identity`` is the same
-    contract tests/test_dispatch_backends.py fuzzes, re-checked on the
-    bench trace itself so the speedup number can never quietly come from
-    a divergent kernel."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from fluidframework_tpu.native import megastep_native
-    from fluidframework_tpu.ops import mergetree_kernel as mk
-
-    if not megastep_native.warm():
-        return {
-            "dispatch_plane": _xla_plane_tag(),
-            "native_dispatch_identity": False,
-            "native_dispatch_error": "libtpumegastep.so unavailable "
-                                     "(g++ build failed?)",
-        }
-
-    proto = mk.init_state(
-        max_segments=args.segments,
-        remove_slots=4,
-        prop_slots=2,
-        text_capacity=args.text_capacity,
-    )
-    ops, payloads, min_seqs, real_ops = gen()
-    ce = args.compact_every
-    w = args.steps  # generators emit 2*steps rounds; the back half is timed
-    reps = max(1, min(args.reps, 3))
-
-    # ---------------- XLA lane: the same fused scan _mergetree_run times
-    has_ob = bool((ops[:, :, 0, :] == mk.OpKind.OBLITERATE).any())
-    apply_batch = jax.vmap(
-        functools.partial(mk.apply_ops, ob_flag=has_ob), in_axes=(0, 2, 2)
-    )
-    compact_batch = jax.vmap(
-        lambda s, m: mk.compact(mk.set_min_seq(s, m), has_ob)
-    )
-
-    def scan(state, all_ops, all_payloads, all_minseqs):
-        def body(carry, xs):
-            s, i = carry
-            o, p, m = xs
-            s = apply_batch(s, o, p)
-            s = jax.lax.cond(
-                (i + 1) % ce == 0,
-                lambda s: compact_batch(s, m), lambda s: s, s,
-            )
-            return (s, i + 1), None
-
-        (s, _), _ = jax.lax.scan(
-            body, (state, jnp.zeros((), jnp.int32)),
-            (all_ops, all_payloads, all_minseqs),
-        )
-        return s
-
-    runner = jax.jit(scan, donate_argnums=(0,))
-
-    def fresh_jax():
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (D,) + x.shape), proto
-        )
-
-    dev_w = (jnp.asarray(ops[:w]), jnp.asarray(payloads[:w]),
-             jnp.asarray(min_seqs[:w]))
-    dev_t = (jnp.asarray(ops[w:]), jnp.asarray(payloads[w:]),
-             jnp.asarray(min_seqs[w:]))
-    dt_xla = float("inf")
-    for _ in range(reps):
-        st = runner(fresh_jax(), *dev_w)
-        jax.block_until_ready(st)
-        t0 = time.perf_counter()
-        st = runner(st, *dev_t)
-        jax.block_until_ready(st)
-        dt_xla = min(dt_xla, time.perf_counter() - t0)
-    xla_final = jax.tree.map(np.asarray, st)
-
-    # ---------------- native lane: same trace, [round, D, B, ...] layout
-    n_ops = np.ascontiguousarray(np.moveaxis(ops, -1, 1))
-    n_pay = np.ascontiguousarray(np.moveaxis(payloads, -1, 1))
-
-    def fresh_np():
-        return jax.tree.map(
-            lambda x: np.broadcast_to(
-                np.asarray(x), (D,) + np.asarray(x).shape
-            ).copy(),
-            proto,
-        )
-
-    def replay_half(state, s0, s1):
-        # Chunk the rounds into K=compact_every megastep rings so chunk
-        # boundaries land exactly on the scan's compact cadence (the
-        # cadence counter resets per half, like the jitted runner's).
-        h = s1 - s0
-        for c in range(0, h, ce):
-            k = min(ce, h - c)
-            state = megastep_native.megastep(
-                state, n_ops[s0 + c:s0 + c + k], n_pay[s0 + c:s0 + c + k]
-            )
-            if (c + k) % ce == 0:
-                state = megastep_native.fleet_compact(
-                    state, min_seqs[s0 + c + k - 1]
-                )
-        return state
-
-    dt_native = float("inf")
-    for _ in range(reps):
-        stn = replay_half(fresh_np(), 0, w)
-        t0 = time.perf_counter()
-        stn = replay_half(stn, w, ops.shape[0])
-        dt_native = min(dt_native, time.perf_counter() - t0)
-
-    identical = True
-    for name in mk.DocState._fields:
-        a, b = getattr(xla_final, name), getattr(stn, name)
-        aa = a if isinstance(a, tuple) else (a,)
-        bb = b if isinstance(b, tuple) else (b,)
-        for x, y in zip(aa, bb):
-            if not np.array_equal(np.asarray(x), np.asarray(y)):
-                identical = False
-
-    timed_ops = real_ops // 2
-    xla_rate = timed_ops / dt_xla
-    native_rate = timed_ops / dt_native
-    return {
-        "backend": "native-cpu",
-        "dispatch_plane": "native-cpu",
-        "xla_dispatch_ops_per_sec": round(xla_rate, 1),
-        "native_dispatch_ops_per_sec": round(native_rate, 1),
-        "native_dispatch_speedup": round(native_rate / xla_rate, 2),
-        "native_dispatch_identity": bool(identical),
-    }
 
 
 def _string_ingest_rate(n_docs, rounds, writers, seed=0, megastep_k=8,
@@ -1038,10 +888,6 @@ def bench_config1(args) -> dict:
         )
 
     out = _mergetree_run(args, 1, gen, "config1_singledoc_replay_ops_per_sec")
-    if getattr(args, "dispatch_plane", "jax") == "native":
-        out.update(_dispatch_plane_probe(args, 1, gen))
-    else:
-        out["dispatch_plane"] = _xla_plane_tag()
     if args.seg_shards > 1:
         try:
             seg = _seg_replay_rate(args, args.seg_shards)
@@ -1092,10 +938,6 @@ def bench_config3(args) -> dict:
     out["docs"] = D
     if lane_k < D:
         out["lanes"] = [lane_k, D - lane_k]
-    if getattr(args, "dispatch_plane", "jax") == "native":
-        out.update(_dispatch_plane_probe(args, D, gen))
-    else:
-        out["dispatch_plane"] = _xla_plane_tag()
     out["ingest_ops_per_sec"], out["engine_health"] = _string_ingest_rate(
         min(D, 128), rounds=16, writers=4, megastep_k=args.megastep_k
     )
@@ -2472,12 +2314,6 @@ def _driver_main() -> int:
             res = {"metric": _metric_name(key), "value": None,
                    "unit": _unit_name(key), "vs_baseline": None,
                    "error": err}
-        else:
-            # Every row names the plane that actually dispatched it: the
-            # merge-tree configs stamp "native-cpu" themselves when the
-            # native probe ran; everything else is the XLA backend the
-            # child reported.
-            res.setdefault("dispatch_plane", f"xla:{res['platform']}")
         if reduced:
             res["reduced_scale"] = True  # requested CPU: small, not broken
         results[key] = res
@@ -2493,24 +2329,6 @@ def _driver_main() -> int:
               file=sys.stderr)
         return 1
     return 0
-
-
-def _merge_artifact(path: str, key: str, res: dict) -> None:
-    """Merge one config row into a keyed JSON artifact (creating it when
-    absent): multiple single-config invocations build one round file."""
-    data: dict = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                loaded = json.load(f)
-            if isinstance(loaded, dict):
-                data = loaded
-        except (json.JSONDecodeError, OSError):
-            data = {}
-    data[key] = res
-    with open(path, "w") as f:
-        json.dump(data, f, indent=2, sort_keys=True)
-        f.write("\n")
 
 
 def _unit_name(key: str) -> str:
@@ -2540,17 +2358,7 @@ def main() -> int:
     p.add_argument("--artifact", default=None,
                    help="with --config multichip: also write the full "
                         "per-device table to this JSON file (the "
-                        "MULTICHIP round artifact); with --config 1/3 the "
-                        "row merges into the file under config<k> (two "
-                        "invocations build one NATIVE round artifact)")
-    p.add_argument("--dispatch-plane", default="jax",
-                   choices=["jax", "native"],
-                   help="with --config 1/3: 'native' additionally replays "
-                        "the same trace through BOTH the jitted XLA scan "
-                        "and the native CPU dispatch plane "
-                        "(native/megastep.cpp) and records both rates, "
-                        "the speedup, and byte-identity of the final "
-                        "states")
+                        "MULTICHIP round artifact)")
     p.add_argument("--docs", type=int, default=None)
     # (segments/text-capacity/steps also use None defaults so per-config
     # tuning never clobbers an explicitly requested value.)
@@ -2629,7 +2437,7 @@ def main() -> int:
         "fanout": bench_fanout,
         "loadgen": bench_loadgen,
     }
-    def _emit(res: dict) -> dict:
+    def _emit(res: dict) -> None:
         # Every config row carries the observability attachment
         # (latency_p50_ms / latency_p99_ms / phase_shares — ISSUE 7).
         # The soak row is exempt: its p50/p99 are measured UNDER FAULT on
@@ -2641,11 +2449,10 @@ def main() -> int:
         # from real worker processes — same rule as soak.
         if res.get("metric", "").startswith(("soak_", "fanout_", "loadgen_")):
             print(json.dumps(res), flush=True)
-            return res
+            return
         res = _attach_observability(res, args.megastep_k)
         res.update(device_row or {})
         print(json.dumps(res), flush=True)
-        return res
 
     if args.config is None:
         # Flags without --config: the pre-driver-mode behavior (headline
@@ -2655,12 +2462,7 @@ def main() -> int:
         for key in ("1", "2", "3", "4", "5", "latency", "headline"):
             _emit(table[key](args))
     else:
-        res = _emit(table[args.config](args))
-        if args.artifact and args.config in ("1", "3"):
-            # Round-artifact merge: each invocation contributes its row
-            # under config<k>, so `--config 1 --artifact F` then
-            # `--config 3 --artifact F` build one dual-plane artifact.
-            _merge_artifact(args.artifact, f"config{args.config}", res)
+        _emit(table[args.config](args))
     if trace_recorder is not None:
         n = trace_recorder.export_chrome_trace(args.trace)
         print(json.dumps({

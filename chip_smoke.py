@@ -500,7 +500,6 @@ def string_leg(tag: str, size: dict, args, env: dict, workdir: str,
         check(not h.get("ingest_fallback_msgs"), "per-message ingest was used")
         check(h["ingest_plane"] == "native",
               f"ingest plane {h['ingest_plane']}, not native")
-        check("seg_plane_unsupported" not in h, "seg plane downgraded")
         check(h["megastep_dispatches"] > 0, "no dispatch")
         check(h["full_steps"] > 0, "the fleet-wide step never ran")
         if args.mesh:

@@ -4,9 +4,8 @@ The in-process local driver and the local service client are, by design,
 bindings TO the local server (tinylicious shape) — which left the driver
 and framework layers importing ``server.local_service`` upward, edges the
 fftpu-check baseline carried with rationales since the layer gate landed.
-This module inverts them the same way ``models.dispatch`` inverted the
-engines' mesh edge: the lower layers depend on an abstract provider slot,
-and the concrete service registers itself here when its module loads.
+This module inverts them: the lower layers depend on an abstract provider
+slot, and the concrete service registers itself here when its module loads.
 
 Resolution order:
 

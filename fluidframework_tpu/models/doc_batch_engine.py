@@ -79,7 +79,7 @@ from ..dds.mergetree_ref import RefMergeTree
 from ..dds.shared_string import decode_obliterate_places
 from ..observability.flight_recorder import RecompileWatchdog, instant, span
 from ..ops import mergetree_kernel as mk
-from .dispatch import dispatch_plane
+from ..parallel import mesh as pm
 from . import placement
 from ..protocol.messages import DeltaType, MessageType, SequencedMessage
 from ..utils.telemetry import HealthCounters, Histogram, SampledTelemetryHelper
@@ -415,11 +415,6 @@ class DocBatchEngine:
         self._lat_pending: list[tuple[float, int]] = []
 
         if use_mesh:
-            # Engine-owned dispatch seam (models/dispatch.py): the plane
-            # owns mesh construction + shard_map program factories; the
-            # concrete provider (parallel.mesh by default) registers
-            # itself, inverting the old models -> parallel import.
-            pm = self._pm = dispatch_plane()
             if mesh is not None:
                 self.mesh = mesh
             elif seg_shards > 1:
@@ -432,7 +427,6 @@ class DocBatchEngine:
             n_shards = self.mesh.devices.size
             self.seg_shards = int(dict(self.mesh.shape).get(pm.SEG_AXIS, 1))
         else:
-            self._pm = None
             self.mesh = None
             n_shards = 1
             self.seg_shards = 1
@@ -498,25 +492,15 @@ class DocBatchEngine:
                 # Segment-lane programs: one donated dispatch applies a
                 # [K, B] op ring to one seg-sharded hot doc, per-segment
                 # work split over the segs axis (two collective hops
-                # inside — mk.apply_megastep_seg).  A plane without
-                # seg-lane programs (the native CPU plane) raises a loud
-                # NotImplementedError here; the engine maps it to the
-                # doc-sharded path and counts the downgrade — never a
-                # silent degradation.
-                try:
-                    seg_specs = pm.seg_state_specs(self._proto)
-                    self._seg_megastep = pm.mesh_seg_program(
-                        mk.apply_megastep_seg, self.mesh, seg_specs
-                    )
-                    self._seg_compact = pm.mesh_seg_program(
-                        mk.compact_seg, self.mesh, seg_specs,
-                        arg_specs=(pm.P(),),
-                    )
-                except NotImplementedError:
-                    self._seg_megastep = None
-                    self._seg_compact = None
-                    self.seg_shards = 1
-                    self.counters.bump("seg_plane_unsupported")
+                # inside — mk.apply_megastep_seg).
+                seg_specs = pm.seg_state_specs(self._proto)
+                self._seg_megastep = pm.mesh_seg_program(
+                    mk.apply_megastep_seg, self.mesh, seg_specs
+                )
+                self._seg_compact = pm.mesh_seg_program(
+                    mk.compact_seg, self.mesh, seg_specs,
+                    arg_specs=(pm.P(),),
+                )
         self._lane_apply = _lane_apply_jit
         self._lane_compact = _lane_compact_jit
         # Recompile watchdog: executable-cache growth on any fleet program
@@ -1248,7 +1232,7 @@ class DocBatchEngine:
                 self.megastep_k, self.capacity, self.ops_per_step,
                 mk.OP_FIELDS, self.max_insert_len, mesh=self.mesh,
                 doc_axis=(
-                    self._pm.fleet_doc_axes(self.mesh)
+                    pm.fleet_doc_axes(self.mesh)
                     if self.mesh is not None else "docs"
                 ),
             )
@@ -1608,7 +1592,7 @@ class DocBatchEngine:
         except (ValueError, NotImplementedError):
             return False
         lane = _SegmentLane(
-            state=self._pm.shard_seg_state(blocked, self.mesh),
+            state=pm.shard_seg_state(blocked, self.mesh),
             n_shards=self.seg_shards, s_local=s_local,
             queue=RowQueue(mk.OP_FIELDS, self.max_insert_len),
         )
@@ -1695,7 +1679,7 @@ class DocBatchEngine:
         ):
             host = jax.tree.map(np.asarray, lane.state)
             blocked = mk.seg_rebalance_state(host, s_local=lane.s_local)
-            lane.state = self._pm.shard_seg_state(blocked, self.mesh)
+            lane.state = pm.shard_seg_state(blocked, self.mesh)
         lane.version += 1
         lane.rebalances += 1
         lane.ops_since_rebalance = 0
@@ -1719,7 +1703,7 @@ class DocBatchEngine:
         for d, h in enumerate(self.hosts):
             mins[self._slot[d]] = h.min_seq
         if self.mesh is not None:
-            mins_dev = jax.device_put(mins, self._pm.shard_docs(self.mesh))
+            mins_dev = jax.device_put(mins, pm.shard_docs(self.mesh))
         else:
             mins_dev = jnp.asarray(mins)
         self.state = self._compact(self.state, mins_dev)
@@ -1752,7 +1736,7 @@ class DocBatchEngine:
             # errors are per-lane scalars checked below, so an active seg
             # or overflow lane must not force the batch-state gather.
             with span("readback", kind="error_count"):
-                batch_clean = int(self._pm.error_count(self.state.error)) == 0
+                batch_clean = int(pm.error_count(self.state.error)) == 0
         if not batch_clean:
             with span("readback", kind="error_vector"):
                 err = np.asarray(self.state.error)
@@ -2758,7 +2742,7 @@ class DocBatchEngine:
             for d, h in enumerate(self.hosts):
                 mins[self._slot[d]] = h.min_seq
             if self.mesh is not None:
-                mins_dev = jax.device_put(mins, self._pm.shard_docs(self.mesh))
+                mins_dev = jax.device_put(mins, pm.shard_docs(self.mesh))
             else:
                 mins_dev = jnp.asarray(mins)
             self.state = self._compact(self.state, mins_dev)

@@ -54,7 +54,7 @@ from ..dds.tree.field_kinds import OptionalChange
 from ..dds.tree.forest import ROOT_FIELD, Forest, Node
 from ..observability.flight_recorder import RecompileWatchdog, instant, span
 from ..ops import tree_kernel as tk
-from .dispatch import dispatch_plane
+from ..parallel import mesh as pm
 from . import placement
 from ..protocol.messages import MessageType, SequencedMessage
 from ..utils.telemetry import HealthCounters
@@ -366,14 +366,11 @@ class TreeBatchEngine:
         self._step = _tree_step_jit
         self._megastep = _tree_megastep_jit
         self._compact = _tree_compact_jit
-        self._pm = None
         if mesh is not None:
             # Partition-rule-matched placement + shard_map-wrapped fleet
-            # programs resolved through the engine-owned dispatch seam
-            # (models/dispatch.py): one donated dispatch steps every
+            # programs (parallel.mesh): one donated dispatch steps every
             # shard, zero hot-path collectives (same machinery as the
             # string engine).
-            pm = self._pm = dispatch_plane()
             self.state = pm.shard_fleet_state(self.state, mesh)
             # On a docs x segs mesh the doc dim shards over BOTH axes
             # flattened — the program specs must match the placement
@@ -917,7 +914,7 @@ class TreeBatchEngine:
                 self.megastep_k, self.fleet_capacity, self.ops_per_step,
                 tk.NESTED_OP_FIELDS, self.max_insert_len, mesh=self.mesh,
                 doc_axis=(
-                    self._pm.fleet_doc_axes(self.mesh)
+                    pm.fleet_doc_axes(self.mesh)
                     if self.mesh is not None else "docs"
                 ),
             )
@@ -1054,7 +1051,7 @@ class TreeBatchEngine:
             # Per-shard latch reduce: one scalar readback instead of a
             # cross-mesh [D] error gather on every step.
             with span("readback", kind="error_count"):
-                clean = int(self._pm.error_count(self.state.error)) == 0
+                clean = int(pm.error_count(self.state.error)) == 0
             if clean:
                 return steps
         with span("readback", kind="error_vector"):

@@ -406,8 +406,8 @@ def _ensure_boundaries(
 
     A split that finds the document full (``nseg == S``) latches
     ERR_SEG_OVERFLOW and opens no slot, but its left half is trimmed, its
-    uid spent and its anchors moved all the same (native/megastep.cpp
-    mirrors that; the host grows the document and replays).
+    uid spent and its anchors moved all the same (tests/test_apply_op_body's
+    one-cut split holds that; the host grows the document and replays).
     """
     vis, vlen, excl = geom
     S = s.seg_len.shape[0]
@@ -799,8 +799,8 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
     is_ack = kind == OpKind.ACK
     is_range = is_remove | is_annotate
     # Out-of-range kinds keep what ``lax.switch`` made of them by clamping
-    # (native/megastep.cpp mirrors it): below NOOP nothing, above OBLITERATE
-    # an obliterate.
+    # (``apply_op_seg`` still does; no test feeds one): below NOOP nothing,
+    # above OBLITERATE an obliterate.
     is_ob = (kind >= OpKind.OBLITERATE) if flag else False
 
     with jax.named_scope(SHARED_SCOPE):

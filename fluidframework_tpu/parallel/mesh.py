@@ -270,16 +270,3 @@ def error_count(error: jnp.ndarray) -> jnp.ndarray:
     step (the gather happens only when this count is nonzero)."""
     return jnp.sum((error != 0).astype(jnp.int32))
 
-
-# ---------------------------------------------------------------------------
-# Dispatch-seam registration: this module IS the default dispatch plane
-# (models/dispatch.py).  The engines resolve it through the registry
-# instead of importing parallel.mesh upward — the models -> parallel
-# inversion the fftpu-check baseline used to carry.
-# ---------------------------------------------------------------------------
-
-import sys as _sys
-
-from ..models.dispatch import register_dispatch_plane as _register
-
-_register(_sys.modules[__name__])

@@ -16,6 +16,17 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
+# XLA:CPU runs every participant of an in-process collective on a thread of
+# the PjRt client's pool and holds it for as long as the rendezvous waits.
+# The pool has max(cores, devices) threads unless PJRT_NPROC says otherwise,
+# so on a box with 8 cores the 8 virtual devices leave no slack: reading a
+# mesh-served engine's state (`doc_state`'s `x[slot]`, one 8-participant
+# all-reduce program per leaf, dispatched back to back) left seven
+# participants waiting for an eighth that never got a thread, and XLA
+# aborted the process 40 s later.  Two programs' worth of threads (the one in
+# the rendezvous and the next, whose participants take theirs before the first
+# is complete) is the least that passed; more only oversubscribes the workers.
+os.environ.setdefault("PJRT_NPROC", "16")
 
 # Persistent XLA compile cache: the suite is compile-dominated on small CI
 # boxes (hundreds of unique engine/kernel geometries, each a multi-second
@@ -34,7 +45,6 @@ import pytest  # noqa: E402
 # lane (`pytest -m "not device"`).
 _DEVICE_MODULES = {
     "test_columnar_ingest",
-    "test_dispatch_backends",
     "test_doc_batch_engine",
     "test_fleet_consumer",
     "test_kernel_channel",
@@ -42,6 +52,7 @@ _DEVICE_MODULES = {
     "test_matrix_kernel",
     "test_megastep",
     "test_mergetree_kernel",
+    "test_mesh_conformance",
     "test_multidevice",
     "test_native_ingest",
     "test_obliterate",

@@ -1645,7 +1645,7 @@ SEEDINGS = [
     # loadgen sits in the service layer: an upward import FROM a state-
     # layer module INTO loadgen must trip the gate (proves the new
     # subsystem is really declared, not silently outside the graph).
-    ("models/dispatch.py",
+    ("models/placement.py",
      lambda s: s + "\nfrom ..loadgen import schedule as _seeded\n",
      "layer-upward-import", "layer-check"),
     ("server/scribe.py",
@@ -1736,16 +1736,16 @@ SEEDINGS = [
          "    def require_migratable(",
      ),
      "blocking-under-lock", "blocking-under-lock"),
-    # A lazy native-plane g++ build planted under the serving lock in a
-    # NEW module: megastep_native.warm spawns a compiler subprocess
-    # (blocking_calls in layers.json), and ckpt_lock denies subprocess —
-    # the exact hazard the warm()/loaded() split keeps out of the native
-    # dispatch plane's serving path.
-    ("parallel/native_plane.py",
+    # A lazy native g++ build planted under the serving lock:
+    # ingest_native.warm spawns a compiler subprocess (blocking_calls in
+    # layers.json), and ckpt_lock denies subprocess — the exact hazard the
+    # warm()/loaded() split keeps out of the engines' serving path.
+    ("models/recovery.py",
      lambda s: s + (
          "\n\ndef _seeded_lazy_build(engine):\n"
+         "    from ..native import ingest_native\n"
          "    with engine.ckpt_lock:\n"
-         "        megastep_native.warm()\n"
+         "        ingest_native.warm()\n"
      ),
      "blocking-under-lock", "blocking-under-lock"),
     # The "re-enable donation" edit on the declared replicated-out

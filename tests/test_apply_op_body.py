@@ -8,9 +8,9 @@ that overflow the text pool, invalid obliterates), are replayed through the
 batched kernel — ``vmap(apply_ops)`` slice by slice, and K > 1 through
 ``apply_megastep`` — and through ``dds/mergetree_ref.RefMergeTree``.  Every
 leaf's live content must agree: segment boundaries, text, stamps, remove
-sets, props, obliterate records, and the error latch bit for bit.  (Raw
-byte identity of the padding too is ``test_dispatch_backends``' job, against
-``native/megastep.cpp``.)
+sets, props, obliterate records, and the error latch bit for bit.  (The
+padding slots hold shift remnants and are held by no independent reference;
+(c) compares them for the cuts.)
 
 (b) The jaxpr of the vmapped scan keeps the shape the body was written for:
 at most two cumulative sums and two rewrites of each per-segment column a
