@@ -259,9 +259,14 @@ def _mergetree_run(args, D, gen, metric, lane_k: int | None = None):
         common no-obliterate trace is one fully-fused, fully-donated scan.
         (A per-step lax.cond forces whole-state copies across the branch
         boundary — measured ~37% of the headline.)"""
-        apply_batch = jax.vmap(
-            functools.partial(mk.apply_ops, ob_flag=ob_static), in_axes=(0, 2, 2)
-        )
+        def apply_batch(s, ops, payloads):
+            # ops: [B, F, lane docs]; the row loop ends at the lane's
+            # deepest queue (mk.apply_fleet_ops).
+            return mk.apply_fleet_ops(
+                s, jnp.moveaxis(ops, -1, 0), jnp.moveaxis(payloads, -1, 0),
+                ob_static,
+            )
+
         compact_batch = jax.vmap(
             lambda s, m: mk.compact(mk.set_min_seq(s, m), ob_static)
         )

@@ -182,20 +182,15 @@ def _i32(v) -> int:
 # created per test / per restart, and the programs close over nothing
 # instance-specific.
 
+# One [D, B] slice: the row loop ends at the slice's deepest queue, the row
+# vmapped over documents inside it (mk.apply_fleet_ops).
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _fleet_step(state, ops, payloads):
-    # Scalar (unbatched) obliterate gate: keeps the ob machinery a real
-    # lax.cond branch under vmap (see mk.apply_op docstring).
-    flag = jnp.any(state.ob_key >= 0) | jnp.any(
-        ops[..., 0] == mk.OpKind.OBLITERATE
-    )
-    return jax.vmap(mk.apply_ops, in_axes=(0, 0, 0, None))(
-        state, ops, payloads, flag
-    )
+    return mk.apply_fleet_ops(state, ops, payloads)
 
 
 # Megastep dispatch: a [K, D, B] op ring applied as ONE donated program
-# (lax.scan over slices, vmap over docs, per-slice obliterate gate carried
+# (lax.scan over slices, per-slice obliterate gate and row count taken
 # on device — see mk.apply_megastep).  Amortizes the per-slice jit dispatch
 # and host->device upload that starved the device at high fleet rates.
 _fleet_megastep = functools.partial(jax.jit, donate_argnums=(0,))(
@@ -395,7 +390,11 @@ class DocBatchEngine:
         # outgrew the batch geometry must not re-pay the export/pack cost
         # at a fixed cadence forever).
         self._readmit_interval: dict[int, int] = {}
-        self.counters = HealthCounters(telemetry)
+        # The row-slot pair is in every health line, 0 included: its
+        # reader takes a window delta from the first line on.
+        self.counters = HealthCounters(
+            telemetry, row_slots_scanned=0, row_slots_dense=0
+        )
         # Sampled hot-path timing through the reference's sampled-telemetry
         # shape (one event per N steps; flush_all drains the tail at
         # shutdown / status-snapshot time via ``flush_telemetry``).
@@ -536,13 +535,21 @@ class DocBatchEngine:
         # Single-chip optimization: under a mesh the doc axis is sharded
         # evenly and arbitrary-index gathers would cross shards.
         self.bucketing = self.mesh is None
-        # Leaving the fleet-wide regime takes two small busy sets in a row:
-        # the first one after a fleet-wide step runs fleet-wide once more
-        # (one slice).  A busy set that hovers around the threshold would
-        # otherwise alternate between programs, and the tail of a wide burst
-        # (its last partial loop, a drain) would pay the first trace of a
-        # cohort size it may never see again.
-        self._wide_regime = False
+        # No first dispatch while serving where a built program can do the
+        # work: a busy set whose cohort shape (lanes, K) this engine has not
+        # dispatched yet, and whose every queue fits one slice, is stepped by
+        # the fleet-wide K = 1 program instead, once that one is built
+        # (_step_fleet).  The row loop ends at the deepest queue, so a
+        # shallow fleet-wide slice costs a fraction of a second; a shape's
+        # first dispatch traces, lowers and loads for seconds and holds up
+        # every document's ops meanwhile.  So the tail of a wide burst and a
+        # busy set that hovers around the threshold reach no cohort program
+        # they would have to build; warmup(), a ladder of bursts before the
+        # fleet-wide program exists, and a backlog deeper than one slice
+        # (there the dense fleet-wide slices would cost more than the build)
+        # are what builds a cohort shape.
+        self._built: set[tuple[int, int]] = set()  # cohort (lanes, K)
+        self._full_built = False  # the fleet-wide K = 1 program
         self.full_steps = 0     # fleet-wide steps taken
         self.cohort_steps = 0   # bucketed steps taken
         self.cohort_lanes = 0   # sum of cohort sizes (work proxy)
@@ -1197,17 +1204,19 @@ class DocBatchEngine:
         payloads: np.ndarray,
         rows: list[int] | None = None,
         slots: bool = False,
-    ) -> list[int]:
+    ) -> tuple[list[int], int]:
         """Dequeue up to ops_per_step ops per listed doc into the padded
         arrays (``docs[j]`` fills row ``rows[j]``, default ``j``) — the
         ONE drain used by full-fleet, cohort, and megastep packing (their
         semantics must never diverge).  Vectorized: each doc moves as two
         slice copies (op rows + payload rows), never a per-op Python loop.
         The caller guarantees the target rows are zeroed
-        (StagingRing.acquire); returns the rows written so a reused buffer
-        re-zeroes exactly those."""
+        (StagingRing.acquire); returns the rows written, so a reused buffer
+        re-zeroes exactly those, and the deepest take: each queue fills a
+        prefix of the slots, so that is where the slice's row loop ends."""
         B = self.ops_per_step
         written: list[int] = []
+        deepest = 0
         for j, d in enumerate(docs):
             h = self.hosts[d]
             take = min(B, len(h.queue))
@@ -1224,7 +1233,15 @@ class DocBatchEngine:
             if not h.queue:
                 self._busy.discard(d)
             written.append(r)
-        return written
+            deepest = max(deepest, take)
+        return written, deepest
+
+    def _count_row_slots(self, scanned: int, slices: int = 1) -> None:
+        """How often the row loop's data trip count engages: the row slots
+        ``slices`` dispatched slices loop over against the ``ops_per_step``
+        each a dense loop would (``row_slots_scanned_share``)."""
+        self.counters.bump("row_slots_scanned", scanned)
+        self.counters.bump("row_slots_dense", slices * self.ops_per_step)
 
     def _staging(self) -> StagingRing:
         if self._stage is None:
@@ -1286,13 +1303,13 @@ class DocBatchEngine:
             # own docs and the shard-layout upload splits per chip with no
             # reshuffle.
             rows = [int(s) for s in self._slot[busy]]
+            scanned = 0
             for k in range(K):
-                stage.mark(
-                    k,
-                    self._drain_into(
-                        busy, ops[k], payloads[k], rows=rows, slots=True
-                    ),
+                written, deepest = self._drain_into(
+                    busy, ops[k], payloads[k], rows=rows, slots=True
                 )
+                stage.mark(k, written)
+                scanned += deepest
                 if k + 1 < K:
                     pairs = [
                         (d, r) for d, r in zip(busy, rows) if d in self._busy
@@ -1301,19 +1318,24 @@ class DocBatchEngine:
                     rows = [r for _, r in pairs]
         if self.mesh is None and K == 1:
             dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
-            with span("dispatch", kind="full", k=K):
+            with span("dispatch", kind="full", k=K, rows=scanned):
                 self.state = self._step(self.state, dev_ops, dev_payloads)
+            self._full_built = True
         else:
             # The mesh path always dispatches the [K, D, B] megastep
             # program (K=1 included — apply_megastep at K=1 is bit-
             # identical to one apply_ops dispatch): one donated shard_map
             # call steps every chip, zero hot-path collectives.
             dev_ops, dev_payloads = stage.upload(ops, payloads)
-            with span("dispatch", kind="full", k=K, shards=self.n_shards):
+            with span(
+                "dispatch", kind="full", k=K, rows=scanned,
+                shards=self.n_shards,
+            ):
                 self.state = self._megastep(self.state, dev_ops, dev_payloads)
         self.full_steps += K
         self.counters.bump("megastep_dispatches")
         self.counters.bump("megastep_slices", K)
+        self._count_row_slots(scanned, K)
         return K
 
     def step(self) -> int:
@@ -1364,10 +1386,9 @@ class DocBatchEngine:
         while self._busy:
             busy = sorted(self._busy)
             if not (self.bucketing and len(busy) <= self.capacity // 4):
-                self._wide_regime = self.bucketing
                 steps += self._full_step(busy)
-            elif self._wide_regime:
-                self._wide_regime = False
+            elif self._cold_and_shallow(busy):
+                self.counters.bump("cohort_cold_fallbacks")
                 steps += self._full_step(busy, k_max=1)
             else:
                 steps += self._cohort_step(busy)
@@ -1394,6 +1415,19 @@ class DocBatchEngine:
             if self.sampled is not None:
                 self.sampled.record(time.perf_counter() - t0, "step")
         return steps
+
+    @staticmethod
+    def _cohort_lanes(n_busy: int) -> int:
+        return max(1, 1 << (n_busy - 1).bit_length())  # pow2 ladder
+
+    def _cold_and_shallow(self, busy: list[int]) -> bool:
+        """The busy set's cohort program would be dispatched for the first
+        time, the fleet-wide K = 1 program is built, and one slice of it
+        clears every queue (see __init__)."""
+        lanes = self._cohort_lanes(len(busy))
+        if not self._full_built or (lanes, 1) in self._built:
+            return False
+        return max(len(self.hosts[d].queue) for d in busy) <= self.ops_per_step
 
     def _maybe_readmit(self) -> None:
         """Backoff-scheduled quarantine readmission (see __init__)."""
@@ -1422,7 +1456,7 @@ class DocBatchEngine:
         pair as well as the dispatch.  Returns the slices applied."""
         with span("pack", kind="cohort", docs=len(busy)):
             K = self._select_k(busy, cohort=True)
-            Kc = max(1, 1 << (len(busy) - 1).bit_length())  # pow2 ladder
+            Kc = self._cohort_lanes(len(busy))
             idx = np.full((Kc,), busy[-1], np.int32)  # gather pad: dup
             idx[: len(busy)] = busy
             valid = np.zeros((Kc,), bool)
@@ -1431,34 +1465,35 @@ class DocBatchEngine:
             ops, payloads = stage.acquire(K, Kc)
             row_of = {d: j for j, d in enumerate(busy)}
             cur = busy
+            scanned = 0
             for k in range(K):
-                stage.mark(
-                    k,
-                    self._drain_into(
-                        cur, ops[k], payloads[k],
-                        rows=[row_of[d] for d in cur],
-                    ),
+                written, deepest = self._drain_into(
+                    cur, ops[k], payloads[k], rows=[row_of[d] for d in cur]
                 )
+                stage.mark(k, written)
+                scanned += deepest
                 if k + 1 < K:
                     cur = [d for d in cur if d in self._busy]
         with span("gather", lanes=Kc):
             sub = self._gather_cohort(self.state, jnp.asarray(idx))
         if K == 1:
             dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
-            with span("dispatch", kind="cohort", k=K, lanes=Kc):
+            with span("dispatch", kind="cohort", k=K, lanes=Kc, rows=scanned):
                 sub = self._step(sub, dev_ops, dev_payloads)
         else:
             dev_ops, dev_payloads = stage.upload(ops, payloads)
-            with span("dispatch", kind="cohort", k=K, lanes=Kc):
+            with span("dispatch", kind="cohort", k=K, lanes=Kc, rows=scanned):
                 sub = self._megastep(sub, dev_ops, dev_payloads)
         with span("scatter", lanes=Kc):
             self.state = self._scatter_cohort(
                 self.state, sub, jnp.asarray(idx), jnp.asarray(valid)
             )
+        self._built.add((Kc, K))
         self.cohort_steps += K
         self.cohort_lanes += K * Kc
         self.counters.bump("megastep_dispatches")
         self.counters.bump("megastep_slices", K)
+        self._count_row_slots(scanned, K)
         return K
 
     def _step_lanes(self) -> None:
@@ -1481,10 +1516,11 @@ class DocBatchEngine:
                 dev_ops, dev_payloads = stage.upload(
                     ops[0, 0], payloads[0, 0]
                 )
-                with span("dispatch", kind="lane"):
+                with span("dispatch", kind="lane", rows=take):
                     lane.state = self._lane_apply(
                         lane.state, dev_ops, dev_payloads
                     )
+                self._count_row_slots(take)
 
     # -------------------------------------------------------- segment lanes
     def _step_seg_lanes(self) -> None:
@@ -2706,10 +2742,9 @@ class DocBatchEngine:
         ``megastep_k`` plus one compact through the exact serving entry
         points, so a promoted standby pays ZERO XLA compiles on its first
         real dispatch.  NOOP slices are identity by kernel contract, so
-        state bytes are untouched.  Cohort-bucketed executables (mesh-less
-        Zipf tails) still compile on first use — they are per-cohort-size
-        and cheap relative to the fleet programs.  Returns the number of
-        warmup dispatches run."""
+        state bytes are untouched.  Mesh-less fleets also get every cohort
+        size at K = 1; a cohort megastep (K > 1) still compiles on first
+        use.  Returns the number of warmup dispatches run."""
         warmed = 0
         with self.ckpt_lock, span("warmup", k_max=self.megastep_k):
             stage = self._staging()
@@ -2718,7 +2753,26 @@ class DocBatchEngine:
                 ops, payloads = stage.acquire(1, self.capacity)
                 dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
                 self.state = self._step(self.state, dev_ops, dev_payloads)
+                self._full_built = True
                 warmed += 1
+            if self.bucketing and self.capacity >= 4:
+                # Every cohort size at K = 1 (gather, step, masked scatter
+                # that keeps no lane): serving builds none of them once the
+                # fleet-wide program above exists (_cold_and_shallow).
+                lanes = 1
+                while lanes <= self._cohort_lanes(self.capacity // 4):
+                    idx = jnp.asarray(np.zeros((lanes,), np.int32))
+                    ops, payloads = stage.acquire(1, lanes)
+                    dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
+                    sub = self._gather_cohort(self.state, idx)
+                    sub = self._step(sub, dev_ops, dev_payloads)
+                    self.state = self._scatter_cohort(
+                        self.state, sub, idx,
+                        jnp.asarray(np.zeros((lanes,), bool)),
+                    )
+                    self._built.add((lanes, 1))
+                    warmed += 1
+                    lanes *= 2
             depths = []
             k = 1
             while k <= self.megastep_k:

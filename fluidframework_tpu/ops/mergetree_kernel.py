@@ -27,8 +27,11 @@ Design notes (TPU):
   layout assignment can pick the small axis as minor even for [R,S].  Tuples
   of 2-D-after-vmap leaves make every layout trivially optimal.
 - Within one document, ops are inherently sequential (each op's position
-  depends on prior ops); `lax.scan` applies an op batch per doc.  The
-  document axis supplies the parallelism (`vmap`, sharded by `shard_map`).
+  depends on prior ops); a `lax.fori_loop` applies an op batch row slot by
+  row slot and ends at the deepest queue of the batch (``row_count``: the
+  trip count is data), not at the batch's width.  The document axis supplies
+  the parallelism: the row is `vmap`ped INSIDE the loop (``apply_fleet_ops``;
+  one trip count for every document), the fleet sharded by `shard_map`.
 - Mutation = masked gather/select: inserting a segment shifts the suffix of
   every per-segment array by one slot (a vectorized O(S) move, not a
   data-dependent loop).
@@ -718,7 +721,7 @@ def _do_ack(s: DocState, op, payload) -> DocState:
 # step's time by it (benchmark/host_plane.py; the ``kernel_*_share`` metrics).
 # What every row runs whatever its kind (the perspective's geometry, the two
 # boundary splits, the range mask) is under ``SHARED_SCOPE``, which names no
-# kind; the scan's carry has no scope at all.  The persistent compile cache
+# kind; the row loop's carry has no scope at all.  The persistent compile cache
 # has to key on this metadata (utils/compile_cache.py), or a cached executable
 # keeps its old names.
 BRANCH_SCOPES = ("insert", "remove", "annotate", "ack", "obliterate")
@@ -764,7 +767,7 @@ def _text_write_indices(writes: _TextWrite, width: int, capacity: int):
 def _write_text(text, writes: _TextWrite, payloads):
     """Apply the text writes of a batch of rows ([B] starts and counts,
     [B, L] payloads) to the pool in ONE scatter.  The pool is append-only
-    and nothing in the op body reads it, so the scan need not carry it: a
+    and nothing in the op body reads it, so the row loop need not carry it: a
     scatter into [D, T] per row costs the TPU three passes over the whole
     pool (it is relaid out for the scatter and back)."""
     dst = _text_write_indices(writes, payloads.shape[1], text.shape[0])
@@ -779,7 +782,7 @@ def _write_text(text, writes: _TextWrite, payloads):
 def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
     """The op body: one straight-line program for every kind.  Returns the
     new state and the row's ``_TextWrite``; ``s.text`` is never looked at
-    (``apply_ops`` keeps the pool out of the scan's carry).
+    (``apply_ops`` keeps the pool out of the loop's carry).
 
     Under ``vmap`` a ``lax.switch`` on the row's kind runs every branch for
     every row and selects between whole ``DocState``s, text pool included.
@@ -849,7 +852,7 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
             jnp.where(fits, jnp.clip(text_len, 0, payload.shape[0]), 0),
         )
         # The [OB,S] swallow analysis only traces when an obliterate can
-        # exist (apply_ops hoists the runtime branch to whole-scan level).
+        # exist (apply_ops hoists the runtime branch to whole-loop level).
         new_rem_k, new_rem_c, obpre, swallow_over = (
             _obliterate_new_segment(s, k, key, client, ref_seq)
             if flag
@@ -944,6 +947,85 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
         ), write
 
 
+def row_count(ops: jnp.ndarray) -> jnp.ndarray:
+    """The row loop's trip count for a batch of op rows ([..., B, OP_FIELDS],
+    any leading document axes): 1 + the index of the last slot whose kind is
+    not NOOP in ANY document, 0 where every slot is a NOOP.  The engines
+    pack a queue into a prefix of the slots, so this is the deepest queue of
+    the batch; an interior NOOP still runs as a row."""
+    live = ops[..., 0] != OpKind.NOOP
+    live = jnp.any(live.reshape(-1, live.shape[-1]), axis=0)
+    slots = jnp.arange(1, live.shape[0] + 1, dtype=I32)
+    return jnp.max(jnp.where(live, slots, 0), initial=0)
+
+
+def _ob_gate(s: DocState, ops: jnp.ndarray) -> jnp.ndarray:
+    """The obliterate gate of a batch: any table nonempty | any op in the
+    batch is an OBLITERATE (one scalar whatever the leading axes)."""
+    return jnp.any(s.ob_key >= 0) | jnp.any(ops[..., 0] == OpKind.OBLITERATE)
+
+
+def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool) -> DocState:
+    """The row loop of ``apply_ops`` (one document) and, with ``over_docs``,
+    of ``apply_fleet_ops`` (every leaf, ``ops`` and ``payloads`` lead with
+    the document axis): a ``lax.fori_loop`` over the first ``row_count(ops)``
+    of the B row slots.  The loop is OUTSIDE the ``vmap`` over documents and
+    its body is the vmapped row: one trip count for the whole batch, and the
+    row is traced once (``vmap`` of a ``while`` batches its body twice, and
+    a batched bound would make it select over the whole carried state every
+    iteration)."""
+    lift = jax.vmap if over_docs else (lambda f: f)
+    B, T = ops.shape[-2], s.text.shape[-1]
+    slot = jnp.arange(B, dtype=I32)
+    rows = row_count(ops)
+
+    def loop_spec(st: DocState, flag: bool) -> DocState:
+        # Staged before it is vmapped, so that a row's scopes reach the
+        # instructions' ``op_name`` as written (``.../insert/...``, not
+        # ``vmap(insert)``): the device trace groups by them.
+        @jax.jit
+        def row(st, op, payload):
+            return _apply_row(st, op, payload, flag, T)
+
+
+        def step(i, carry):
+            st, writes = carry
+            op = jax.lax.dynamic_index_in_dim(ops, i, -2, keepdims=False)
+            payload = jax.lax.dynamic_index_in_dim(payloads, i, -2, keepdims=False)
+            st, write = lift(row)(st, op, payload)
+            # The row's text write goes to slot i of the [B] starts and
+            # counts as a masked write (a single-element dynamic update is
+            # a scatter over documents).  Slots the loop never reaches keep
+            # count 0: they write nothing.
+            return st, jax.tree.map(
+                lambda w, arr: jnp.where(slot == i, w[..., None], arr),
+                write, writes,
+            )
+
+        none = jnp.zeros(ops.shape[:-1], I32)
+        out, writes = jax.lax.fori_loop(
+            0, rows, step,
+            (
+                st._replace(text=jnp.zeros(st.text.shape[:-1] + (0,), I32)),
+                _TextWrite(none, none),
+            ),
+        )
+        return out._replace(text=lift(_write_text)(st.text, writes, payloads))
+
+    if isinstance(ob_flag, bool):
+        return loop_spec(s, ob_flag)
+    # Hoist the runtime branch to WHOLE-LOOP level: one cond per batch
+    # instead of two per op, so the common no-obliterate path is a single
+    # fully-fused loop body (conds inside a loop break XLA fusion and were
+    # costing ~2x on obliterate-free workloads).
+    return jax.lax.cond(
+        ob_flag,
+        lambda st: loop_spec(st, True),
+        lambda st: loop_spec(st, False),
+        s,
+    )
+
+
 def apply_op(
     s: DocState, op: jnp.ndarray, payload: jnp.ndarray, ob_flag=None
 ) -> DocState:
@@ -951,13 +1033,15 @@ def apply_op(
 
     ``ob_flag`` gates the obliterate machinery off the hot path: it must be
     True whenever the ob table may be nonempty or this op may be an
-    OBLITERATE (default: computed per doc).  A PYTHON bool specializes the
-    trace outright (``apply_ops`` hoists the runtime branch to whole-scan
-    level, so the op body stays one program with no interior cond); a traced
-    flag picks between the two traces with one ``lax.cond`` and so must be a
-    scalar computed OUTSIDE any vmap (any doc's table nonempty | any op in
-    the batch is OBLITERATE): a batched predicate would degrade the cond to
-    select-of-both.
+    OBLITERATE (default: computed from this document and this op).  A PYTHON
+    bool specializes the trace outright; a traced flag picks between the two
+    traces with one ``lax.cond`` at whole-loop level (``_row_loop``), so the
+    op body stays one program with no interior cond.  Both the gate and the
+    row loop's trip count are scalars of everything the call is given: for a
+    batch of documents call ``apply_fleet_ops``, never ``vmap`` of this (a
+    batched predicate degrades the cond to select-of-both, a batched bound
+    the loop to run-to-the-maximum with a select over the whole state per
+    iteration).  Here B = 1: the row runs unless it is a NOOP.
     """
     return apply_ops(s, op[None], payload[None], ob_flag)
 
@@ -965,69 +1049,70 @@ def apply_op(
 def apply_ops(
     s: DocState, ops: jnp.ndarray, payloads: jnp.ndarray, ob_flag=None
 ) -> DocState:
-    """Apply a batch of ops to one document, in order (lax.scan).
+    """Apply a batch of ops to ONE document, in order: a ``lax.fori_loop``
+    over the first ``row_count(ops)`` of the B row slots, ``_apply_row`` its
+    body.  A batch costs its depth, not its width: a NOOP row costs what any
+    row costs, and the engines fill a prefix of the slots.
 
     ops: int32[B, OP_FIELDS]; payloads: int32[B, MAX_INSERT_LEN].
-    This is the per-document sequential spine; parallelism comes from
-    `jax.vmap(apply_ops)` over a leading document axis (pass ``ob_flag``
-    with in_axes=None — see apply_op).
+    This is the per-document sequential spine (the overflow and quarantine
+    lanes, replay); the parallelism over documents is ``apply_fleet_ops``,
+    not ``vmap`` of this (see ``apply_op``).
     """
     if ob_flag is None:
-        ob_flag = jnp.any(s.ob_key >= 0) | jnp.any(ops[:, 0] == OpKind.OBLITERATE)
+        ob_flag = _ob_gate(s, ops)
+    return _row_loop(s, ops, payloads, ob_flag, over_docs=False)
 
-    def scan_spec(st: DocState, flag: bool) -> DocState:
-        T = st.text.shape[0]
 
-        def step(carry, xs):
-            op, payload = xs
-            return _apply_row(carry, op, payload, flag, T)
+def apply_fleet_ops(
+    s: DocState, ops: jnp.ndarray, payloads: jnp.ndarray, ob_flag=None
+) -> DocState:
+    """Apply one [D, B] slice of ops to a [D, ...] batch of documents: the
+    same row loop with the row vmapped over the document axis INSIDE it.
 
-        out, writes = jax.lax.scan(
-            step, st._replace(text=jnp.zeros((0,), I32)), (ops, payloads)
-        )
-        return out._replace(text=_write_text(st.text, writes, payloads))
+    The step ends at the deepest queue of the batch: the trip count
+    (``row_count``) and the obliterate gate (``ob_flag``, default: any
+    document's table nonempty | any op of the slice an OBLITERATE) are
+    scalars of the whole slice, taken outside the ``vmap``, so the loop is a
+    ``while`` on one predicate for every document.  A slice that fills all B
+    slots of some document runs B iterations; interior NOOPs (a document
+    with fewer ops than the deepest) run as rows.  Bit-identical, padding
+    slots and the error latch included, to applying each document's ops with
+    ``apply_ops`` under the same gate.
 
-    if isinstance(ob_flag, bool):
-        return scan_spec(s, ob_flag)
-    # Hoist the runtime branch to WHOLE-SCAN level: one cond per batch
-    # instead of two per op, so the common no-obliterate path is a single
-    # fully-fused scan body (conds inside a scan break XLA fusion and were
-    # costing ~2x on obliterate-free workloads).
-    return jax.lax.cond(
-        ob_flag,
-        lambda st: scan_spec(st, True),
-        lambda st: scan_spec(st, False),
-        s,
-    )
+    ops: int32[D, B, OP_FIELDS]; payloads: int32[D, B, L].
+    """
+    if ob_flag is None:
+        ob_flag = _ob_gate(s, ops)
+    return _row_loop(s, ops, payloads, ob_flag, over_docs=True)
 
 
 def apply_megastep(
     s: DocState, ops: jnp.ndarray, payloads: jnp.ndarray
 ) -> DocState:
     """Apply a [K, D, B] op ring to a [D, ...] document batch in ONE fused
-    program: ``lax.scan`` over the K slice axis, ``vmap`` over the D doc
-    axis inside the scan body.
+    program: ``lax.scan`` over the K slice axis, ``apply_fleet_ops`` its
+    body.
 
     This is the megastep dispatch amortizer: where the per-slice path pays
     one jit dispatch + one host->device upload per [D, B] slice, a megastep
     pays them once per K slices — error bits latch into the carried state
     on device and are read back once per megastep, never per slice.
 
-    Semantics are bit-identical to K sequential ``apply_ops`` dispatches:
-    each slice's obliterate gate is the same whole-batch scalar the
-    per-slice dispatch computes (any doc's ob table nonempty | any op in
-    the slice is an OBLITERATE), re-evaluated per slice from the CARRIED
-    state — hoisting it to the scan carry keeps the common no-obliterate
-    slice a single fully-fused scan body (see apply_ops).
+    Semantics are bit-identical to K sequential ``apply_fleet_ops``
+    dispatches: each slice's obliterate gate is the same whole-batch scalar
+    the per-slice dispatch computes, re-evaluated per slice from the CARRIED
+    state, and each slice's row loop takes its own trip count: a shallow
+    last slice costs its own depth.  Wrapped in ``shard_map``
+    (parallel.mesh) both scalars are a shard's own, taken from its slice of
+    the documents: the row loop holds no collective, so shards may run
+    different counts.
 
     ops: int32[K, D, B, OP_FIELDS]; payloads: int32[K, D, B, L].
     """
 
     def body(st: DocState, xs):
-        o, p = xs
-        flag = jnp.any(st.ob_key >= 0) | jnp.any(o[..., 0] == OpKind.OBLITERATE)
-        st = jax.vmap(apply_ops, in_axes=(0, 0, 0, None))(st, o, p, flag)
-        return st, None
+        return apply_fleet_ops(st, *xs), None
 
     out, _ = jax.lax.scan(body, s, (ops, payloads))
     return out

@@ -5,16 +5,16 @@ row, held to the host oracle and to its own structure.
 kernel backend applied: remote ops, local pending ops, their acks, sided
 obliterates), with illegal rows spliced in (positions out of range, inserts
 that overflow the text pool, invalid obliterates), are replayed through the
-batched kernel — ``vmap(apply_ops)`` slice by slice, and K > 1 through
+batched kernel — ``apply_fleet_ops`` slice by slice, and K > 1 through
 ``apply_megastep`` — and through ``dds/mergetree_ref.RefMergeTree``.  Every
 leaf's live content must agree: segment boundaries, text, stamps, remove
 sets, props, obliterate records, and the error latch bit for bit.  (The
 padding slots hold shift remnants and are held by no independent reference;
 (c) compares them for the cuts.)
 
-(b) The jaxpr of the vmapped scan keeps the shape the body was written for:
-at most two cumulative sums and two rewrites of each per-segment column a
-row, no select over the text pool (which the scan does not even carry: one
+(b) The jaxpr of the fleet's row loop keeps the shape the body was written
+for: at most two cumulative sums and two rewrites of each per-segment column
+a row, no select over the text pool (which the loop does not even carry: one
 scatter after it is the pool's only write), no ``cond``/``switch`` on a
 batched predicate.
 
@@ -312,24 +312,31 @@ def _eqns(jaxpr):
 
 
 def _step_program(flag, n_docs=3):
-    """(every equation of the vmapped ``apply_ops``, those of its scan's
-    body, the scan itself, documents in the batch)."""
+    """(every equation of ``apply_fleet_ops``, those of its row loop's
+    body, the loop itself, documents in the batch).  The loop is a ``while``
+    since its trip count became data (``mk.row_count``), and the vmapped row
+    one ``jit`` equation (``row``) of its body: tests/test_row_loop_depth.py
+    holds both."""
     fleet = _fleet(n_docs)
     ops = jnp.zeros((n_docs, B, mk.OP_FIELDS), jnp.int32)
     pays = jnp.zeros((n_docs, B, L), jnp.int32)
-    step = jax.vmap(functools.partial(mk.apply_ops, ob_flag=flag))
+    step = functools.partial(mk.apply_fleet_ops, ob_flag=flag)
     every = list(_eqns(jax.make_jaxpr(step)(fleet, ops, pays).jaxpr))
-    scans = [e for e in every if e.primitive.name == "scan"]
-    assert len(scans) == 1
-    return every, list(_eqns(scans[0].params["jaxpr"].jaxpr)), scans[0], n_docs
+    loops = [e for e in every if e.primitive.name == "while"]
+    assert len(loops) == 1
+    return (every, list(_eqns(loops[0].params["body_jaxpr"].jaxpr)),
+            loops[0], n_docs)
 
 
-def _shifted_versions(scan, n_docs):
-    """Per [n_docs, S] column the scan carries: how many versions of it the
-    row body reads shifted along the segment axis (a ``concatenate`` of
+def _shifted_versions(loop, n_docs):
+    """Per [n_docs, S] column the row loop carries: how many versions of it
+    the row body reads shifted along the segment axis (a ``concatenate`` of
     ``slice``s of one array, which is how a slot is opened).  One version is
     one rewrite of the column, however many slots the rewrite opens."""
-    body = scan.params["jaxpr"].jaxpr
+    (row,) = [e for e in loop.params["body_jaxpr"].jaxpr.eqns
+              if e.primitive.name in ("jit", "pjit")
+              and e.params["name"] == "row"]
+    body = row.params["jaxpr"].jaxpr
     col = (n_docs, S)
 
     def is_col(v):
@@ -388,7 +395,7 @@ def test_vmapped_scan_body_structure(guard, flag):
         # Nothing selects between pools, anywhere in the program ...
         assert not [e for e in every
                     if e.primitive.name == "select_n" and touches(e)]
-        # ... the scan neither carries nor touches the pool ...
+        # ... the row loop neither carries nor touches the pool ...
         assert not touches(scan)
         assert not [e for e in body if touches(e)]
         # ... and one scatter after it is the only write.

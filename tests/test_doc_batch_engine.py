@@ -141,19 +141,20 @@ def test_zipf_bucketing_cuts_full_fleet_steps():
     )
 
 
-def test_leaving_the_fleet_wide_regime_takes_two_small_loops():
-    """The first small busy set after a fleet-wide step runs fleet-wide once
-    more, one slice; the second runs as a cohort.  So the tail of a wide
-    burst (its last partial loop) reaches no cohort program it would have
-    to trace, and a fleet that never went wide never pays a wide step."""
+def test_a_cold_cohort_shape_is_not_built_where_one_fleet_wide_slice_does():
+    """Once the fleet-wide program is built, a small busy set whose cohort
+    shape was never dispatched and whose queues fit one slice is stepped
+    fleet-wide: serving pays no first dispatch it can avoid.  A fleet that
+    never went wide builds its cohorts as it meets them, and so does
+    warmup()."""
     n_docs = 16
     svc, expected = drive_docs(n_docs, seed=3)
     logs = [list(svc.document(f"doc{d}").sequencer.log) for d in range(n_docs)]
 
-    def engine():
+    def engine(ops_per_step=64):
         return DocBatchEngine(
             n_docs, max_segments=256, text_capacity=4096, max_insert_len=8,
-            ops_per_step=64, use_mesh=False, megastep_k=1,
+            ops_per_step=ops_per_step, use_mesh=False, megastep_k=1,
         )
 
     def feed(eng, d, msgs):
@@ -161,11 +162,27 @@ def test_leaving_the_fleet_wide_regime_takes_two_small_loops():
             eng.ingest(d, msg)
         return d in eng._busy
 
+    def taken(eng):
+        return (
+            eng.full_steps, eng.cohort_steps,
+            eng.health().get("cohort_cold_fallbacks", 0),
+        )
+
     # Narrow from the start: cohorts only.
     eng = engine()
     assert feed(eng, 0, logs[0])
     eng.step()
-    assert (eng.full_steps, eng.cohort_steps) == (0, 1)
+    assert taken(eng) == (0, 1, 0)
+
+    # A backlog deeper than one slice builds its cohort: dense fleet-wide
+    # slices would cost more than the build.
+    eng = engine(ops_per_step=2)
+    eng._full_built = True
+    assert feed(eng, 0, logs[0]) and len(eng.hosts[0].queue) > 2
+    assert not eng._cold_and_shallow([0])
+    eng.step()
+    assert eng.full_steps == 0 and eng.cohort_steps >= 2
+    assert eng.text(0) == expected[0]
 
     # Wide, then loops of one busy document each.
     eng = engine()
@@ -175,18 +192,24 @@ def test_leaving_the_fleet_wide_regime_takes_two_small_loops():
         feed(probe, d, logs[d][:cut[d]])
     probe.step()
     tails = [d for d in range(n_docs) if feed(probe, d, logs[d][cut[d]:])]
-    first, second = tails[:2]
+    first, second, third = tails[:3]
     assert sum(feed(eng, d, logs[d][:cut[d]]) for d in range(n_docs)) > 4
     eng.step()
-    assert (eng.full_steps, eng.cohort_steps) == (1, 0)
+    assert taken(eng) == (1, 0, 0)
     assert feed(eng, first, logs[first][cut[first]:])
     eng.step()
-    assert (eng.full_steps, eng.cohort_steps) == (2, 0)
+    assert taken(eng) == (2, 0, 1)
     assert feed(eng, second, logs[second][cut[second]:])
     eng.step()
-    assert (eng.full_steps, eng.cohort_steps) == (2, 1)
+    assert taken(eng) == (3, 0, 2)
+    before = [eng.text(d) for d in range(n_docs)]
+    eng.warmup()
+    assert [eng.text(d) for d in range(n_docs)] == before
+    assert feed(eng, third, logs[third][cut[third]:])
+    eng.step()
+    assert taken(eng) == (3, 1, 2)
     for d in range(n_docs):
-        if d not in (first, second):
+        if d not in (first, second, third):
             feed(eng, d, logs[d][cut[d]:])
     eng.step()
     assert not eng.errors().any()

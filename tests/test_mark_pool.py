@@ -475,7 +475,7 @@ def test_host_fold_subphase_spans_recorded():
             eng.ingest(0, m)
         shares = fr.phase_shares(rec.events())
     finally:
-        fr.install(fr.FlightRecorder(capacity=1))  # detach-equivalent
+        fr.uninstall()
     for phase in ("host_fold_mark_alloc", "host_fold_rebase",
                   "host_fold_translate"):
         assert phase in shares, shares
