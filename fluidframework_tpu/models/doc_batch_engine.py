@@ -450,16 +450,17 @@ class DocBatchEngine:
         # Per-shard applied-op counters (host-side, no device readback):
         # accumulated at drain time, the hot-shard detection signal.
         self._shard_ops = np.zeros((n_shards,), np.int64)
+        # The other side of a mesh's skew: per shard, the deepest take
+        # among its documents, summed over the fleet-wide slices packed.
+        # It is where that shard's own row loop ends (``mk.row_count`` of
+        # its rows under ``shard_map``); never reset.
+        self._shard_row_slots = np.zeros((n_shards,), np.int64)
 
         proto = mk.init_state(
             max_segments, remove_slots, prop_slots, text_capacity, ob_slots
         )
         self._proto = proto  # pristine row: retires vacated migration slots
-        self.state = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (self.capacity,) + x.shape), proto
-        )
-        if self.mesh is not None:
-            self.state = pm.shard_fleet_state(self.state, self.mesh)
+        self.state = pm.init_fleet_state(proto, self.capacity, self.mesh)
 
         # Module-level jitted programs (shared compile cache across engine
         # instances; one executable per geometry/batch shape).
@@ -1213,10 +1214,12 @@ class DocBatchEngine:
         The caller guarantees the target rows are zeroed
         (StagingRing.acquire); returns the rows written, so a reused buffer
         re-zeroes exactly those, and the deepest take: each queue fills a
-        prefix of the slots, so that is where the slice's row loop ends."""
+        prefix of the slots, so that is where the slice's row loop ends
+        (with ``slots``, each shard's own deepest take is counted too)."""
         B = self.ops_per_step
         written: list[int] = []
         deepest = 0
+        shard_deepest = [0] * self.n_shards if slots else None
         for j, d in enumerate(docs):
             h = self.hosts[d]
             take = min(B, len(h.queue))
@@ -1228,12 +1231,18 @@ class DocBatchEngine:
             payloads[r, :take] = src_payloads
             if slots:
                 # Row IS the device slot here (full-fleet packing): charge
-                # the op count to its shard for hot-shard detection.
-                self._shard_ops[r // self.docs_per_shard] += take
+                # the op count to its shard for hot-shard detection, and
+                # keep the shard's deepest take.
+                shard = r // self.docs_per_shard
+                self._shard_ops[shard] += take
+                if take > shard_deepest[shard]:
+                    shard_deepest[shard] = take
             if not h.queue:
                 self._busy.discard(d)
             written.append(r)
             deepest = max(deepest, take)
+        if slots:
+            self._shard_row_slots += shard_deepest
         return written, deepest
 
     def _count_row_slots(self, scanned: int, slices: int = 1) -> None:
@@ -2873,6 +2882,10 @@ class DocBatchEngine:
         if self.n_shards > 1:
             ops, depth = self.shard_load()
             self.counters.gauge("shard_ops", [int(v) for v in ops])
+            self.counters.gauge(
+                "shard_row_slots_scanned",
+                [int(v) for v in self._shard_row_slots],
+            )
             self.counters.gauge(
                 "shard_queue_depth", [int(v) for v in depth]
             )

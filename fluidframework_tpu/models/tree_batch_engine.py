@@ -357,24 +357,19 @@ class TreeBatchEngine:
         self._shard_ops = np.zeros((self.n_shards,), np.int64)
         proto = tk.init_nested_forest(capacity, pool_capacity)
         self._proto = proto  # pristine row: retires vacated/re-seeded slots
-        self.state = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                x, (self.fleet_capacity,) + x.shape
-            ),
-            proto,
-        )
+        # Under a mesh: partition-rule-matched placement, each device
+        # making its own rows.
+        self.state = pm.init_fleet_state(proto, self.fleet_capacity, mesh)
         self._step = _tree_step_jit
         self._megastep = _tree_megastep_jit
         self._compact = _tree_compact_jit
         if mesh is not None:
-            # Partition-rule-matched placement + shard_map-wrapped fleet
-            # programs (parallel.mesh): one donated dispatch steps every
-            # shard, zero hot-path collectives (same machinery as the
-            # string engine).
-            self.state = pm.shard_fleet_state(self.state, mesh)
+            # shard_map-wrapped fleet programs (parallel.mesh): one
+            # donated dispatch steps every shard, zero hot-path
+            # collectives (same machinery as the string engine).
             # On a docs x segs mesh the doc dim shards over BOTH axes
             # flattened — the program specs must match the placement
-            # shard_fleet_state derives from the mesh, or the first
+            # init_fleet_state derives from the mesh, or the first
             # donated dispatch reshards the fleet.
             da = pm.fleet_doc_axes(mesh)
             specs = pm.fleet_state_specs(self.state, da)
