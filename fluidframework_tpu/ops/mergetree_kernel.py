@@ -35,6 +35,13 @@ Design notes (TPU):
 - Mutation = masked gather/select: inserting a segment shifts the suffix of
   every per-segment array by one slot (a vectorized O(S) move, not a
   data-dependent loop).
+- The text pool is append-only and no row reads it, so the row loop does not
+  carry it: the rows hand out (start, count) pairs, and after the loop each
+  document's writes go in as ONE strip around its ``text_end``, read, merged
+  and written back in place (``_write_text``; a Pallas kernel over whole tile
+  rows of documents, ops/pallas_kernels.py).  The write costs D strips of
+  B * L elements, not the pool: a scatter into [D, T] has XLA relay the whole
+  pool out to one axis and back.
 - Capacity overflow (segments, text pool, remove slots) sets an error bit
   instead of trapping; the host inspects error flags and reacts (grow +
   re-replay, or route the doc to the host oracle).
@@ -745,38 +752,84 @@ class _TextWrite(NamedTuple):
     count: jnp.ndarray
 
 
-def _text_write_indices(writes: _TextWrite, width: int, capacity: int):
-    """[B, width] pool indices of a batch of rows' text writes, with the
-    result of applying them one after another and NO index twice.  Only a
-    rejected insert (position out of range) writes without advancing
-    ``text_end``, and a later row then writes over it: such elements go to
-    ``capacity`` (dropped), so a scatter's unspecified order cannot show."""
-    B = writes.start.shape[0]
-    tpos = jnp.arange(width, dtype=I32)
-    pos = writes.start[:, None] + tpos[None, :]                    # [B, L]
-    live = tpos[None, :] < writes.count[:, None]                   # [B, L]
-    # covered[i, j]: some later row i' writes position pos[i, j] too.
-    later = jnp.arange(B)[:, None] < jnp.arange(B)[None, :]        # [B, B']
-    lo = writes.start[None, None, :]                               # [1, 1, B']
-    hi = lo + writes.count[None, None, :]
-    hit = (lo <= pos[:, :, None]) & (pos[:, :, None] < hi)         # [B, L, B']
-    covered = jnp.any(hit & later[:, None, :], axis=-1)
-    return jnp.where(live & ~covered, pos, capacity)
+def _unwritten(writes: _TextWrite, done):
+    """[D, B]: the rows that still have something to write, ``done`` [D] the
+    slots ``_write_text`` is through with."""
+    slot = jnp.arange(writes.count.shape[1], dtype=I32)
+    return (slot >= done[:, None]) & (writes.count > 0)
+
+
+def _strip_pass(text, writes: _TextWrite, payloads, done):
+    """One pass of ``_write_text``: per document, the strip around its first
+    row not written yet (slot >= ``done``), with every following row written
+    into it in order for as long as the rows lie inside it.  Returns the pool
+    and the new ``done``.
+
+    The strip's contents are composed row by row over the live prefix of the
+    slots (a later row over an earlier one: the last writer wins, as when the
+    rows write one after another), [D, strip] of work a row; then each
+    document's strip is read, merged and written back in place
+    (``write_text_strips``)."""
+    # Imported where it is traced: Pallas costs a second to import, and most
+    # processes that import this module never trace a step.
+    from . import pallas_kernels as pk
+
+    n_docs, cap = text.shape
+    n_rows, width = payloads.shape[1:]
+    strip = pk.text_strip_width(cap, min(n_rows * width, cap))
+    slot = jnp.arange(n_rows, dtype=I32)
+    todo = _unwritten(writes, done)
+    lead = jnp.min(jnp.where(todo, slot, n_rows), axis=1)
+    first = jnp.min(jnp.where(todo, writes.start, cap), axis=1)
+    at = jnp.clip(first // pk.LANES * pk.LANES, 0, cap - strip)
+    off = writes.start - at[:, None]
+    outside = todo & ((off < 0) | (off + writes.count > strip))
+    # Every pass takes its leading row (what of it lies outside the pool is
+    # dropped, as an index past the end was), so B passes end any batch.
+    stop = jnp.min(jnp.where(outside & (slot > lead[:, None]), slot, n_rows), axis=1)
+    count = jnp.where(todo & (slot < stop[:, None]), writes.count, 0)
+    rows = jnp.max(jnp.where(jnp.any(count > 0, axis=0), slot + 1, 0), initial=0)
+    lane = jnp.arange(strip, dtype=I32)
+
+    def compose(i, carry):
+        new, mask = carry
+        pick = lambda x: jax.lax.dynamic_index_in_dim(x, i, 1, keepdims=False)
+        rel = lane - pick(off)[:, None]                             # [D, strip]
+        n, pay = pick(count)[:, None], pick(payloads)
+        for j in range(width):
+            new = jnp.where((rel == j) & (j < n), pay[:, j, None], new)
+        return new, mask | ((rel >= 0) & (rel < n))
+
+    new, mask = jax.lax.fori_loop(
+        jnp.min(lead), rows, compose,
+        (jnp.zeros((n_docs, strip), I32), jnp.zeros((n_docs, strip), bool)),
+    )
+    return pk.write_text_strips(text, at, new, mask.astype(I32)), stop
 
 
 def _write_text(text, writes: _TextWrite, payloads):
-    """Apply the text writes of a batch of rows ([B] starts and counts,
-    [B, L] payloads) to the pool in ONE scatter.  The pool is append-only
-    and nothing in the op body reads it, so the row loop need not carry it: a
-    scatter into [D, T] per row costs the TPU three passes over the whole
-    pool (it is relaid out for the scatter and back)."""
-    dst = _text_write_indices(writes, payloads.shape[1], text.shape[0])
-    # The barrier keeps the [B, L] -> [B * L] reshapes out of the scatter's
-    # fusion: fused, the fleet-wide program compiles in 70 s, not 17.
-    dst, vals = jax.lax.optimization_barrier(
-        (dst.reshape(-1), payloads.reshape(-1))
+    """Apply the text writes of a batch of rows to the pools of D documents:
+    text [D, T], starts and counts [D, B], payloads [D, B, L].  The result is
+    the rows' writes applied one after another, element for element.
+
+    The pool is append-only and nothing in the op body reads it, so the row
+    loop does not carry it, and a row writes at its document's ``text_end``,
+    which only moves forward and by at most L a row (``encode_insert``,
+    ``encode_insert_batch`` and native/ingest.cpp all chunk an insert to L,
+    and no other path makes a device row).  So all writes of one document in
+    one batch fall into one strip of B * L elements, and the write costs D
+    strips, not the pool: a per-element scatter into [D, T] cost the TPU
+    three passes over the whole pool (relaid to one axis, scattered, relaid
+    back), a fifth of a shallow fleet-wide step.  A hand-made row whose
+    ``text_len`` exceeds L moves the next starts past the strip; such rows
+    take a further pass each, so the loop below runs once for every batch an
+    engine can stage, and not at all for a batch that writes nothing."""
+    text, _ = jax.lax.while_loop(
+        lambda carry: jnp.any(_unwritten(writes, carry[1])),
+        lambda carry: _strip_pass(carry[0], writes, payloads, carry[1]),
+        (text, jnp.zeros(text.shape[:1], I32)),
     )
-    return text.at[dst].set(vals, mode="drop")
+    return text
 
 
 def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
@@ -973,20 +1026,21 @@ def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool) -> DocState:
     its body is the vmapped row: one trip count for the whole batch, and the
     row is traced once (``vmap`` of a ``while`` batches its body twice, and
     a batched bound would make it select over the whole carried state every
-    iteration)."""
+    iteration).  The loop carries every leaf but the text pool and collects
+    the rows' ``_TextWrite``s; the pool is written once, after the loop and
+    after the obliterate gate's ``cond`` (``_write_text``)."""
     lift = jax.vmap if over_docs else (lambda f: f)
     B, T = ops.shape[-2], s.text.shape[-1]
     slot = jnp.arange(B, dtype=I32)
     rows = row_count(ops)
 
-    def loop_spec(st: DocState, flag: bool) -> DocState:
+    def loop_spec(st: DocState, flag: bool):
         # Staged before it is vmapped, so that a row's scopes reach the
         # instructions' ``op_name`` as written (``.../insert/...``, not
         # ``vmap(insert)``): the device trace groups by them.
         @jax.jit
         def row(st, op, payload):
             return _apply_row(st, op, payload, flag, T)
-
 
         def step(i, carry):
             st, writes = carry
@@ -1003,27 +1057,27 @@ def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool) -> DocState:
             )
 
         none = jnp.zeros(ops.shape[:-1], I32)
-        out, writes = jax.lax.fori_loop(
-            0, rows, step,
-            (
-                st._replace(text=jnp.zeros(st.text.shape[:-1] + (0,), I32)),
-                _TextWrite(none, none),
-            ),
-        )
-        return out._replace(text=lift(_write_text)(st.text, writes, payloads))
+        return jax.lax.fori_loop(0, rows, step, (st, _TextWrite(none, none)))
 
+    # The pool stays outside the loop and outside the gate's ``cond``: the
+    # rows' writes do not depend on the flag, and go in after both.
+    bare = s._replace(text=jnp.zeros(s.text.shape[:-1] + (0,), I32))
     if isinstance(ob_flag, bool):
-        return loop_spec(s, ob_flag)
-    # Hoist the runtime branch to WHOLE-LOOP level: one cond per batch
-    # instead of two per op, so the common no-obliterate path is a single
-    # fully-fused loop body (conds inside a loop break XLA fusion and were
-    # costing ~2x on obliterate-free workloads).
-    return jax.lax.cond(
-        ob_flag,
-        lambda st: loop_spec(st, True),
-        lambda st: loop_spec(st, False),
-        s,
-    )
+        out, writes = loop_spec(bare, ob_flag)
+    else:
+        # Hoist the runtime branch to WHOLE-LOOP level: one cond per batch
+        # instead of two per op, so the common no-obliterate path is a single
+        # fully-fused loop body (conds inside a loop break XLA fusion and
+        # were costing ~2x on obliterate-free workloads).
+        out, writes = jax.lax.cond(
+            ob_flag,
+            lambda st: loop_spec(st, True),
+            lambda st: loop_spec(st, False),
+            bare,
+        )
+    fleet = (lambda x: x) if over_docs else (lambda x: x[None])
+    text = _write_text(fleet(s.text), jax.tree.map(fleet, writes), fleet(payloads))
+    return out._replace(text=text if over_docs else text[0])
 
 
 def apply_op(

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for the hot long-document primitives.
+"""Pallas TPU kernels: the long-document position search and the fleet
+step's text-pool write (``write_text_strips``, at the end of the file).
 
 Position resolution over a long document asks: for each query position q
 (perspective-visible coordinates), which segment contains q and at what
@@ -23,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 I32 = jnp.int32
 
@@ -132,3 +134,113 @@ def resolve_positions_blocked(
     if jax.default_backend() == "tpu":
         return resolve_positions_pallas(lens, positions)
     return resolve_positions_reference(lens, positions)
+
+
+# ----------------------------------------------- the text pool's strip write
+LANES, SUBLANES = 128, 8    # the (8, 128) tile of an int32 array in HBM
+STRIP_TILE_ROWS = 64        # tile rows (of 8 documents) per grid step, at most
+STRIP_VMEM_BYTES = 8 << 20  # ... and as many as fit this much VMEM
+
+
+def text_strip_width(capacity: int, window: int) -> int:
+    """Width of the lane-aligned strip that holds any ``window`` consecutive
+    elements of a ``capacity``-wide pool row: the window rounded up to whole
+    lanes plus one more, or the whole row where that is no narrower (or the
+    row is not made of whole lanes)."""
+    width = -(-window // LANES) * LANES + LANES
+    return width if capacity % LANES == 0 and width < capacity else capacity
+
+
+def _strip_kernel(start_ref, new_ref, mask_ref, pool_in, pool, buf, sem, *,
+                  tile_rows: int):
+    """One grid step: up to ``STRIP_TILE_ROWS`` tile rows of the pool.  A DMA
+    moves whole (8, 128) tiles, so a document's strip travels with the seven
+    other rows of its tile row, and the eight documents of a tile row take
+    their turns one after another (turn r: row r of every tile row of the
+    block, all their copies in flight together)."""
+    del pool_in  # the same buffer as ``pool`` (input_output_aliases)
+    block, _, width = buf.shape
+    base = pl.program_id(0) * block
+    n = jnp.minimum(block, tile_rows - base)
+
+    def each(fn):
+        jax.lax.fori_loop(0, n, lambda t, c: (fn(t), c)[1], 0)
+
+    def copy(t, r, back: bool):
+        first = pl.multiple_of((base + t) * SUBLANES, SUBLANES)
+        hbm = pool.at[pl.ds(first, SUBLANES)]
+        if width < pool.shape[1]:    # else the strip is the row (start 0)
+            at = pl.multiple_of(start_ref[(base + t) * SUBLANES + r], LANES)
+            hbm = hbm.at[:, pl.ds(at, width)]
+        if back:
+            return pltpu.make_async_copy(buf.at[t], hbm, sem.at[1])
+        return pltpu.make_async_copy(hbm, buf.at[t], sem.at[0])
+
+    row = jax.lax.broadcasted_iota(I32, buf.shape, 1)
+
+    def turn(r, carry):
+        each(lambda t: copy(t, r, False).start())
+        each(lambda t: copy(t, r, False).wait())
+        take = (row == r) & (mask_ref[...].reshape(buf.shape) != 0)
+        buf[...] = jnp.where(take, new_ref[...].reshape(buf.shape), buf[...])
+        each(lambda t: copy(t, r, True).start())
+        each(lambda t: copy(t, r, True).wait())
+        return carry
+
+    # A loop, not eight copies of the body: every step program traces and
+    # lowers this kernel on its first dispatch.
+    jax.lax.fori_loop(0, SUBLANES, turn, 0)
+
+
+def write_text_strips(pool, starts, new, mask):
+    """``pool[d, starts[d] + j] = new[d, j]`` wherever ``mask[d, j] != 0``,
+    for every document d and j < width: one strip a document, read, merged
+    and written back in place (the pool is aliased in and out), so the cost
+    follows D x width and not the pool.
+
+    pool: int32[D, T]; starts: int32[D], each a multiple of ``LANES`` where
+    width is (``text_strip_width``) and at most T - width; new, mask:
+    int32[D, width].  Whole tile rows of eight documents go through the
+    Pallas kernel (Mosaic on a TPU, the interpreter elsewhere: the program is
+    the same); the D % 8 documents after them, and so a batch of fewer than
+    eight, take a plain ``dynamic_update_slice`` each, as every document of
+    a pool that is not made of whole lanes does (Mosaic slices whole tiles).
+    """
+    n_docs, capacity = pool.shape
+    width = new.shape[1]
+    tile_rows = n_docs // SUBLANES if capacity % LANES == 0 else 0
+    if tile_rows:
+        # A grid step holds its strips once in scratch and the new values
+        # and the mask twice each (the pipeline's two buffers).
+        fit = STRIP_VMEM_BYTES // (5 * SUBLANES * width * 4)
+        block = max(1, min(STRIP_TILE_ROWS, fit, tile_rows))
+        docs = block * SUBLANES
+        pool = pl.pallas_call(
+            functools.partial(_strip_kernel, tile_rows=tile_rows),
+            out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(pl.cdiv(tile_rows, block),),
+                in_specs=[
+                    pl.BlockSpec((docs, width), lambda i, starts: (i, 0)),
+                    pl.BlockSpec((docs, width), lambda i, starts: (i, 0)),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[
+                    pltpu.VMEM((block, SUBLANES, width), I32),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ],
+            ),
+            input_output_aliases={3: 0},
+            interpret=jax.default_backend() != "tpu",
+            name="text_strip_write",
+        )(starts, new, mask, pool)
+
+    def one(d, pool):
+        at = (d, starts[d])
+        old = jax.lax.dynamic_slice(pool, at, (1, width))
+        strip = jnp.where(mask[d] != 0, new[d], old[0])
+        return jax.lax.dynamic_update_slice(pool, strip[None], at)
+
+    return jax.lax.fori_loop(tile_rows * SUBLANES, n_docs, one, pool)

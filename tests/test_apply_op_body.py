@@ -15,8 +15,10 @@ padding slots hold shift remnants and are held by no independent reference;
 (b) The jaxpr of the fleet's row loop keeps the shape the body was written
 for: at most two cumulative sums and two rewrites of each per-segment column
 a row, no select over the text pool (which the loop does not even carry: one
-scatter after it is the pool's only write), no ``cond``/``switch`` on a
-batched predicate.
+strip a document, written after the loop and after the obliterate gate, is
+the pool's only write, and no scatter touches it), no ``cond``/``switch`` on
+a batched predicate.  The strip write itself (``mk._write_text``) is held to
+the rows' writes applied one after another.
 
 (c) A row's two boundary cuts, planned from one geometry and opened in one
 pass (``mk._ensure_boundaries``), against the one-cut split applied twice,
@@ -42,6 +44,7 @@ from fluidframework_tpu.dds.mergetree_ref import RefMergeTree
 from fluidframework_tpu.dds.shared_string import SharedString
 from fluidframework_tpu.models import doc_batch_engine as dbe
 from fluidframework_tpu.ops import mergetree_kernel as mk
+from fluidframework_tpu.ops.pallas_kernels import text_strip_width
 from fluidframework_tpu.server.local_service import LocalDocument
 
 from test_mergetree_oracle import draw_op, issue_op, pump
@@ -322,7 +325,12 @@ def _step_program(flag, n_docs=3):
     pays = jnp.zeros((n_docs, B, L), jnp.int32)
     step = functools.partial(mk.apply_fleet_ops, ob_flag=flag)
     every = list(_eqns(jax.make_jaxpr(step)(fleet, ops, pays).jaxpr))
-    loops = [e for e in every if e.primitive.name == "while"]
+    # The text write has loops of its own; the row loop is the one whose
+    # body holds the staged row.
+    loops = [e for e in every if e.primitive.name == "while"
+             and any(b.primitive.name in ("jit", "pjit")
+                     and b.params["name"] == "row"
+                     for b in e.params["body_jaxpr"].jaxpr.eqns)]
     assert len(loops) == 1
     return (every, list(_eqns(loops[0].params["body_jaxpr"].jaxpr)),
             loops[0], n_docs)
@@ -398,10 +406,10 @@ def test_vmapped_scan_body_structure(guard, flag):
         # ... the row loop neither carries nor touches the pool ...
         assert not touches(scan)
         assert not [e for e in body if touches(e)]
-        # ... and one scatter after it is the only write.
-        writes = [e for e in every if touches(e)
-                  and tuple(e.outvars[0].aval.shape) == pool]
-        assert [e.primitive.name for e in writes] == ["scatter"], writes
+        # ... and nothing scatters into it (``test_pool_write_is_one_strip``
+        # holds what does write it).
+        assert not [e for e in every
+                    if e.primitive.name.startswith("scatter") and touches(e)]
     else:
         # A switch on the row's kind is a ``cond`` in a jaxpr, and one on a
         # batched predicate would have been turned into selects by vmap: the
@@ -644,39 +652,168 @@ def test_two_cuts_random(seed):
     assert len({int(n) for n in np.asarray(want.nseg)}) > 3
 
 
-@pytest.mark.parametrize("seed", range(4))
-def test_one_text_scatter_equals_the_writes_in_order(seed):
-    """``_write_text`` against the rows' writes applied one after another:
-    overlapping starts (a rejected insert leaves ``text_end`` where it was
-    and the next row writes over it), empty rows, rows at the pool's end."""
-    rng = np.random.default_rng(seed)
-    n, width, cap = 24, 8, 96
-    counts = rng.integers(0, width + 1, n)
-    counts[rng.random(n) < 0.3] = 0
-    starts, at = [], 0
-    for c in counts:
+# ------------------------------------------------ the pool's strip write
+def _writes_in_order(text, starts, counts, payloads):
+    """The reference: every document's rows write one after another; what
+    falls past the pool's end is dropped."""
+    want = np.array(text)
+    cap = want.shape[1]
+    for d in range(want.shape[0]):
+        for st, c, row in zip(starts[d], counts[d], payloads[d]):
+            for j in range(int(c)):
+                if 0 <= st + j < cap:
+                    want[d, st + j] = row[j]
+    return want
+
+
+def _chain(rng, n, width, cap, t0, commit=0.6, empty=0.3, jump=None):
+    """Starts and counts of one document's batch, the way ``_apply_row``
+    makes them: a row writes ``count`` elements at ``text_end`` (0 where the
+    insert does not fit) and a committed row moves ``text_end`` on by its
+    ``text_len``: ``jump(i)`` where the case gives one (a hand-made row whose
+    ``text_len`` exceeds L), else its count.  A rejected row leaves
+    ``text_end`` where it was: the next row writes over it."""
+    starts, counts, at = [], [], t0
+    for i in range(n):
+        c = 0 if rng.random() < empty else int(rng.integers(1, width + 1))
+        text_len = c if jump is None or c == 0 else max(c, jump(i))
+        if at + text_len > cap:
+            c = text_len = 0
         starts.append(at)
-        if rng.random() < 0.6:          # committed: text_end moves on
-            at += int(c)
-        at = min(at, cap - width)
-    payloads = rng.integers(1, 1000, (n, width)).astype(np.int32)
-    want = np.zeros((cap,), np.int32)
-    for st, c, row in zip(starts, counts, payloads):
-        want[st:st + c] = row[:c]
-    writes = mk._TextWrite(jnp.asarray(starts, jnp.int32),
-                           jnp.asarray(counts, jnp.int32))
-    got = mk._write_text(jnp.zeros((cap,), jnp.int32), writes,
-                         jnp.asarray(payloads))
+        counts.append(c)
+        if rng.random() < commit:
+            at += text_len
+    return starts, counts
+
+
+def _pool_case(case, rng, n_docs):
+    """(rows, width, cap, starts [D, n], counts [D, n]) of a named case."""
+    n, width, cap = 24, 8, 1024
+    chain = functools.partial(_chain, rng, n, width, cap)
+    if case == "random":
+        docs = [chain(int(rng.integers(0, cap))) for _ in range(n_docs)]
+    elif case == "clamped_at_pool_end":
+        # t0 > T - B * L: the strip cannot start at the first write.
+        docs = [chain(cap - int(rng.integers(1, n * width))) for _ in range(n_docs)]
+    elif case == "window_wider_than_pool":
+        n, width, cap = 24, 8, 96                      # B * L >= T
+        docs = [_chain(rng, n, width, cap, int(rng.integers(0, cap)))
+                for _ in range(n_docs)]
+    elif case == "rejected_then_overwritten":
+        docs = [chain(int(rng.integers(0, cap // 2)), commit=0.0, empty=0.0)
+                for _ in range(n_docs)]
+    elif case == "all_counts_zero":
+        docs = [chain(int(rng.integers(0, cap)), empty=1.0) for _ in range(n_docs)]
+    elif case == "text_len_over_L_inside_the_strip":
+        docs = [chain(int(rng.integers(0, cap // 2)), commit=1.0,
+                      jump=lambda i: width + 2 if i == 1 else 0)
+                for _ in range(n_docs)]
+    elif case == "text_len_over_L_past_the_strip":
+        # Starts that jump by more than they wrote, and further than any
+        # strip reaches: three times in one batch.
+        cap = 4096
+        docs = [_chain(rng, n, width, cap, int(rng.integers(0, 64)), commit=1.0,
+                       empty=0.1, jump=lambda i: 700 if i in (2, 9, 17) else 0)
+                for _ in range(n_docs)]
+    else:
+        assert case == "every_document_elsewhere"
+        ends = rng.permutation(cap - n * width)[:n_docs]
+        docs = [chain(int(t0)) for t0 in ends]
+    starts, counts = (np.array(x, np.int32) for x in zip(*docs))
+    return n, width, cap, starts, counts
+
+
+@pytest.mark.parametrize("n_docs", [1, 5, 8, 21],
+                         ids=["one_doc", "under_a_tile_row", "one_tile_row",
+                              "tile_rows_and_a_tail"])
+@pytest.mark.parametrize("case", [
+    "random", "clamped_at_pool_end", "window_wider_than_pool",
+    "rejected_then_overwritten", "all_counts_zero",
+    "text_len_over_L_inside_the_strip", "text_len_over_L_past_the_strip",
+    "every_document_elsewhere"])
+def test_text_write_equals_the_writes_in_order(case, n_docs):
+    """``_write_text`` against the rows' writes applied one after another,
+    padding included: overlapping starts, empty rows and batches, strips
+    clamped at the pool's end or as wide as the pool, starts beyond the
+    strip, and every document at a ``text_end`` of its own (whole tile rows
+    through the kernel, the rest by plain updates)."""
+    rng = np.random.default_rng([len(case), n_docs])
+    n, width, cap, starts, counts = _pool_case(case, rng, n_docs)
+    payloads = rng.integers(1, 1000, (n_docs, n, width)).astype(np.int32)
+    text = -rng.integers(1, 1000, (n_docs, cap)).astype(np.int32)
+    want = _writes_in_order(text, starts, counts, payloads)
+    got = jax.jit(mk._write_text)(
+        jnp.asarray(text),
+        mk._TextWrite(jnp.asarray(starts), jnp.asarray(counts)),
+        jnp.asarray(payloads))
     assert np.array_equal(np.asarray(got), want)
-    # No index twice: a scatter may apply its updates in any order.
-    dst = np.asarray(mk._text_write_indices(writes, width, cap)).reshape(-1)
-    kept = dst[dst < cap]
-    assert len(kept) == len(set(kept.tolist()))
-    backwards = np.zeros((cap,), np.int32)
-    for i in reversed(range(dst.size)):
-        if dst[i] < cap:
-            backwards[dst[i]] = payloads.reshape(-1)[i]
-    assert np.array_equal(backwards, want)
+    # The case is the case it says it is.
+    live = counts > 0
+    if case == "all_counts_zero":
+        assert not live.any() and np.array_equal(want, text)
+    else:
+        assert live.any(axis=1).all() and not np.array_equal(want, text)
+    first = np.where(live.any(axis=1),
+                     np.where(live, starts, cap).min(axis=1), 0)
+    reach = np.where(live, starts + counts, 0).max(axis=1) - first
+    if case == "clamped_at_pool_end":
+        assert (first > cap - n * width).all()
+    if case == "text_len_over_L_past_the_strip":
+        assert (reach > n * width + 256).all()
+    elif case == "text_len_over_L_inside_the_strip":
+        assert (np.diff(starts, axis=1) > width).any()
+        assert (reach <= n * width).all()
+    if case == "rejected_then_overwritten":
+        assert (starts == starts[:, :1]).all()
+    if case == "every_document_elsewhere":
+        assert len(set(starts[:, 0].tolist())) == n_docs
+
+
+def _pool_writers(n_docs):
+    """The equations of ``apply_fleet_ops`` that give out a pool, loops and
+    staged calls that merely pass it on left out."""
+    every = _step_program(True, n_docs)[0]
+    pool = (n_docs, T)
+    is_pool = lambda v: tuple(getattr(v.aval, "shape", ())) == pool
+    return every, is_pool, [
+        e for e in every if any(is_pool(v) for v in e.outvars)
+        and e.primitive.name not in ("while", "scan", "cond", "jit", "pjit")]
+
+
+@pytest.mark.parametrize("n_docs", [3, 16, 21])
+def test_pool_write_is_one_strip_a_document(n_docs):
+    """The pool is written once a slice and by strips alone: whole tile rows
+    of eight documents by ONE kernel call that takes the pool in and gives
+    it out in place, each document after them by a
+    ``dynamic_update_slice``; what goes in beside the pool is a strip a
+    document, B * L elements rounded up to whole lanes plus one lane more
+    (or the whole row where that is narrower), and nothing scatters into
+    the pool, reshapes it or selects over it (the per-element scatter this
+    replaces fails every one of these)."""
+    every, is_pool, writers = _pool_writers(n_docs)
+    strip = text_strip_width(T, B * L)
+    assert strip == -(-B * L // 128) * 128 + 128 < T
+    names = [e.primitive.name for e in writers]
+    # (The documents after the last whole tile row share one update, in a
+    # loop over them; with none the loop is empty.)
+    assert names == (["pallas_call"] * (n_docs >= 8)
+                     + ["dynamic_update_slice"]), names
+    for e in writers:
+        (operand,) = [v for v in e.invars if is_pool(v)]
+        assert e.outvars[0].aval.shape == operand.aval.shape
+        beside = [v.aval.shape for v in e.invars
+                  if getattr(v.aval, "shape", ()) and not is_pool(v)]
+        if e.primitive.name == "pallas_call":
+            assert dict(e.params["input_output_aliases"]) == {
+                e.invars.index(operand): 0}
+            assert sorted(beside) == sorted(
+                [(n_docs,), (n_docs, strip), (n_docs, strip)])
+        else:
+            assert beside == [(1, strip)]
+    for e in every:
+        if any(is_pool(v) for v in e.invars):
+            assert not e.primitive.name.startswith("scatter"), e
+            assert e.primitive.name not in ("reshape", "select_n", "gather"), e
 
 
 def test_apply_op_traced_flag_is_one_scalar_cond():

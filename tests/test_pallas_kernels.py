@@ -1,15 +1,24 @@
 """Pallas long-document position resolution, differentially against the
-jnp oracle (interpreter mode — tests run on the CPU mesh)."""
+jnp oracle, and the text pool's strip write against numpy (interpreter mode —
+tests run on the CPU mesh)."""
 
 from __future__ import annotations
 
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 from fluidframework_tpu.ops.pallas_kernels import (
+    LANES,
+    STRIP_TILE_ROWS,
+    SUBLANES,
     resolve_positions_blocked,
     resolve_positions_pallas,
     resolve_positions_reference,
+    text_strip_width,
+    write_text_strips,
 )
 
 
@@ -108,3 +117,39 @@ def test_blocked_is_reference_off_tpu():
     np.testing.assert_array_equal(np.asarray(bi), np.asarray(ri))
     np.testing.assert_array_equal(np.asarray(bo), np.asarray(ro))
     np.testing.assert_array_equal(np.asarray(bh), np.asarray(rh))
+
+
+# ------------------------------------------------- the pool's strip write
+@pytest.mark.parametrize("capacity,window", [(1024, 256), (512, 64), (96, 256),
+                                             (200, 64)])
+@pytest.mark.parametrize("n_docs", [
+    1, 7, SUBLANES, 3 * SUBLANES + 5, STRIP_TILE_ROWS * SUBLANES + 19])
+def test_strip_write_merges_one_strip_a_document(n_docs, capacity, window):
+    """``write_text_strips`` == numpy, element for element: batches under a
+    tile row (plain updates only), whole tile rows (the kernel, interpreted
+    here), a second, ragged grid step, and a tail after it; strips of whole
+    lanes at starts all over the row, the last lane included, rows that are
+    their own strip (narrower than the window), and pools not made of lanes
+    (plain updates for every document: Mosaic slices whole tiles)."""
+    rng = np.random.default_rng([n_docs, capacity, window])
+    width = text_strip_width(capacity, window)
+    if capacity % LANES == 0 and capacity > window + 2 * LANES:
+        assert width % LANES == 0 and window + LANES <= width < capacity
+    else:
+        assert width == capacity
+    starts = (rng.integers(0, (capacity - width) // LANES + 1, n_docs)
+              * LANES).astype(np.int32)
+    starts[-1] = capacity - width
+    pool = rng.integers(-1000, 0, (n_docs, capacity)).astype(np.int32)
+    new = rng.integers(1, 1000, (n_docs, width)).astype(np.int32)
+    mask = (rng.random((n_docs, width)) < 0.3).astype(np.int32)
+    mask[0] = 0
+    want = pool.copy()
+    for d in range(n_docs):
+        strip = want[d, starts[d]:starts[d] + width]
+        strip[mask[d] != 0] = new[d][mask[d] != 0]
+    got = jax.jit(write_text_strips)(
+        jnp.asarray(pool), jnp.asarray(starts), jnp.asarray(new),
+        jnp.asarray(mask))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert not np.array_equal(want, pool) or n_docs == 1

@@ -47,8 +47,28 @@ K = mk.OpKind
 
 
 # ------------------------------------------------- the dense reference
+def _scatter_write_text(text, writes, payloads):
+    """``mk._write_text`` as it was until the strip write: ONE per-element
+    scatter of a document's [B * L] payload elements, an element that a later
+    row writes too sent past the end (dropped), so no index twice.  Kept
+    HERE as the reference: every program below is compared with the old
+    write, pool padding included, not with itself."""
+    n_rows, width = payloads.shape
+    cap = text.shape[0]
+    tpos = jnp.arange(width, dtype=jnp.int32)
+    pos = writes.start[:, None] + tpos[None, :]
+    live = tpos[None, :] < writes.count[:, None]
+    later = jnp.arange(n_rows)[:, None] < jnp.arange(n_rows)[None, :]
+    lo = writes.start[None, None, :]
+    hit = (lo <= pos[:, :, None]) & (pos[:, :, None] < lo + writes.count[None, None, :])
+    covered = jnp.any(hit & later[:, None, :], axis=-1)
+    dst = jnp.where(live & ~covered, pos, cap)
+    return text.at[dst.reshape(-1)].set(payloads.reshape(-1), mode="drop")
+
+
 def _dense_apply_ops(s, ops, payloads, ob_flag):
-    """``apply_ops`` as it was: ``lax.scan`` of the row body over all B."""
+    """``apply_ops`` as it was: ``lax.scan`` of the row body over all B, then
+    the one scatter."""
 
     def scan_spec(st, flag):
         cap = st.text.shape[0]
@@ -59,7 +79,7 @@ def _dense_apply_ops(s, ops, payloads, ob_flag):
         out, writes = jax.lax.scan(
             step, st._replace(text=jnp.zeros((0,), jnp.int32)),
             (ops, payloads))
-        return out._replace(text=mk._write_text(st.text, writes, payloads))
+        return out._replace(text=_scatter_write_text(st.text, writes, payloads))
 
     return jax.lax.cond(
         ob_flag, lambda st: scan_spec(st, True),
@@ -224,13 +244,14 @@ def test_kinds_equal_the_dense_scan(kind):
         _assert_same(dbe._fleet_step(got, ops, pays), want)
 
 
-def _megastep_case():
-    rows = _Rows(n_docs=8, seed=3)
+def _megastep_case(n_docs=8):
+    rows = _Rows(n_docs=n_docs, seed=3)
     state = _seeded(rows)
     # Two slices of different depth; within each, the first half of the
     # documents (one shard of two) is deeper than the second.
-    first = rows.slice([5, 3, 4, 1, 2, 0, 1, 2])
-    second = rows.slice([1, 0, 1, 1, 0, 0, 0, 0])
+    half = n_docs // 2
+    first = rows.slice(([5, 3, 4, 1] * half)[:half] + ([2, 0, 1, 2] * half)[:half])
+    second = rows.slice(([1, 0, 1, 1] * half)[:half] + [0] * half)
     ops = jnp.stack([first[0], second[0]])
     pays = jnp.stack([first[1], second[1]])
     want = _copy(state)
@@ -239,38 +260,47 @@ def _megastep_case():
     return state, ops, pays, want
 
 
-@pytest.mark.parametrize("program", ["fleet_step", "megastep", "lane", "mesh"])
+# The text write takes whole tile rows of eight documents through its kernel
+# and the documents after them by plain updates: ``_tile_rows`` programs hold
+# both (20 documents; 12 a shard), the others the plain updates alone.
+@pytest.mark.parametrize("program", [
+    "fleet_step", "fleet_step_tile_rows", "megastep", "megastep_tile_rows",
+    "lane", "mesh", "mesh_tile_rows"])
 def test_programs_equal_the_dense_scan(program):
-    if program == "fleet_step":
-        rows = _Rows(seed=5)
+    n_docs = {"fleet_step": D, "fleet_step_tile_rows": 20, "megastep": 8,
+              "megastep_tile_rows": 20, "lane": 1, "mesh": 8,
+              "mesh_tile_rows": 24}[program]
+    if program.startswith("fleet_step"):
+        rows = _Rows(n_docs=n_docs, seed=5)
         state = _seeded(rows)
         # Seven consecutive batches on one carried state.
         want = _copy(state)
         for depth in (0, 1, 2, 3, 4, 6, 32):
             ops, pays = rows.slice(
                 [depth] + [int(rows.rng.integers(0, depth + 1))
-                           for _ in range(D - 1)])
+                           for _ in range(n_docs - 1)])
             state = dbe._fleet_step(state, ops, pays)
             want = _dense_fleet_step(want, ops, pays)
             _assert_same(state, want)
-    elif program == "megastep":
-        state, ops, pays, want = _megastep_case()
+    elif program.startswith("megastep"):
+        state, ops, pays, want = _megastep_case(n_docs)
         assert [int(mk.row_count(o)) for o in ops] == [5, 1]
         _assert_same(dbe._fleet_megastep(state, ops, pays), want)
     elif program == "lane":
         # One document, the row not vmapped: the trip count is its own.
-        rows = _Rows(n_docs=1, seed=9)
+        rows = _Rows(n_docs=n_docs, seed=9)
         state = jax.tree.map(lambda x: x[0], _seeded(rows))
         ops, pays = rows.slice([5])
         want = jax.jit(_dense_apply_ops)(
             state, ops[0], pays[0], _ob_flag(state, ops[0]))
         _assert_same(dbe._lane_apply_jit(state, ops[0], pays[0]), want)
     else:
-        state, ops, pays, want = _megastep_case()
+        state, ops, pays, want = _megastep_case(n_docs)
         mesh = pm.doc_mesh(jax.devices()[:2])
-        # Shard 0 holds documents 0-3, shard 1 documents 4-7: their counts
-        # differ in both slices, and each runs its own.
-        assert [[int(mk.row_count(o[:4])), int(mk.row_count(o[4:]))]
+        # Each shard holds half of the documents: their counts differ in
+        # both slices, and each runs its own.
+        half = n_docs // 2
+        assert [[int(mk.row_count(o[:half])), int(mk.row_count(o[half:]))]
                 for o in ops] == [[5, 2], [1, 0]]
         specs = pm.fleet_state_specs(state)
         program = pm.mesh_fleet_program(mk.apply_megastep, mesh, specs)

@@ -1,0 +1,82 @@
+"""The text pool's strip write, compiled HERE for a TPU v5e that is described
+and not attached (the TPU's compiler is installed in the sandbox): Mosaic
+accepts the kernel at the fleets' real widths, the pool goes in and out in
+place, and no pool-sized temporary is made.  Nothing runs, so nothing here is
+a time; interpret mode (tests/test_pallas_kernels.py) holds the results.
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU's library, and every xdist worker imports this file.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from fluidframework_tpu.ops import pallas_kernels as pk
+
+I32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to hold
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # An executable for a described device can be written to the persistent
+    # cache but not read back: keep these compiles out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("n_docs,capacity,window", [
+    (6144, 65536, 32 * 8),      # string_fleet_1chip: fleet_main's geometry
+    (2500, 65536, 32 * 8),      # a shard of string_fleet_10k_mesh4: 312 tile rows + 4
+    (64, 65536, 32 * 8),        # a cohort
+    (1024, 16384, 32 * 64),     # the engine's default max_insert_len
+    (64, 1024, 32 * 64),        # a pool narrower than the window: whole rows
+    (16, 200, 64),              # a pool not made of lanes: whole rows too
+], ids=["fleet_6144", "mesh_shard_2500", "cohort_64", "insert_len_64",
+        "whole_row", "row_not_of_lanes"])
+def test_strip_write_compiles_for_the_v5e(one_chip, monkeypatch, n_docs,
+                                          capacity, window):
+    # ``write_text_strips`` interprets the kernel unless the backend is a
+    # TPU; the backend here is the CPU, the target is not.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    width = pk.text_strip_width(capacity, window)
+    assert width == min(-(-window // pk.LANES) * pk.LANES + pk.LANES, capacity)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    compiled = jax.jit(pk.write_text_strips, donate_argnums=0).lower(
+        arg(n_docs, capacity), arg(n_docs), arg(n_docs, width),
+        arg(n_docs, width)).compile()
+    text = compiled.as_text()
+    # (A pool that is not made of whole lanes takes plain updates alone.)
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        capacity % pk.LANES == 0)
+    # In place: the pool's bytes are aliased to the result, and what the
+    # program keeps beside its arguments is strips, not a pool.
+    memory = compiled.memory_analysis()
+    # (On the device a pool is whole tiles: 2,500 rows are 2,504.)
+    pool_bytes = (-(-n_docs // pk.SUBLANES) * pk.SUBLANES
+                  * -(-capacity // pk.LANES) * pk.LANES * 4)
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 8
+    relaid = re.compile(r"= s32\[%d\]" % (n_docs * capacity))
+    assert not relaid.search(text)
+    for op in ("scatter(", "copy(", "reshape("):
+        assert not [ln for ln in text.splitlines() if op in ln
+                    and f"s32[{n_docs},{capacity}]" in ln.split(op)[0]], op
