@@ -212,6 +212,64 @@ _fleet_compact = functools.partial(jax.jit, donate_argnums=(0,))(
 )
 
 
+# What zamboni reads or writes of a document: the per-segment columns, the
+# obliterate table, ``nseg`` and ``min_seq``.  The text pool, ``text_end``,
+# ``uid_next`` and ``error`` are no operand of the cohort compaction.
+_COMPACT_FIELDS = tuple(
+    f for f in mk.DocState._fields
+    if f not in ("text", "text_end", "uid_next", "error")
+)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _compact_cohort(cols, idx, mins):
+    """Zamboni over the rows ``idx`` of the fleet at the floors ``mins``:
+    those rows' ``_COMPACT_FIELDS`` taken out, ``_fleet_compact_body`` on
+    them, written back in place into the donated ``cols``.  Pad lanes repeat
+    a real row (and its floor), so they write the same values twice.
+
+    Rows move one at a time, a dynamic slice out and a dynamic-update-slice
+    back in: a gather or a scatter relays the narrow obliterate columns to
+    another layout first, a fleet-sized copy for a few rows' sake."""
+    lanes = idx.shape[0]
+    row = jax.lax.dynamic_index_in_dim
+    put_row = jax.lax.dynamic_update_index_in_dim
+
+    def take(lane, sub):
+        return jax.tree.map(
+            lambda s, x: put_row(s, row(x, idx[lane], 0, False), lane, 0),
+            sub, cols,
+        )
+
+    sub = jax.lax.fori_loop(
+        0, lanes, take,
+        jax.tree.map(lambda x: jnp.zeros((lanes, *x.shape[1:]), x.dtype), cols),
+    )
+    none = jnp.zeros((lanes,), mk.I32)
+    done = _fleet_compact_body(
+        mk.DocState(
+            text=jnp.zeros((lanes, 0), mk.I32), text_end=none, uid_next=none,
+            error=none, **sub,
+        ),
+        mins,
+    )
+    done = {f: getattr(done, f) for f in _COMPACT_FIELDS}
+
+    def put(lane, cols):
+        return jax.tree.map(
+            lambda x, s: put_row(x, row(s, lane, 0, False), idx[lane], 0),
+            cols, done,
+        )
+
+    return jax.lax.fori_loop(0, lanes, put, cols)
+
+
+@jax.jit
+def _fleet_evictable(state):
+    """Segments zamboni would still drop, summed over the batch."""
+    return jnp.sum(jax.vmap(mk.evictable_count)(state))
+
+
 _lane_apply_jit = jax.jit(mk.apply_ops)
 _lane_compact_jit = jax.jit(lambda s, m: mk.compact(mk.set_min_seq(s, m)))
 
@@ -392,8 +450,13 @@ class DocBatchEngine:
         self._readmit_interval: dict[int, int] = {}
         # The row-slot pair is in every health line, 0 included: its
         # reader takes a window delta from the first line on.
+        # So are the summary loop's four: documents acked, compaction
+        # dispatches, documents compacted and lanes compacted (pow2 padding
+        # included; a fleet-wide compaction adds ``capacity``).
         self.counters = HealthCounters(
-            telemetry, row_slots_scanned=0, row_slots_dense=0
+            telemetry, row_slots_scanned=0, row_slots_dense=0,
+            acks_seen=0, compact_dispatches=0, compacted_docs=0,
+            compacted_lanes=0,
         )
         # Sampled hot-path timing through the reference's sampled-telemetry
         # shape (one event per N steps; flush_all drains the tail at
@@ -512,6 +575,7 @@ class DocBatchEngine:
             ("fleet_step", self._step),
             ("fleet_megastep", self._megastep),
             ("fleet_compact", self._compact),
+            ("compact_cohort", _compact_cohort),
             ("lane_apply", self._lane_apply),
         ):
             self.recompile_watchdog.register(prog_name, prog)
@@ -556,6 +620,12 @@ class DocBatchEngine:
         self.cohort_lanes = 0   # sum of cohort sizes (work proxy)
         self._gather_cohort = _gather_cohort_jit
         self._scatter_cohort = _scatter_cohort_jit
+        # Documents whose summary was acked (``compact(docs)``) and that
+        # ``step`` has not compacted yet, and the lane counts of the cohort
+        # compaction this engine has dispatched: like a step's shapes, a new
+        # one is not built while a built one can do the work.
+        self.compact_due: set[int] = set()
+        self._compact_built: set[int] = set()
 
     # ------------------------------------------------------------------ ingest
     def ingest(self, doc_idx: int, msg: SequencedMessage) -> None:
@@ -898,13 +968,7 @@ class DocBatchEngine:
         from a checkpoint): its ingest consumes parsed messages.  A live
         native-path doc that merely CHECKPOINTED is not in a lane — it
         stays on the C++ fast path."""
-        return (
-            doc_idx in self.oracles
-            or doc_idx in self.overflow
-            or doc_idx in self.quarantine
-            or doc_idx in self.seg_lanes
-            or self.hosts[doc_idx].restored
-        )
+        return self._off_batch(doc_idx) or self.hosts[doc_idx].restored
 
     def ingest_lines(self, doc_idx: int, data: bytes) -> int:
         """Stage newline-separated wire JSON through the NATIVE encoder
@@ -1415,6 +1479,8 @@ class DocBatchEngine:
                 self.watchdog()
             if self.readmit_after_steps:
                 self._maybe_readmit()
+        if self.compact_due:
+            self._compact_due_docs()
         # Sync boundary housekeeping (host-side, O(programs + samples)):
         # resolve e2e latency samples, poll for mid-serve recompiles, and
         # feed the sampled step timing when a telemetry sink is attached.
@@ -1732,18 +1798,41 @@ class DocBatchEngine:
         instant("seg_rebalance", doc=self.doc_keys[d])
         return True
 
-    def compact(self) -> None:
-        """Advance MSNs and run zamboni eviction across the fleet.
+    def compact(self, docs=None) -> None:
+        """Advance MSNs and run zamboni eviction: over the documents
+        ``docs`` (those whose summary ack the consumer just read), or over
+        the whole fleet.
 
-        Rows still staged are applied FIRST.  A host's ``min_seq`` is the
-        MSN of the newest message ingested, which may postdate the
-        ref-seq of rows still queued (a consumer that fell behind reads
-        ops and the summary ack that follows them in one pass); zamboni
-        at that floor would evict tombstones those rows still resolve
-        their positions against, and they would land in the wrong place
-        with no error latched."""
+        Either way the floor is each host's ``min_seq``, the MSN of the
+        newest message ingested, and rows still staged are applied FIRST:
+        that MSN may postdate the ref-seq of rows still queued (a consumer
+        that fell behind reads ops and the summary ack that follows them in
+        one pass); zamboni at that floor would evict tombstones those rows
+        still resolve their positions against, and they would land in the
+        wrong place with no error latched.
+
+        With ``docs`` nothing is dispatched here: the documents are marked
+        due and ``step`` compacts them after its slices have applied what
+        was staged (``_compact_due_docs``), a due document with rows still
+        queued waiting for the step that empties its queue.  Without, this
+        call steps and then compacts every document and every lane."""
+        if docs is not None:
+            with self.ckpt_lock:
+                self.compact_due.update(int(d) for d in docs)
+            return
         if self._has_staged_rows():
             self.step()
+        with span("compact", kind="fleet", docs=self.n_docs,
+                  lanes=self.capacity):
+            self._compact_batch_fleet_wide()
+            self.compact_due.clear()
+        for d in (*self.seg_lanes, *self.overflow, *self.oracles,
+                  *self.quarantine):
+            self._compact_lane(d)
+
+    def _compact_batch_fleet_wide(self) -> None:
+        """Every row of the batch state at its host's ``min_seq``: one
+        program over ``capacity`` lanes (``shard_map`` under a mesh)."""
         mins = np.zeros((self.capacity,), np.int32)
         for d, h in enumerate(self.hosts):
             mins[self._slot[d]] = h.min_seq
@@ -1752,19 +1841,110 @@ class DocBatchEngine:
         else:
             mins_dev = jnp.asarray(mins)
         self.state = self._compact(self.state, mins_dev)
-        for d, lane in self.seg_lanes.items():
+        self.counters.bump("compact_dispatches")
+        self.counters.bump("compacted_docs", self.n_docs)
+        self.counters.bump("compacted_lanes", self.capacity)
+
+    def _compact_lane(self, d: int) -> None:
+        """Zamboni for a document that left the batch: its lane alone."""
+        floor = self.hosts[d].min_seq
+        if d in self.seg_lanes:
+            lane = self.seg_lanes[d]
             lane.state = self._seg_compact(
-                lane.state, jnp.asarray(self.hosts[d].min_seq, jnp.int32)
+                lane.state, jnp.asarray(floor, jnp.int32)
             )
             lane.version += 1
-        for d, lane in self.overflow.items():
+        elif d in self.overflow:
+            lane = self.overflow[d]
             lane.state = self._lane_compact(
-                lane.state, jnp.asarray(self.hosts[d].min_seq, jnp.int32)
+                lane.state, jnp.asarray(floor, jnp.int32)
             )
-        for d, tree in self.oracles.items():
-            tree.update_min_seq(self.hosts[d].min_seq)
-        for d, tree in self.quarantine.items():
-            tree.update_min_seq(self.hosts[d].min_seq)
+        elif d in self.oracles:
+            self.oracles[d].update_min_seq(floor)
+        else:
+            self.quarantine[d].update_min_seq(floor)
+
+    def _compact_due_docs(self) -> None:
+        """``step``'s last device work: zamboni for the due documents whose
+        staged rows are all applied.  Batch documents go through the cohort
+        program at ``_cohort_lanes`` of their number; more of them than the
+        largest lane count this engine has dispatched go out as several
+        dispatches of built sizes, so that serving builds no larger program
+        than set-up did (a ladder builds its largest size first, ``warmup``
+        builds 1 to 8).  Above ``capacity // 4`` of them, and under a mesh,
+        it is the fleet-wide program.  A document in a lane compacts its
+        lane alone."""
+        due = sorted(d for d in self.compact_due if not self._queue_depth(d))
+        lanes_of = [d for d in due if self._off_batch(d)]
+        batch = [d for d in due if not self._off_batch(d)]
+        fleet_wide = batch and (
+            self.mesh is not None or len(batch) > self.capacity // 4
+        )
+        if fleet_wide and self._busy:
+            # Every row is compacted at its host's floor: all of them wait.
+            due, batch = lanes_of, []
+        self.compact_due.difference_update(due)
+        for d in lanes_of:
+            with span("compact", kind="lane", docs=1, lanes=1):
+                self._compact_lane(d)
+        if not batch:
+            return
+        if fleet_wide:
+            with span("compact", kind="fleet", docs=len(batch),
+                      lanes=self.capacity):
+                self._compact_batch_fleet_wide()
+            return
+        while batch:
+            lanes = self._cohort_lanes(len(batch))
+            lanes = min(lanes, max(self._compact_built, default=lanes))
+            now, batch = batch[:lanes], batch[lanes:]
+            with span("compact", kind="cohort", docs=len(now), lanes=lanes):
+                idx = np.full((lanes,), self._slot[now[-1]], np.int32)
+                idx[: len(now)] = self._slot[now]
+                mins = np.full(
+                    (lanes,), self.hosts[now[-1]].min_seq, np.int32
+                )
+                mins[: len(now)] = [self.hosts[d].min_seq for d in now]
+                self._dispatch_compact_cohort(idx, mins)
+            self.counters.bump("compact_dispatches")
+            self.counters.bump("compacted_docs", len(now))
+            self.counters.bump("compacted_lanes", lanes)
+
+    def _dispatch_compact_cohort(self, idx: np.ndarray, mins: np.ndarray) -> None:
+        """One cohort compaction over the batch rows ``idx`` (the numpy
+        arrays ride the call: no upload of their own)."""
+        cols = _compact_cohort(
+            {f: getattr(self.state, f) for f in _COMPACT_FIELDS}, idx, mins
+        )
+        self.state = self.state._replace(**cols)
+        self._compact_built.add(len(idx))
+
+    def _off_batch(self, d: int) -> bool:
+        """The document's replica is a lane's, not its batch row."""
+        return (
+            d in self.overflow or d in self.seg_lanes
+            or d in self.oracles or d in self.quarantine
+        )
+
+    def evictable_left(self) -> int:
+        """Segments zamboni would still drop at each replica's own
+        ``min_seq``, over the batch and the overflow lanes: 0 when every
+        compaction evicted what its floor allowed."""
+        left = int(_fleet_evictable(self.state))
+        for lane in self.overflow.values():
+            left += int(mk.evictable_count(lane.state))
+        return left
+
+    def device_min_seqs(self) -> list[int]:
+        """Each document's collab-window floor as its device replica holds
+        it (a host lane's document: the floor its oracle was given)."""
+        by_slot = np.asarray(self.state.min_seq)
+        out = [int(by_slot[self._slot[d]]) for d in range(self.n_docs)]
+        for d, lane in self.overflow.items():
+            out[d] = int(lane.state.min_seq)
+        for d in (*self.seg_lanes, *self.oracles, *self.quarantine):
+            out[d] = int(self.hosts[d].min_seq)
+        return out
 
     # --------------------------------------------------------------- recovery
     def recover(self) -> list[int]:
@@ -2752,8 +2932,9 @@ class DocBatchEngine:
         points, so a promoted standby pays ZERO XLA compiles on its first
         real dispatch.  NOOP slices are identity by kernel contract, so
         state bytes are untouched.  Mesh-less fleets also get every cohort
-        size at K = 1; a cohort megastep (K > 1) still compiles on first
-        use.  Returns the number of warmup dispatches run."""
+        size at K = 1 (a cohort megastep, K > 1, still compiles on first
+        use) and the cohort compaction at 8, 4, 2 and 1 lanes.  Returns the
+        number of warmup dispatches run."""
         warmed = 0
         with self.ckpt_lock, span("warmup", k_max=self.megastep_k):
             stage = self._staging()
@@ -2810,6 +2991,16 @@ class DocBatchEngine:
                 mins_dev = jnp.asarray(mins)
             self.state = self._compact(self.state, mins_dev)
             warmed += 1
+            if self.bucketing:
+                # The cohort compaction, largest size first (serving goes
+                # out in built sizes): rows at their own floors, so zamboni
+                # finds nothing a fleet-wide one would not have dropped.
+                lanes = min(8, self._cohort_lanes(max(1, self.capacity // 4)))
+                while lanes:
+                    idx = np.zeros((lanes,), np.int32)
+                    self._dispatch_compact_cohort(idx, mins[idx])
+                    warmed += 1
+                    lanes //= 2
             jax.block_until_ready(self.state)
             # Absorb the warmup compiles into the watchdog count NOW, so
             # they show up as boot-time cache growth rather than landing

@@ -979,6 +979,14 @@ class TreeBatchEngine:
             self._rows_upper = np.where(active, nrow + queued, 0)
             self._pool_upper = np.where(active, words + queued_words, 0)
 
+    def compact(self, docs=None) -> None:
+        """What the consumer calls on a summary ack (``docs``: the acked
+        documents).  The tree fleet has no per-document compaction yet:
+        whatever is staged is applied, then the whole fleet compacts."""
+        if self._busy:
+            self.step()
+        self._compact_fleet()
+
     def step(self) -> int:
         """Apply everything staged as batched device megasteps.  Holds
         ``ckpt_lock`` end to end (the background checkpoint writer only

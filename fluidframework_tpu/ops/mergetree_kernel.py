@@ -1821,6 +1821,18 @@ def _gather_keep(s: DocState, keep: jnp.ndarray) -> DocState:
     )
 
 
+def _dead_mask(s: DocState) -> jnp.ndarray:
+    """Live segments whose winning remove is acked at or below min_seq."""
+    rem0 = _min_tree(s.rem_keys)
+    return _alive(s) & (rem0 < LOCAL_BASE) & (rem0 <= s.min_seq)
+
+
+def evictable_count(s: DocState) -> jnp.ndarray:
+    """How many segments ``compact`` would drop right now (0 right after a
+    compaction at the document's ``min_seq``)."""
+    return jnp.sum(_dead_mask(s) & ~_anchored_mask(s)).astype(I32)
+
+
 @jax.named_scope("compact")
 def compact(s: DocState, ob_flag=None) -> DocState:
     """Evict segments whose winning remove is acked at or below min_seq.
@@ -1834,8 +1846,7 @@ def compact(s: DocState, ob_flag=None) -> DocState:
     if ob_flag is None:
         ob_flag = jnp.any(s.ob_key >= 0)
     alive = _alive(s)
-    rem0 = _min_tree(s.rem_keys)
-    dead = alive & (rem0 < LOCAL_BASE) & (rem0 <= s.min_seq)
+    dead = _dead_mask(s)
     if isinstance(ob_flag, bool):
         anchored = _anchored_mask(s) if ob_flag else jnp.zeros_like(alive)
     else:

@@ -86,6 +86,11 @@ class FleetConsumer:
         self._tails: list[bytes] = [b"" for _ in doc_ids]
         self.rows_staged = 0
         self.bytes_consumed = 0
+        # Summary acks read so far, per document, and whether one was handed
+        # to the engine since the last step (the serving loop then steps
+        # even if the pump staged no row: the ack's compaction runs there).
+        self.acks_by_doc = [0] * len(doc_ids)
+        self.acks_unstepped = False
         # Doc indices whose firehose socket the SERVER closed (shard
         # restart/shutdown): the consumer is dead for those docs and its
         # supervisor should restart it.
@@ -203,7 +208,7 @@ class FleetConsumer:
         """``pump``'s work once ``select`` has returned: read, peel and
         ingest every ready socket; labels the pump's span ``sp``."""
         staged = 0
-        acked = False
+        acked: list[int] = []
         bytes_before = self.bytes_consumed
         self.last_ready = len(ready)
         for key, _events in ready:
@@ -233,13 +238,17 @@ class FleetConsumer:
             feed, self._tails[idx] = buf[: cut + 1], buf[cut + 1 :]
             self.bytes_consumed += len(feed)
             # Scribe-driven MSN: a summary ack in the feed is the zamboni
-            # TRIGGER (one substring probe per chunk, anchored on the wire
-            # type field — no extra parse).  The compaction floor itself is
-            # each host's min_seq, refreshed by the ack message's own
-            # min_seq stamp through ingest; the ack's contents["msn"] is
-            # the durable ack-derived floor, carried on the wire for
-            # consumers that need durability-bounded windows.
-            acked = acked or b'"type":"summaryAck"' in feed
+            # TRIGGER for THIS document (a feed is one document's socket;
+            # one substring probe per feed, anchored on the wire type field,
+            # no extra parse).  The compaction floor itself is the host's
+            # min_seq, refreshed by the ack message's own min_seq stamp
+            # through ingest; the ack's contents["msn"] is the durable
+            # ack-derived floor, carried on the wire for consumers that need
+            # durability-bounded windows.
+            n_acks = feed.count(b'"type":"summaryAck"')
+            if n_acks:
+                acked.append(idx)
+                self.acks_by_doc[idx] += n_acks
             if _BOOT_MARKER in feed:
                 # Fan-out plane drop-to-catch-up, boot flavor: the missed
                 # range left the retained log — snapshot-boot instead of
@@ -255,11 +264,14 @@ class FleetConsumer:
             # the moment the megastep budget falls behind.
             self._apply_flow_control()
         if acked:
-            # Compact collab windows on the ack, not on a timer: the
-            # scribe's durable floor just advanced, and every host's
-            # min_seq was refreshed by the ack message itself.
-            self.engine.compact()
+            # Compact collab windows on the ack, not on a timer, and only
+            # the acked documents': the engine compacts them in the step
+            # that has applied the rows read before the ack (never here,
+            # inside the pump).
+            self.engine.compact(acked)
+            self.acks_unstepped = True
             self.engine.counters.bump("msn_compactions")
+            self.engine.counters.bump("acks_seen", len(acked))
         sp.set(ready=len(ready), bytes=self.bytes_consumed - bytes_before,
                staged=staged)
         return staged
@@ -365,8 +377,10 @@ class FleetConsumer:
     def step(self) -> int:
         """Apply everything staged as one batched device step (the engine
         runs its own recovery, watchdog cadence, and checkpoint cadence
-        inside ``step`` when configured)."""
+        inside ``step`` when configured), then compact the documents whose
+        summary ack a pump handed over since the last step."""
         eng = self.engine
+        self.acks_unstepped = False
         with span("step", docs=len(eng._busy)) as sp:
             dispatches = eng.counters.get("megastep_dispatches")
             slices = eng.step()

@@ -417,6 +417,9 @@ def main(argv: list[str] | None = None) -> int:
             p.error(f"--historian wants host:port, got {args.historian!r}")
     fc = FleetConsumer(args.host, args.port, eng, doc_ids,
                        boot_store=boot_store, historian=historian)
+    if args.family == "string":
+        # The done line's reduction, built now: nothing compiles at exit.
+        eng.evictable_left()
     if fc.booted_docs:
         print(json.dumps({
             "bootedFromSummary": [doc_ids[d] for d in fc.booted_docs],
@@ -509,7 +512,14 @@ def main(argv: list[str] | None = None) -> int:
         """The per-family identity surface for the done=True status line."""
         if args.family == "tree":
             return {"trees": dict(zip(doc_ids, eng.trees_json()))}
-        return {"texts": dict(zip(doc_ids, eng.texts()))}
+        # The collab window's floor as the device holds it and the acks
+        # read, both in --docs order, and what zamboni left undone.
+        return {
+            "texts": dict(zip(doc_ids, eng.texts())),
+            "min_seqs": eng.device_min_seqs(),
+            "acks_seen": list(fc.acks_by_doc),
+            "evictable_left": eng.evictable_left(),
+        }
 
     terminated = False
 
@@ -572,11 +582,13 @@ def main(argv: list[str] | None = None) -> int:
                     doc_ids[i] for i in fc.dead_socks
                 ))
                 return 1
-            stepped = bool(staged or fc.paused_socks)
+            stepped = bool(staged or fc.paused_socks or fc.acks_unstepped)
             if stepped:
                 # Paused partitions mean staged backlog over the watermark:
                 # keep stepping so the gate can re-arm those sockets, even
                 # when this pump read nothing (flow control, not idleness).
+                # A summary ack read alone is work too: the step compacts
+                # the acked documents.
                 end_idle()  # paused partitions, nothing ready: work too
                 fc.step()
             else:
@@ -613,6 +625,13 @@ def main(argv: list[str] | None = None) -> int:
                         status(done=True, drained=True, **final_state())
                         return 0
     except KeyboardInterrupt:
+        eng.maybe_checkpoint(force=True)
+        return 0
+    except Exception:
+        # A SIGTERM that lands while JAX traces a program comes back as
+        # whatever the tracer makes of the interrupt, not as the interrupt.
+        if not terminated:
+            raise
         eng.maybe_checkpoint(force=True)
         return 0
     finally:

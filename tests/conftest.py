@@ -44,6 +44,7 @@ import pytest  # noqa: E402
 # lane).  Everything else is host-plane Python and forms the <2-min smoke
 # lane (`pytest -m "not device"`).
 _DEVICE_MODULES = {
+    "test_cohort_compact",
     "test_columnar_ingest",
     "test_doc_batch_engine",
     "test_fleet_consumer",

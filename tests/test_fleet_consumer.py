@@ -126,6 +126,57 @@ def test_wire_to_device_fleet_with_live_tail(server):
         fc.close()
 
 
+def test_a_summary_ack_hands_over_its_own_document_only(server):
+    """Two documents' feeds in one pump, one of them with a summary ack:
+    only that document is handed to the engine, and nothing is compacted
+    inside the pump (PR 35)."""
+    from fluidframework_tpu.protocol.messages import (
+        MessageType,
+        UnsequencedMessage,
+    )
+
+    ws = {d: _writers(server, d, 1) for d in ("a0", "a1")}
+    rows = 0
+    for d, (w,) in ws.items():
+        w.insert_text(0, "hello")
+        rows += _flush(server, d, [w])
+    with server.lock:
+        doc = server.service.document("a1")
+        doc.connect("summarizer", lambda m: None)
+        doc.process_all()
+        handle = doc.upload_summary({"type": "tree", "entries": {}})
+        doc.submit(UnsequencedMessage(
+            client_id="summarizer", client_seq=1, ref_seq=doc.sequencer.seq,
+            type=MessageType.SUMMARIZE,
+            contents={"handle": handle, "refSeq": doc.sequencer.seq},
+        ))
+        doc.process_all()
+    eng = DocBatchEngine(8, max_segments=256, text_capacity=4096,
+                         max_insert_len=8, ops_per_step=8, use_mesh=False)
+    handed = []
+    compact = eng.compact
+    eng.compact = lambda docs=None: (handed.append(list(docs)),
+                                     compact(docs))[1]
+    fc = FleetConsumer("127.0.0.1", server.port, eng, ["a0", "a1"])
+    try:
+        # Catch-up: everything is in the sockets; pump until both are read.
+        while fc.rows_staged < rows or not handed:
+            fc.pump(0.05)
+        h = eng.health()
+        assert handed == [[1]] and eng.compact_due == {1}
+        assert h["acks_seen"] == 1 and h["msn_compactions"] == 1
+        assert h["compact_dispatches"] == 0 and fc.acks_unstepped
+        assert fc.acks_by_doc == [0, 1]
+        fc.step()
+        h = eng.health()
+        assert (h["compact_dispatches"], h["compacted_docs"],
+                h["compacted_lanes"]) == (1, 1, 1)
+        assert not eng.compact_due and not fc.acks_unstepped
+        assert eng.texts()[:2] == ["hello", "hello"]
+    finally:
+        fc.close()
+
+
 def test_fleet_main_entry_cross_process(server):
     """The deployable fleet entry (deploy/compose.yaml fleet tier): spawn
     fleet_main as its OWN process against the TCP front; it consumes,

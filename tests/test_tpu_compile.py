@@ -80,3 +80,39 @@ def test_strip_write_compiles_for_the_v5e(one_chip, monkeypatch, n_docs,
     for op in ("scatter(", "copy(", "reshape("):
         assert not [ln for ln in text.splitlines() if op in ln
                     and f"s32[{n_docs},{capacity}]" in ln.split(op)[0]], op
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_cohort_compaction_moves_rows_and_never_the_fleet(one_chip, lanes):
+    """``_compact_cohort`` at fleet_main's geometry (6,144 x 4,096): the
+    donated columns come back aliased, the only instructions whose result
+    is a whole [6144, 4096] column are its in-place row updates, what the
+    program keeps beside its arguments is a few rows, and the text pool is
+    no operand of it (PR 35)."""
+    from fluidframework_tpu.models import doc_batch_engine as dbe
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    n_docs, segments = 6144, 4096
+    proto = jax.eval_shape(lambda: mk.init_state(segments, 4, 4, 65536, 8))
+    cols = {
+        f: jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                (n_docs, *x.shape), x.dtype, sharding=one_chip),
+            getattr(proto, f))
+        for f in dbe._COMPACT_FIELDS
+    }
+    assert "text" not in cols and "text_end" not in cols
+    idx = jax.ShapeDtypeStruct((lanes,), I32, sharding=one_chip)
+    compiled = dbe._compact_cohort.lower(cols, idx, idx).compile()
+    memory = compiled.memory_analysis()
+    column = n_docs * segments * 4
+    assert memory.alias_size_in_bytes >= 22 * column
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
+    assert memory.temp_size_in_bytes < column // 16
+    text = compiled.as_text()
+    assert "65536" not in text            # no pool, of the fleet or of a row
+    whole = re.compile(
+        r"= \(?s32\[%d,%d\]\S* ([\w-]+)\(" % (n_docs, segments))
+    ops = {m.group(1) for m in whole.finditer(text)}
+    assert ops <= {"parameter", "dynamic-update-slice", "get-tuple-element",
+                   "tuple", "bitcast", "while"}, ops
