@@ -82,6 +82,7 @@ from ..ops import mergetree_kernel as mk
 from ..parallel import mesh as pm
 from . import placement
 from ..protocol.messages import DeltaType, MessageType, SequencedMessage
+from ..utils.stack_room import with_stack_room
 from ..utils.telemetry import HealthCounters, Histogram, SampledTelemetryHelper
 from .recovery import (
     RecoveryTracker,
@@ -274,9 +275,34 @@ _lane_apply_jit = jax.jit(mk.apply_ops)
 _lane_compact_jit = jax.jit(lambda s, m: mk.compact(mk.set_min_seq(s, m)))
 
 
+# The cohort path's three programs (``DocBatchEngine._cohort_trio``): the
+# busy documents' rows gathered out of the fleet, stepped, and scattered
+# back.  The text pool takes no part in the moving: it stays in the fleet's
+# state, the gather and the scatter see a state without it (``text`` None),
+# and the step writes the strips its inserts append straight into it, at
+# the cohort's rows.  (A gather of 64 rows of the [6144, 65536] pool cost the
+# TPU a copy of the whole pool: XLA slices a gather's operand to halves that
+# fit its window first, 1.6 GB read and written for 16 MB of rows.)
 @jax.jit
 def _gather_cohort_jit(st, idx):
-    return jax.tree.map(lambda x: x[idx], st)
+    """The rows ``idx`` of every leaf of ``st``, the fleet's state without
+    its pool; the cohort's own ``text`` is empty ([lanes, 0])."""
+    sub = jax.tree.map(lambda x: x[idx], st)
+    return sub._replace(text=jnp.zeros((idx.shape[0], 0), mk.I32))
+
+
+# One [lanes, B] slice (K = 1) or a [K, lanes, B] ring applied to a gathered
+# cohort, the fleet's pool beside it and donated with it: ``_fleet_step``
+# and ``_fleet_megastep`` for rows that lie anywhere in the pool.  Named so
+# that the device trace reads them as step programs.
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _cohort_fleet_step(pool, sub, idx, ops, payloads):
+    return mk.apply_cohort_ops(pool, sub, idx, ops, payloads)
+
+
+_cohort_megastep = functools.partial(jax.jit, donate_argnums=(0, 1))(
+    mk.apply_cohort_megastep
+)
 
 
 @jax.jit
@@ -304,10 +330,17 @@ def _fleet_digest(state):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_cohort_jit(st, sub, idx, valid):
+    """``sub``'s lanes written to the rows ``idx`` of the donated ``st``
+    where ``valid`` (a pad lane goes nowhere).  Whole rows, the pool's
+    included, for ``restore_scatter``; the cohort path hands in the state
+    without its pool (``text`` None), and the cohort's empty ``text`` is
+    left behind."""
     def put(x, s):
         safe = jnp.where(valid, idx, x.shape[0])
         return x.at[safe].set(s, mode="drop")
 
+    if st.text is None:
+        sub = sub._replace(text=None)
     return jax.tree.map(put, st, sub)
 
 
@@ -576,6 +609,8 @@ class DocBatchEngine:
             ("fleet_megastep", self._megastep),
             ("fleet_compact", self._compact),
             ("compact_cohort", _compact_cohort),
+            ("cohort_step", _cohort_fleet_step),
+            ("cohort_megastep", _cohort_megastep),
             ("lane_apply", self._lane_apply),
         ):
             self.recompile_watchdog.register(prog_name, prog)
@@ -595,8 +630,10 @@ class DocBatchEngine:
         # When few docs remain busy, gather just those docs' state rows
         # into a power-of-two cohort, step the small sub-fleet, and
         # masked-scatter the rows back — pad lanes route out of bounds
-        # (mode="drop"), so duplicate writes never occur.  The jit caches
-        # one executable per cohort size (log2(D) variants).
+        # (mode="drop"), so duplicate writes never occur.  The text pool
+        # does not travel: the cohort's step writes into the fleet's, at
+        # the cohort's rows (_cohort_trio).  The jit caches one executable
+        # per cohort size (log2(D) variants).
         # Single-chip optimization: under a mesh the doc axis is sharded
         # evenly and arbitrary-index gathers would cross shards.
         self.bucketing = self.mesh is None
@@ -1528,7 +1565,9 @@ class DocBatchEngine:
         """One bucketed megastep over just the busy docs: gather the
         cohort's state rows once, apply up to K fused [Kc, B] slices, and
         masked-scatter the rows back — K > 1 amortizes the gather/scatter
-        pair as well as the dispatch.  Returns the slices applied."""
+        pair as well as the dispatch.  Every leaf moves but the text pool,
+        which stays in ``self.state`` and is written in place by the step
+        (``_cohort_trio``).  Returns the slices applied."""
         with span("pack", kind="cohort", docs=len(busy)):
             K = self._select_k(busy, cohort=True)
             Kc = self._cohort_lanes(len(busy))
@@ -1549,27 +1588,50 @@ class DocBatchEngine:
                 scanned += deepest
                 if k + 1 < K:
                     cur = [d for d in cur if d in self._busy]
-        with span("gather", lanes=Kc):
-            sub = self._gather_cohort(self.state, jnp.asarray(idx))
-        if K == 1:
-            dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
-            with span("dispatch", kind="cohort", k=K, lanes=Kc, rows=scanned):
-                sub = self._step(sub, dev_ops, dev_payloads)
+        if (Kc, K) in self._built:
+            self._cohort_trio(idx, valid, ops, payloads, scanned)
         else:
-            dev_ops, dev_payloads = stage.upload(ops, payloads)
-            with span("dispatch", kind="cohort", k=K, lanes=Kc, rows=scanned):
-                sub = self._megastep(sub, dev_ops, dev_payloads)
-        with span("scatter", lanes=Kc):
-            self.state = self._scatter_cohort(
-                self.state, sub, jnp.asarray(idx), jnp.asarray(valid)
-            )
-        self._built.add((Kc, K))
+            # A size's first dispatch traces and lowers three programs under
+            # this call: from a stretch of frame stack that no chunk boundary
+            # crosses, or the depth of this frame decides what they cost
+            # (utils/stack_room.py).
+            with_stack_room(self._cohort_trio, idx, valid, ops, payloads, scanned)
         self.cohort_steps += K
         self.cohort_lanes += K * Kc
         self.counters.bump("megastep_dispatches")
         self.counters.bump("megastep_slices", K)
         self._count_row_slots(scanned, K)
         return K
+
+    def _cohort_trio(self, idx, valid, ops, payloads, scanned: int = 0) -> None:
+        """The cohort path's three dispatches over the fleet rows ``idx``
+        ([lanes]; a pad lane repeats a row, carries NOOPs and is not
+        ``valid``), for the staged ``ops`` / ``payloads`` [K, lanes, B, ...].
+
+        Where the pool lives: in ``self.state.text`` throughout.  The gather
+        and the scatter are given the state without it; the step takes it
+        donated beside the gathered rows, writes its strips at ``idx`` and
+        hands it back, and it is put back into ``self.state`` right after
+        the dispatch."""
+        K, lanes = ops.shape[:2]
+        rows = jnp.asarray(idx)
+        with span("gather", lanes=lanes):
+            sub = self._gather_cohort(self.state._replace(text=None), rows)
+        if K == 1:
+            step = _cohort_fleet_step
+            dev = self._staging().upload(ops[0], payloads[0])
+        else:
+            step = _cohort_megastep
+            dev = self._staging().upload(ops, payloads)
+        with span("dispatch", kind="cohort", k=K, lanes=lanes, rows=scanned):
+            pool, sub = step(self.state.text, sub, rows, *dev)
+            self.state = self.state._replace(text=pool)
+        with span("scatter", lanes=lanes):
+            rest = self._scatter_cohort(
+                self.state._replace(text=None), sub, rows, jnp.asarray(valid)
+            )
+            self.state = rest._replace(text=pool)
+        self._built.add((lanes, K))
 
     def _step_lanes(self) -> None:
         B = self.ops_per_step
@@ -2951,16 +3013,12 @@ class DocBatchEngine:
                 # fleet-wide program above exists (_cold_and_shallow).
                 lanes = 1
                 while lanes <= self._cohort_lanes(self.capacity // 4):
-                    idx = jnp.asarray(np.zeros((lanes,), np.int32))
                     ops, payloads = stage.acquire(1, lanes)
-                    dev_ops, dev_payloads = stage.upload(ops[0], payloads[0])
-                    sub = self._gather_cohort(self.state, idx)
-                    sub = self._step(sub, dev_ops, dev_payloads)
-                    self.state = self._scatter_cohort(
-                        self.state, sub, idx,
-                        jnp.asarray(np.zeros((lanes,), bool)),
+                    with_stack_room(
+                        self._cohort_trio,
+                        np.zeros((lanes,), np.int32), np.zeros((lanes,), bool),
+                        ops, payloads,
                     )
-                    self._built.add((lanes, 1))
                     warmed += 1
                     lanes *= 2
             depths = []

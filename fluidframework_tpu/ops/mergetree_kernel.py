@@ -41,7 +41,9 @@ Design notes (TPU):
   and written back in place (``_write_text``; a Pallas kernel over whole tile
   rows of documents, ops/pallas_kernels.py).  The write costs D strips of
   B * L elements, not the pool: a scatter into [D, T] has XLA relay the whole
-  pool out to one axis and back.
+  pool out to one axis and back.  A cohort (a few busy documents of a fleet)
+  is stepped without its pool rows: ``apply_cohort_ops`` takes the fleet's
+  pool beside the cohort's other leaves and writes the strips at their rows.
 - Capacity overflow (segments, text pool, remove slots) sets an error bit
   instead of trapping; the host inspects error flags and reacts (grow +
   re-replay, or route the doc to the host oracle).
@@ -759,22 +761,23 @@ def _unwritten(writes: _TextWrite, done):
     return (slot >= done[:, None]) & (writes.count > 0)
 
 
-def _strip_pass(text, writes: _TextWrite, payloads, done):
+def _strip_pass(capacity: int, writes: _TextWrite, payloads, done):
     """One pass of ``_write_text``: per document, the strip around its first
     row not written yet (slot >= ``done``), with every following row written
-    into it in order for as long as the rows lie inside it.  Returns the pool
-    and the new ``done``.
+    into it in order for as long as the rows lie inside it.  Returns the
+    strips as ``write_text_strips`` takes them (``starts`` [D], ``new`` and
+    ``mask`` [D, strip], for a pool ``capacity`` wide) and the new ``done``.
 
     The strip's contents are composed row by row over the live prefix of the
     slots (a later row over an earlier one: the last writer wins, as when the
-    rows write one after another), [D, strip] of work a row; then each
-    document's strip is read, merged and written back in place
-    (``write_text_strips``)."""
+    rows write one after another), [D, strip] of work a row; the caller's
+    writer then reads, merges and writes back each document's strip in
+    place."""
     # Imported where it is traced: Pallas costs a second to import, and most
     # processes that import this module never trace a step.
     from . import pallas_kernels as pk
 
-    n_docs, cap = text.shape
+    n_docs, cap = writes.count.shape[0], capacity
     n_rows, width = payloads.shape[1:]
     strip = pk.text_strip_width(cap, min(n_rows * width, cap))
     slot = jnp.arange(n_rows, dtype=I32)
@@ -804,13 +807,18 @@ def _strip_pass(text, writes: _TextWrite, payloads, done):
         jnp.min(lead), rows, compose,
         (jnp.zeros((n_docs, strip), I32), jnp.zeros((n_docs, strip), bool)),
     )
-    return pk.write_text_strips(text, at, new, mask.astype(I32)), stop
+    return (at, new, mask.astype(I32)), stop
 
 
-def _write_text(text, writes: _TextWrite, payloads):
+def _write_text(text, writes: _TextWrite, payloads, write):
     """Apply the text writes of a batch of rows to the pools of D documents:
-    text [D, T], starts and counts [D, B], payloads [D, B, L].  The result is
-    the rows' writes applied one after another, element for element.
+    starts and counts [D, B], payloads [D, B, L].  The result is the rows'
+    writes applied one after another, element for element.  ``write(text,
+    starts, new, mask)`` puts a pass's strips into the pool: ``text`` is
+    [D, T] and document d owns row d of it (``pallas_kernels
+    .write_text_strips``: a fleet-wide step, a mesh shard, a host lane), or
+    it is the pool of a larger fleet of which the D documents, a cohort, own
+    a few rows anywhere (``write_text_strips_at`` bound to those rows).
 
     The pool is append-only and nothing in the op body reads it, so the row
     loop does not carry it, and a row writes at its document's ``text_end``,
@@ -824,10 +832,14 @@ def _write_text(text, writes: _TextWrite, payloads):
     ``text_len`` exceeds L moves the next starts past the strip; such rows
     take a further pass each, so the loop below runs once for every batch an
     engine can stage, and not at all for a batch that writes nothing."""
+    def strip_pass(carry):
+        strips, done = _strip_pass(text.shape[1], writes, payloads, carry[1])
+        return write(carry[0], *strips), done
+
     text, _ = jax.lax.while_loop(
         lambda carry: jnp.any(_unwritten(writes, carry[1])),
-        lambda carry: _strip_pass(carry[0], writes, payloads, carry[1]),
-        (text, jnp.zeros(text.shape[:1], I32)),
+        strip_pass,
+        (text, jnp.zeros(writes.count.shape[:1], I32)),
     )
     return text
 
@@ -1018,19 +1030,26 @@ def _ob_gate(s: DocState, ops: jnp.ndarray) -> jnp.ndarray:
     return jnp.any(s.ob_key >= 0) | jnp.any(ops[..., 0] == OpKind.OBLITERATE)
 
 
-def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool) -> DocState:
+def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool, capacity: int):
     """The row loop of ``apply_ops`` (one document) and, with ``over_docs``,
-    of ``apply_fleet_ops`` (every leaf, ``ops`` and ``payloads`` lead with
-    the document axis): a ``lax.fori_loop`` over the first ``row_count(ops)``
-    of the B row slots.  The loop is OUTSIDE the ``vmap`` over documents and
-    its body is the vmapped row: one trip count for the whole batch, and the
-    row is traced once (``vmap`` of a ``while`` batches its body twice, and
-    a batched bound would make it select over the whole carried state every
-    iteration).  The loop carries every leaf but the text pool and collects
-    the rows' ``_TextWrite``s; the pool is written once, after the loop and
-    after the obliterate gate's ``cond`` (``_write_text``)."""
+    of ``apply_fleet_ops`` and ``apply_cohort_ops`` (every leaf, ``ops`` and
+    ``payloads`` lead with the document axis): a ``lax.fori_loop`` over the
+    first ``row_count(ops)`` of the B row slots.  The loop is OUTSIDE the
+    ``vmap`` over documents and its body is the vmapped row: one trip count
+    for the whole batch, and the row is traced once (``vmap`` of a ``while``
+    batches its body twice, and a batched bound would make it select over the
+    whole carried state every iteration).
+
+    The text pool is no part of it: the loop carries every other leaf and
+    collects the rows' ``_TextWrite``s, and returns the state with an empty
+    ``text`` beside them.  Where the pool lives is the caller's: ``s.text``
+    of ``apply_ops`` and ``apply_fleet_ops`` (a lane's document, the fleet's
+    or a shard's every row), the fleet's pool beside the cohort's rows in
+    ``apply_cohort_ops`` (there ``s.text`` is empty already).  Its width is
+    ``capacity``, and it is written once, after the loop and after the
+    obliterate gate's ``cond`` (``_write_text``)."""
     lift = jax.vmap if over_docs else (lambda f: f)
-    B, T = ops.shape[-2], s.text.shape[-1]
+    B, T = ops.shape[-2], capacity
     slot = jnp.arange(B, dtype=I32)
     rows = row_count(ops)
 
@@ -1063,21 +1082,17 @@ def _row_loop(s: DocState, ops, payloads, ob_flag, over_docs: bool) -> DocState:
     # rows' writes do not depend on the flag, and go in after both.
     bare = s._replace(text=jnp.zeros(s.text.shape[:-1] + (0,), I32))
     if isinstance(ob_flag, bool):
-        out, writes = loop_spec(bare, ob_flag)
-    else:
-        # Hoist the runtime branch to WHOLE-LOOP level: one cond per batch
-        # instead of two per op, so the common no-obliterate path is a single
-        # fully-fused loop body (conds inside a loop break XLA fusion and
-        # were costing ~2x on obliterate-free workloads).
-        out, writes = jax.lax.cond(
-            ob_flag,
-            lambda st: loop_spec(st, True),
-            lambda st: loop_spec(st, False),
-            bare,
-        )
-    fleet = (lambda x: x) if over_docs else (lambda x: x[None])
-    text = _write_text(fleet(s.text), jax.tree.map(fleet, writes), fleet(payloads))
-    return out._replace(text=text if over_docs else text[0])
+        return loop_spec(bare, ob_flag)
+    # Hoist the runtime branch to WHOLE-LOOP level: one cond per batch
+    # instead of two per op, so the common no-obliterate path is a single
+    # fully-fused loop body (conds inside a loop break XLA fusion and
+    # were costing ~2x on obliterate-free workloads).
+    return jax.lax.cond(
+        ob_flag,
+        lambda st: loop_spec(st, True),
+        lambda st: loop_spec(st, False),
+        bare,
+    )
 
 
 def apply_op(
@@ -1113,9 +1128,19 @@ def apply_ops(
     lanes, replay); the parallelism over documents is ``apply_fleet_ops``,
     not ``vmap`` of this (see ``apply_op``).
     """
+    from . import pallas_kernels as pk
+
     if ob_flag is None:
         ob_flag = _ob_gate(s, ops)
-    return _row_loop(s, ops, payloads, ob_flag, over_docs=False)
+    out, writes = _row_loop(
+        s, ops, payloads, ob_flag, over_docs=False, capacity=s.text.shape[-1]
+    )
+    one = lambda x: x[None]
+    text = _write_text(
+        one(s.text), jax.tree.map(one, writes), one(payloads),
+        pk.write_text_strips,
+    )
+    return out._replace(text=text[0])
 
 
 def apply_fleet_ops(
@@ -1136,9 +1161,15 @@ def apply_fleet_ops(
 
     ops: int32[D, B, OP_FIELDS]; payloads: int32[D, B, L].
     """
+    from . import pallas_kernels as pk
+
     if ob_flag is None:
         ob_flag = _ob_gate(s, ops)
-    return _row_loop(s, ops, payloads, ob_flag, over_docs=True)
+    out, writes = _row_loop(
+        s, ops, payloads, ob_flag, over_docs=True, capacity=s.text.shape[-1]
+    )
+    text = _write_text(s.text, writes, payloads, pk.write_text_strips)
+    return out._replace(text=text)
 
 
 def apply_megastep(
@@ -1169,6 +1200,50 @@ def apply_megastep(
         return apply_fleet_ops(st, *xs), None
 
     out, _ = jax.lax.scan(body, s, (ops, payloads))
+    return out
+
+
+def apply_cohort_ops(
+    pool: jnp.ndarray, s: DocState, rows: jnp.ndarray, ops: jnp.ndarray,
+    payloads: jnp.ndarray,
+) -> tuple[jnp.ndarray, DocState]:
+    """``apply_fleet_ops`` for a cohort whose text stays in its fleet's pool:
+    one [C, B] slice of ops applied to the C documents at the rows ``rows``
+    of a fleet.  ``s`` holds those rows of every leaf but the pool (``text``
+    [C, 0]); ``pool`` is the fleet's [D, T], of which the step writes the
+    strips its inserts append, in place, at the rows ``rows``, and nothing
+    else.  Returns ``(pool, s)``.
+
+    The gate and the trip count are the cohort's own, as a fleet-wide step
+    over these C documents alone would take them, and the result is that
+    step's, bit for bit: the same ``s``, and in ``pool`` the same rows.  A
+    lane whose rows are all NOOPs writes nothing and may repeat another
+    lane's row (the engine pads a cohort so).
+
+    rows: int32[C]; ops: int32[C, B, OP_FIELDS]; payloads: int32[C, B, L].
+    """
+    from . import pallas_kernels as pk
+
+    out, writes = _row_loop(
+        s, ops, payloads, _ob_gate(s, ops), over_docs=True,
+        capacity=pool.shape[-1],
+    )
+    at_rows = lambda pool, *strips: pk.write_text_strips_at(pool, rows, *strips)
+    return _write_text(pool, writes, payloads, at_rows), out
+
+
+def apply_cohort_megastep(
+    pool: jnp.ndarray, s: DocState, rows: jnp.ndarray, ops: jnp.ndarray,
+    payloads: jnp.ndarray,
+) -> tuple[jnp.ndarray, DocState]:
+    """``apply_megastep`` for a cohort (``apply_cohort_ops``): a [K, C, B]
+    op ring in one program, the fleet's pool carried through the ``scan``
+    beside the cohort's rows.  Bit-identical to K ``apply_cohort_ops``."""
+
+    def body(carry, xs):
+        return apply_cohort_ops(*carry, rows, *xs), None
+
+    out, _ = jax.lax.scan(body, (pool, s), (ops, payloads))
     return out
 
 

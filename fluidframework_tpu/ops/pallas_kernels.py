@@ -1,5 +1,7 @@
-"""Pallas TPU kernels: the long-document position search and the fleet
-step's text-pool write (``write_text_strips``, at the end of the file).
+"""Pallas TPU kernels: the long-document position search and the step's
+text-pool write (``write_text_strips`` for a fleet-wide step, which owns every
+row of the pool in order; ``write_text_strips_at`` for a cohort step, which
+owns a few rows anywhere; both at the end of the file).
 
 Position resolution over a long document asks: for each query position q
 (perspective-visible coordinates), which segment contains q and at what
@@ -140,6 +142,7 @@ def resolve_positions_blocked(
 LANES, SUBLANES = 128, 8    # the (8, 128) tile of an int32 array in HBM
 STRIP_TILE_ROWS = 64        # tile rows (of 8 documents) per grid step, at most
 STRIP_VMEM_BYTES = 8 << 20  # ... and as many as fit this much VMEM
+STRIP_ROW_LANES = 256       # cohort lanes per grid step, at most (same fit)
 
 
 def text_strip_width(capacity: int, window: int) -> int:
@@ -192,11 +195,24 @@ def _strip_kernel(start_ref, new_ref, mask_ref, pool_in, pool, buf, sem, *,
     jax.lax.fori_loop(0, SUBLANES, turn, 0)
 
 
+def _merge_strip(pool, row, start, lane, new, mask):
+    """``pool[row, start + i] = new[lane, i]`` wherever ``mask[lane, i] != 0``:
+    one strip by a plain slice and update, for the documents no kernel call
+    takes."""
+    at = (row, start)
+    old = jax.lax.dynamic_slice(pool, at, (1, new.shape[1]))
+    strip = jnp.where(mask[lane] != 0, new[lane], old[0])
+    return jax.lax.dynamic_update_slice(pool, strip[None], at)
+
+
 def write_text_strips(pool, starts, new, mask):
     """``pool[d, starts[d] + j] = new[d, j]`` wherever ``mask[d, j] != 0``,
     for every document d and j < width: one strip a document, read, merged
     and written back in place (the pool is aliased in and out), so the cost
-    follows D x width and not the pool.
+    follows D x width and not the pool.  This is the write of a step that owns
+    the whole pool, document d at row d (the fleet-wide step, a mesh shard, a
+    host lane's one document); a cohort, whose documents are a few rows of
+    a pool it does not carry, writes through ``write_text_strips_at``.
 
     pool: int32[D, T]; starts: int32[D], each a multiple of ``LANES`` where
     width is (``text_strip_width``) and at most T - width; new, mask:
@@ -237,10 +253,106 @@ def write_text_strips(pool, starts, new, mask):
             name="text_strip_write",
         )(starts, new, mask, pool)
 
-    def one(d, pool):
-        at = (d, starts[d])
-        old = jax.lax.dynamic_slice(pool, at, (1, width))
-        strip = jnp.where(mask[d] != 0, new[d], old[0])
-        return jax.lax.dynamic_update_slice(pool, strip[None], at)
+    return jax.lax.fori_loop(
+        tile_rows * SUBLANES, n_docs,
+        lambda d, pool: _merge_strip(pool, d, starts[d], d, new, mask), pool)
 
-    return jax.lax.fori_loop(tile_rows * SUBLANES, n_docs, one, pool)
+
+def _strip_rows_kernel(row_ref, start_ref, turn_ref, new_ref, mask_ref,
+                       pool_in, pool, buf, sem, *, lanes: int):
+    """One grid step: up to ``STRIP_ROW_LANES`` lanes of a cohort, each with
+    a pool row of its own.  A DMA moves whole (8, 128) tiles, so a lane's
+    strip travels with the seven other rows of its tile row, and two lanes
+    whose rows share a tile row may not have it in flight at once: as in
+    ``_strip_kernel`` the lanes take eight turns, a lane at row ``r`` the
+    turn ``r % 8`` (two rows of one tile row never share it), and every read
+    of a turn has landed before a strip of it is merged and sent back.  A
+    lane that writes nothing has turn -1 and moves nothing."""
+    del pool_in  # the same buffer as ``pool`` (input_output_aliases)
+    block, _, width = buf.shape
+    base = pl.program_id(0) * block
+    n = jnp.minimum(block, lanes - base)
+    place = jax.lax.broadcasted_iota(I32, buf.shape[1:], 0)
+
+    def copy(t, back: bool):
+        first = row_ref[base + t] // SUBLANES * SUBLANES
+        hbm = pool.at[pl.ds(pl.multiple_of(first, SUBLANES), SUBLANES)]
+        if width < pool.shape[1]:    # else the strip is the row (start 0)
+            at = pl.multiple_of(start_ref[base + t], LANES)
+            hbm = hbm.at[:, pl.ds(at, width)]
+        if back:
+            return pltpu.make_async_copy(buf.at[t], hbm, sem.at[1])
+        return pltpu.make_async_copy(hbm, buf.at[t], sem.at[0])
+
+    def turn(r, carry):
+        def each(fn):
+            def lane(t, c):
+                pl.when(turn_ref[base + t] == r)(lambda: fn(t))
+                return c
+
+            jax.lax.fori_loop(0, n, lane, 0)
+
+        def merge(t):
+            take = (place == r) & (mask_ref[pl.ds(t, 1), :] != 0)
+            buf[t] = jnp.where(take, new_ref[pl.ds(t, 1), :], buf[t])
+
+        each(lambda t: copy(t, False).start())
+        each(lambda t: copy(t, False).wait())
+        each(merge)
+        each(lambda t: copy(t, True).start())
+        each(lambda t: copy(t, True).wait())
+        return carry
+
+    jax.lax.fori_loop(0, SUBLANES, turn, 0)
+
+
+def write_text_strips_at(pool, rows, starts, new, mask):
+    """``pool[rows[j], starts[j] + i] = new[j, i]`` wherever ``mask[j, i] !=
+    0``, for every lane j and i < width: ``write_text_strips`` for a cohort,
+    whose lanes' documents lie at the rows ``rows`` of a pool that stays
+    where it is (aliased in and out): the cost follows lanes x width, and
+    neither the pool's rows nor its columns.
+
+    pool: int32[D, T]; rows: int32[lanes], each in [0, D); starts:
+    int32[lanes], as ``write_text_strips`` wants them; new, mask:
+    int32[lanes, width].  The lanes that write anything (a nonzero mask)
+    name different rows; the others may repeat a row (a cohort's pad lanes
+    do) and touch nothing.  A pool of whole (8, 128) tiles goes through the
+    Pallas kernel (Mosaic on a TPU, the interpreter elsewhere); any other
+    takes a plain ``dynamic_update_slice`` a lane, one after the other.
+    """
+    n_docs, capacity = pool.shape
+    lanes, width = new.shape
+    if n_docs % SUBLANES or capacity % LANES:
+        return jax.lax.fori_loop(
+            0, lanes,
+            lambda j, pool: _merge_strip(pool, rows[j], starts[j], j, new, mask),
+            pool)
+
+    turn = jnp.where(jnp.any(mask != 0, axis=1), rows % SUBLANES, -1)
+    # A grid step holds its lanes' tile rows once in scratch and their new
+    # values and masks twice each (the pipeline's two buffers).
+    fit = STRIP_VMEM_BYTES // ((SUBLANES + 4) * width * 4)
+    block = max(SUBLANES, min(STRIP_ROW_LANES, fit) // SUBLANES * SUBLANES)
+    block = min(block, lanes)   # (a cohort of one step is one whole block)
+    return pl.pallas_call(
+        functools.partial(_strip_rows_kernel, lanes=lanes),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(lanes, block),),
+            in_specs=[
+                pl.BlockSpec((block, width), lambda i, *_: (i, 0)),
+                pl.BlockSpec((block, width), lambda i, *_: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((block, SUBLANES, width), I32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        input_output_aliases={5: 0},
+        interpret=jax.default_backend() != "tpu",
+        name="text_strip_write_rows",
+    )(rows, starts, turn, new, mask, pool)

@@ -44,6 +44,7 @@ from fluidframework_tpu.dds.mergetree_ref import RefMergeTree
 from fluidframework_tpu.dds.shared_string import SharedString
 from fluidframework_tpu.models import doc_batch_engine as dbe
 from fluidframework_tpu.ops import mergetree_kernel as mk
+from fluidframework_tpu.ops import pallas_kernels as pk
 from fluidframework_tpu.ops.pallas_kernels import text_strip_width
 from fluidframework_tpu.server.local_service import LocalDocument
 
@@ -742,7 +743,8 @@ def test_text_write_equals_the_writes_in_order(case, n_docs):
     payloads = rng.integers(1, 1000, (n_docs, n, width)).astype(np.int32)
     text = -rng.integers(1, 1000, (n_docs, cap)).astype(np.int32)
     want = _writes_in_order(text, starts, counts, payloads)
-    got = jax.jit(mk._write_text)(
+    write = functools.partial(mk._write_text, write=pk.write_text_strips)
+    got = jax.jit(write)(
         jnp.asarray(text),
         mk._TextWrite(jnp.asarray(starts), jnp.asarray(counts)),
         jnp.asarray(payloads))

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from fluidframework_tpu.ops.pallas_kernels import (
     LANES,
+    STRIP_ROW_LANES,
     STRIP_TILE_ROWS,
     SUBLANES,
     resolve_positions_blocked,
@@ -19,6 +20,7 @@ from fluidframework_tpu.ops.pallas_kernels import (
     resolve_positions_reference,
     text_strip_width,
     write_text_strips,
+    write_text_strips_at,
 )
 
 
@@ -153,3 +155,51 @@ def test_strip_write_merges_one_strip_a_document(n_docs, capacity, window):
         jnp.asarray(mask))
     np.testing.assert_array_equal(np.asarray(got), want)
     assert not np.array_equal(want, pool) or n_docs == 1
+
+
+@pytest.mark.parametrize("n_docs,capacity,window", [
+    (64, 1024, 256), (24, 512, 64), (8, 96, 256), (16, 200, 64),
+    (21, 1024, 256)],
+    ids=["strips", "narrow_strips", "whole_rows", "pool_not_of_lanes",
+         "pool_not_of_tile_rows"])
+@pytest.mark.parametrize("lanes", [1, 3, SUBLANES, 33, 300])
+def test_strip_write_at_rows_merges_one_strip_a_lane(n_docs, capacity, window,
+                                                     lanes):
+    """``write_text_strips_at`` == numpy, element for element: rows in any
+    order and all over the pool (neighbours in a tile row among them), pad
+    lanes that repeat the last live row with nothing in their mask, a lane
+    count past one grid step (300 > ``STRIP_ROW_LANES``), strips of whole
+    lanes, rows that are their own strip, and the pools the kernel does not
+    take (not made of whole lanes, or not of whole tile rows: plain updates
+    one lane after the other).  Rows no lane names keep every bit."""
+    assert STRIP_ROW_LANES < 300
+    rng = np.random.default_rng([n_docs, capacity, window, lanes])
+    width = text_strip_width(capacity, window)
+    live = max(1, min(lanes, n_docs) - (lanes > 2) * min(lanes, n_docs) // 4)
+    rows = rng.permutation(n_docs)[:live]
+    if live >= 2:
+        rows[1] = rows[0] ^ 1                       # one tile row, two lanes
+        rows = np.array(list(dict.fromkeys(rows.tolist())))
+        live = len(rows)
+    rows = np.concatenate(
+        [rows, np.full(lanes - live, rows[-1])]).astype(np.int32)
+    starts = (rng.integers(0, (capacity - width) // LANES + 1, lanes)
+              * LANES).astype(np.int32)
+    starts[0] = capacity - width
+    pool = rng.integers(-1000, 0, (n_docs, capacity)).astype(np.int32)
+    new = rng.integers(1, 1000, (lanes, width)).astype(np.int32)
+    mask = (rng.random((lanes, width)) < 0.3).astype(np.int32)
+    mask[live:] = 0
+    if live > 2:
+        mask[2] = 0                                 # a live lane with no write
+    want = pool.copy()
+    for j in range(lanes):
+        strip = want[rows[j], starts[j]:starts[j] + width]
+        strip[mask[j] != 0] = new[j][mask[j] != 0]
+    got = jax.jit(write_text_strips_at)(
+        jnp.asarray(pool), jnp.asarray(rows), jnp.asarray(starts),
+        jnp.asarray(new), jnp.asarray(mask))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert not np.array_equal(want, pool)
+    untouched = np.setdiff1d(np.arange(n_docs), rows)
+    np.testing.assert_array_equal(np.asarray(got)[untouched], pool[untouched])

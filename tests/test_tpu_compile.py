@@ -1,8 +1,10 @@
 """The text pool's strip write, compiled HERE for a TPU v5e that is described
 and not attached (the TPU's compiler is installed in the sandbox): Mosaic
-accepts the kernel at the fleets' real widths, the pool goes in and out in
-place, and no pool-sized temporary is made.  Nothing runs, so nothing here is
-a time; interpret mode (tests/test_pallas_kernels.py) holds the results.
+accepts the kernels at the fleets' real widths, the pool goes in and out in
+place, and no pool-sized temporary is made; the cohort trio's gather and
+scatter never see the pool, and its step takes it aliased.  Nothing runs, so
+nothing here is a time; interpret mode (tests/test_pallas_kernels.py) holds
+the results.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU's library, and every xdist worker imports this file.
@@ -116,3 +118,93 @@ def test_cohort_compaction_moves_rows_and_never_the_fleet(one_chip, lanes):
     ops = {m.group(1) for m in whole.finditer(text)}
     assert ops <= {"parameter", "dynamic-update-slice", "get-tuple-element",
                    "tuple", "bitcast", "while"}, ops
+
+
+@pytest.mark.parametrize("n_docs,capacity,window,lanes", [
+    (6144, 65536, 32 * 8, 1),
+    (6144, 65536, 32 * 8, 64),
+    (6144, 65536, 32 * 8, 1024),    # four grid steps of STRIP_ROW_LANES
+    (1024, 16384, 32 * 64, 256),    # the engine's default max_insert_len
+    (64, 1024, 32 * 64, 16),        # a pool narrower than the window
+], ids=["one_lane", "cohort_64", "cohort_1024", "insert_len_64", "whole_row"])
+def test_strip_write_at_rows_compiles_for_the_v5e(one_chip, monkeypatch,
+                                                  n_docs, capacity, window,
+                                                  lanes):
+    """``write_text_strips_at`` at the cohort sizes of fleet_main's geometry:
+    Mosaic takes it, the pool's bytes are aliased to the result, and beside
+    its arguments the program keeps a few strips at most, never a pool."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    width = pk.text_strip_width(capacity, window)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    compiled = jax.jit(pk.write_text_strips_at, donate_argnums=0).lower(
+        arg(n_docs, capacity), arg(lanes), arg(lanes), arg(lanes, width),
+        arg(lanes, width)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == n_docs * capacity * 4
+    assert memory.temp_size_in_bytes <= (
+        (1 << 18) + 4 * lanes * pk.SUBLANES * width * 4)
+    for op in ("scatter(", "copy(", "reshape(", "gather("):
+        assert not [ln for ln in text.splitlines() if op in ln
+                    and f"s32[{n_docs},{capacity}]" in ln.split(op)[0]], op
+
+
+def test_cohort_trio_moves_rows_and_never_the_pool(one_chip, monkeypatch):
+    """The cohort trio at fleet_main's geometry (6,144 x 4,096 x 65,536), 64
+    lanes: the gather's and the scatter's programs have no operand, result
+    or temporary with the pool's 65,536 columns (or half of them: XLA split
+    the pool in two to gather 64 rows of it, a copy of 1.6 GB a trio until
+    PR 37); the step takes the pool aliased in and out, the only
+    instructions whose result is a whole pool hand it on in place, and what
+    it keeps beside its arguments is rows."""
+    from fluidframework_tpu.models import doc_batch_engine as dbe
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_docs, lanes, chars = 6144, 64, 65536
+    proto = jax.eval_shape(lambda: mk.init_state(4096, 4, 4, chars, 8))
+
+    def rows(n):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                (n, *x.shape), x.dtype, sharding=one_chip), proto)
+
+    def arg(*shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fleet = rows(n_docs)
+    rest = fleet._replace(text=None)
+    sub = rows(lanes)._replace(text=arg(lanes, 0))
+    pool_bytes = n_docs * chars * 4
+    wide = re.compile(r"s32\[\d+,(?:65536|32768)\]")
+
+    gather = dbe._gather_cohort_jit.lower(rest, arg(lanes)).compile()
+    assert not wide.search(gather.as_text())
+    assert jax.tree.structure(gather.out_info) == jax.tree.structure(sub)
+    assert gather.memory_analysis().temp_size_in_bytes < pool_bytes // 64
+
+    scatter = dbe._scatter_cohort_jit.lower(
+        rest, sub, arg(lanes), arg(lanes, dtype=jnp.bool_)).compile()
+    assert not wide.search(scatter.as_text())
+    memory = scatter.memory_analysis()
+    assert memory.temp_size_in_bytes < pool_bytes // 64
+    assert memory.alias_size_in_bytes > 22 * n_docs * 4096 * 4
+
+    step = dbe._cohort_fleet_step.lower(
+        fleet.text, sub, arg(lanes), arg(lanes, 32, mk.OP_FIELDS),
+        arg(lanes, 32, 8)).compile()
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
+    assert memory.temp_size_in_bytes < pool_bytes // 64
+    text = step.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    whole = re.compile(
+        r"= \(?s32\[%d,(?:65536|32768)\]\S* ([\w-]+)\(" % n_docs)
+    ops = {m.group(1) for m in whole.finditer(text)}
+    assert ops <= {"parameter", "custom-call", "get-tuple-element", "tuple",
+                   "bitcast", "while"}, ops
