@@ -522,7 +522,7 @@ def _engine_round_driver(n_docs: int, megastep_k: int, seed: int = 0):
     eng = DocBatchEngine(
         n_docs, max_segments=4096, text_capacity=32768, max_insert_len=16,
         ops_per_step=16, use_mesh=False, recovery="grow",
-        megastep_k=megastep_k, latency_sample_every=4,
+        megastep_k=megastep_k,
     )
     for d in range(n_docs):
         eng.ingest(d, SequencedMessage(
@@ -545,6 +545,7 @@ def _engine_round_driver(n_docs: int, megastep_k: int, seed: int = 0):
                 seq=int(seqs[d]), min_seq=0, ref_seq=int(seqs[d]) - 1,
                 client_id="w0", client_seq=r, type=MessageType.OP,
                 contents={"type": 0, "pos1": pos, "seg": "abcd"},
+                timestamp=time.time(),  # as the sequencer stamps it
             ))
             lengths[d] += 4
         eng.ingest_batch(idxs, msgs)
@@ -553,9 +554,7 @@ def _engine_round_driver(n_docs: int, megastep_k: int, seed: int = 0):
     one_round()  # warm the compiled step outside any timer
     # The warmup round's latency samples include the XLA compile; reset so
     # the reported percentiles describe the steady pipeline.
-    H = type(eng.op_latency)
-    eng.op_latency = H()
-    eng._shard_latency = [H() for _ in eng._shard_latency]
+    eng.op_clock = type(eng.op_clock)(eng.n_shards, eng.shard_of)
 
     def run(n_rounds: int) -> float:
         t0 = time.perf_counter()

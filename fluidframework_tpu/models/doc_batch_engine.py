@@ -77,7 +77,7 @@ import numpy as np
 from ..dds import kernel_backend as kb
 from ..dds.mergetree_ref import RefMergeTree
 from ..dds.shared_string import decode_obliterate_places
-from ..observability.flight_recorder import RecompileWatchdog, instant, span
+from ..observability import OpClock, RecompileWatchdog, instant, span
 from ..ops import mergetree_kernel as mk
 from ..parallel import mesh as pm
 from . import placement
@@ -371,7 +371,6 @@ class DocBatchEngine:
         megastep_k: int = 1,
         spare_slots: int = 0,
         telemetry=None,
-        latency_sample_every: int = 16,
         overload_high_watermark: int = 0,
         overload_low_watermark: int = 0,
         seg_shards: int = 0,
@@ -499,16 +498,6 @@ class DocBatchEngine:
             if telemetry is not None
             else None
         )
-        # Op end-to-end latency: sequencer stamp time -> applied-on-device
-        # readback, sampled every ``latency_sample_every`` staged ops (the
-        # per-message cost of full tracking would show on the feed path).
-        # Pending samples resolve at the step() sync boundary (recover()'s
-        # error readback proves the dispatches that drained them retired).
-        self.latency_sample_every = max(1, latency_sample_every)
-        self.op_latency = Histogram()
-        self._lat_tick = 0
-        self._lat_pending: list[tuple[float, int]] = []
-
         if use_mesh:
             if mesh is not None:
                 self.mesh = mesh
@@ -532,7 +521,12 @@ class DocBatchEngine:
         self.seg_rebalance_every = seg_rebalance_every
         self.max_seg_lanes = max_seg_lanes
         self.n_shards = n_shards
-        self._shard_latency = [Histogram() for _ in range(n_shards)]
+        # The op's own clock (observability/op_clock.py): sequencer stamp ->
+        # received -> applied on the device, one sample a feed weighted by
+        # its rows (a message on the per-message path).  Pending feeds
+        # resolve at the step() sync boundary (recover()'s error readback
+        # proves the dispatches that drained them retired).
+        self.op_clock = OpClock(n_shards, self.shard_of)
         # Device-row placement rides the shared plane (models/placement.py):
         # doc -> slot indirection with per-shard spare-slot free pools, the
         # same plane the tree fleet rides.  ``_slot`` aliases the plane's
@@ -703,7 +697,7 @@ class DocBatchEngine:
         h.ops_since_ckpt += 1
         if not h.dirty_since:
             h.dirty_since = time.monotonic()
-        self._lat_sample(doc_idx, msg.timestamp)
+        self.op_clock.feed(msg.timestamp, self.op_clock.now(), 1, doc_idx)
         if h.boot_counting:
             # Post-summary tail actually replayed on a boot-from-checkpoint/
             # summary consumer (the skipped prefix counts separately above;
@@ -832,7 +826,7 @@ class DocBatchEngine:
             h.ops_since_ckpt += 1
             if not h.dirty_since:
                 h.dirty_since = time.monotonic()
-            self._lat_sample(d, msg.timestamp)
+            self.op_clock.feed(msg.timestamp, self.op_clock.now(), 1, d)
             if h.boot_counting:
                 counters.bump("boot_replay_len")
             if self.recovery != "off":
@@ -1026,6 +1020,7 @@ class DocBatchEngine:
         # already did any building with the lock free.
         from ..native.ingest_native import NativeIngestEncoder, loaded
 
+        t_received = self.op_clock.now()
         h = self.hosts[doc_idx]
         if self._in_lane(doc_idx) or not loaded():
             # Lanes, checkpoint-restored docs, and the no-native fallback
@@ -1066,10 +1061,9 @@ class DocBatchEngine:
             # loop.
             h.queue.extend_block(ops, payloads)
         if len(ops):
-            # One latency sample per chunk (the C++ decode exposes no wire
-            # timestamps): stamp 0.0 = receipt time, so the sample covers
-            # staging -> device apply, not the sequencer hop.
-            self._lat_sample(doc_idx, 0.0, force=True)
+            # The feed is the sample: its oldest line's sequencer stamp,
+            # weighted by the rows it staged.
+            self.op_clock.feed_lines(data, t_received, len(ops), doc_idx)
         if h.queue:
             self._busy.add(doc_idx)
         h.min_seq = max(h.min_seq, h.native.min_seq)
@@ -1206,47 +1200,16 @@ class DocBatchEngine:
         return h.prop_slot[prop]
 
     # ------------------------------------------------------------- op latency
-    def _lat_sample(self, doc_idx: int, stamp: float, force: bool = False) -> None:
-        """Maybe sample one staged op's e2e latency: record its sequencer
-        stamp time (wall clock; 0.0 = unstamped synthetic streams, which
-        fall back to receipt time) to resolve at the next step() sync
-        boundary.  Gated to every ``latency_sample_every``-th staged op so
-        the per-message feed cost stays one int increment."""
-        self._lat_tick += 1
-        if not force and self._lat_tick % self.latency_sample_every:
-            return
-        if len(self._lat_pending) < 4096:  # bound a step-starved feed
-            self._lat_pending.append(
-                (stamp if stamp > 0 else time.time(), doc_idx)
-            )
-
-    def _lat_flush(self) -> None:
-        """Resolve pending latency samples at the applied-on-device
-        boundary (end of step(), after the error-latch readback proved the
-        dispatches retired) into the fleet and per-shard histograms."""
-        if not self._lat_pending:
-            return
-        now = time.time()
-        for stamp, d in self._lat_pending:
-            lat = max(0.0, now - stamp)
-            self.op_latency.record(lat)
-            if 0 <= d < self.n_docs:
-                self._shard_latency[self.shard_of(d)].record(lat)
-        self._lat_pending.clear()
-
     def latency_histograms(self) -> dict[str, Histogram]:
-        """Mergeable op-latency histograms for the metrics plane: the
-        fleet aggregate, one per mesh shard, and the per-incident
+        """Mergeable latency histograms for the metrics plane: the op
+        clock's (``op_latency`` = sequencer stamp -> applied on the device,
+        its three stages, one per mesh shard) and the per-incident
         recovery-time histogram (kill/restore -> first post-restore op
         applied)."""
-        out = {
-            "op_latency": self.op_latency,
+        return {
+            **self.op_clock.histograms(),
             "recovery_time": self.recovery_tracker.histogram,
         }
-        if self.n_shards > 1:
-            for s, h in enumerate(self._shard_latency):
-                out[f"op_latency_shard{s}"] = h
-        return out
 
     def flush_telemetry(self) -> None:
         """Drain residual sampled-telemetry buckets (status snapshot /
@@ -1519,10 +1482,11 @@ class DocBatchEngine:
         if self.compact_due:
             self._compact_due_docs()
         # Sync boundary housekeeping (host-side, O(programs + samples)):
-        # resolve e2e latency samples, poll for mid-serve recompiles, and
-        # feed the sampled step timing when a telemetry sink is attached.
+        # resolve the op clock's pending feeds, poll for mid-serve
+        # recompiles, and feed the sampled step timing when a telemetry
+        # sink is attached.
         with span("housekeeping"):
-            self._lat_flush()
+            self.op_clock.resolve()
             self.recompile_watchdog.poll()
             if self.sampled is not None:
                 self.sampled.record(time.perf_counter() - t0, "step")
@@ -3143,30 +3107,13 @@ class DocBatchEngine:
             )
         # Observability surface: program cache misses (recompiles, warmup
         # included), growth after first specialization (despecializations,
-        # the mid-serve alarm), and sampled op e2e latency (sequencer
-        # stamp -> applied-on-device), ms percentiles.
+        # the mid-serve alarm), and op e2e latency (the op clock's
+        # sequencer stamp -> applied-on-device), ms percentiles.
         self.counters.gauge("recompiles", self.recompile_watchdog.recompiles)
         self.counters.gauge(
             "despecializations", self.recompile_watchdog.despecializations
         )
-        self.counters.gauge("latency_samples", self.op_latency.count)
-        if self.op_latency.count:
-            self.counters.gauge(
-                "latency_p50_ms",
-                round(self.op_latency.percentile(0.5) * 1e3, 3),
-            )
-            self.counters.gauge(
-                "latency_p99_ms",
-                round(self.op_latency.percentile(0.99) * 1e3, 3),
-            )
-        if self.n_shards > 1:
-            self.counters.gauge(
-                "shard_latency_p99_ms",
-                [
-                    round(h.percentile(0.99) * 1e3, 3) if h.count else 0.0
-                    for h in self._shard_latency
-                ],
-            )
+        self.op_clock.emit_gauges(self.counters)
         # Recovery surface: per-incident recovery percentiles plus how far
         # the durable checkpoints trail the live stream right now (the
         # bounded-staleness writer's target signal).

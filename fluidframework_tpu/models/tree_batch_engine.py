@@ -52,7 +52,7 @@ from ..dds.tree.mark_pool import MarkPool
 from ..dds.tree.mark_pool import pool_commit_from_json as _pool_commit_from_json
 from ..dds.tree.field_kinds import OptionalChange
 from ..dds.tree.forest import ROOT_FIELD, Forest, Node
-from ..observability.flight_recorder import RecompileWatchdog, instant, span
+from ..observability import OpClock, RecompileWatchdog, instant, span
 from ..ops import tree_kernel as tk
 from ..parallel import mesh as pm
 from . import placement
@@ -346,6 +346,9 @@ class TreeBatchEngine:
         # mesh multiple; padding/free rows are inert pristine protos).
         # ``_slot`` aliases the plane's live array for hot-path packing.
         self.n_shards = mesh.devices.size if mesh is not None else 1
+        # The op's own clock, as the string engine keeps it (one mechanism
+        # for both families: observability/op_clock.py).
+        self.op_clock = OpClock(self.n_shards, self.shard_of)
         self.placement_plane = placement.PlacementPlane(
             n_docs, self.n_shards, spare_slots
         )
@@ -451,8 +454,14 @@ class TreeBatchEngine:
         if msg.type != MessageType.OP:
             return
         with self.ckpt_lock:
-            for edit in self._unwrap(msg.contents):
-                self._ingest_edit(doc_idx, msg, edit)
+            # The per-message path feeds the op clock one row a message
+            # (``ingest_lines`` feeds it once per feed instead).
+            self.op_clock.feed(msg.timestamp, self.op_clock.now(), 1, doc_idx)
+            self._ingest_op(doc_idx, msg)
+
+    def _ingest_op(self, doc_idx: int, msg: SequencedMessage) -> None:
+        for edit in self._unwrap(msg.contents):
+            self._ingest_edit(doc_idx, msg, edit)
 
     def ingest_batch(self, doc_idxs, msgs) -> None:
         """Batch-delivery seam (BroadcasterLambda.subscribe_batch / the
@@ -475,8 +484,12 @@ class TreeBatchEngine:
         raises through the Python decode (which owns error semantics) —
         per-document isolation, other docs' feeds are untouched.  Returns
         op rows staged (applied edits for fallback-routed docs)."""
+        t_received = self.op_clock.now()
         with self.ckpt_lock, span("ingest", doc=doc_idx, bytes=len(data)):
-            return self._ingest_lines(doc_idx, data)
+            rows = self._ingest_lines(doc_idx, data)
+            # The feed is the op clock's sample, on either decode.
+            self.op_clock.feed_lines(data, t_received, rows, doc_idx)
+            return rows
 
     def _ingest_lines(self, doc_idx: int, data: bytes) -> int:
         h = self.hosts[doc_idx]
@@ -501,9 +514,9 @@ class TreeBatchEngine:
             for raw in data.split(b"\n"):
                 line = raw.strip()
                 if line:
-                    self.ingest(
-                        doc_idx, SequencedMessage.from_json(line.decode())
-                    )
+                    msg = SequencedMessage.from_json(line.decode())
+                    if msg.type == MessageType.OP:
+                        self._ingest_op(doc_idx, msg)
         if doc_idx in self.fallbacks:
             return h.total_commits - commits_before
         return len(h.queue) - rows_before
@@ -995,6 +1008,9 @@ class TreeBatchEngine:
         with self.ckpt_lock:
             had_work = bool(self._busy)
             steps = self._step_fleet()
+            # The sync boundary: the error readback has proved the
+            # dispatches retired, so every feed staged so far is applied.
+            self.op_clock.resolve()
             if had_work and self.recovery_tracker.active:
                 self.recovery_tracker.complete()
         # Cadence checkpoints after the serving lock releases (same
@@ -1335,6 +1351,14 @@ class TreeBatchEngine:
         return warmed
 
     # ----------------------------------------------------------------- health
+    def latency_histograms(self) -> dict:
+        """Mergeable latency histograms for the metrics plane (API parity
+        with ``DocBatchEngine``): the op clock's and the recovery clock's."""
+        return {
+            **self.op_clock.histograms(),
+            "recovery_time": self.recovery_tracker.histogram,
+        }
+
     def health(self) -> dict:
         self.counters.gauge("megastep_k", self.megastep_k)
         self.counters.gauge(
@@ -1392,8 +1416,10 @@ class TreeBatchEngine:
                 if q:
                     depth[self.shard_of(d)] += q
             self.counters.gauge("shard_queue_depth", depth)
-        # Recovery surface (same shape as the string engine): incident
+        # Op latency (the op clock's sequencer stamp -> applied), then the
+        # recovery surface (same shape as the string engine): incident
         # percentiles + current checkpoint staleness.
+        self.op_clock.emit_gauges(self.counters)
         self.recovery_tracker.emit_gauges(self.counters)
         now = time.monotonic()
         self.counters.gauge(

@@ -32,12 +32,14 @@ import http.client
 import json
 import selectors
 import socket
+import time
 
 from ..fanout.plane import RESYNC_BOOT_MARKER
 from ..models.doc_batch_engine import DocBatchEngine
 from ..observability.flight_recorder import span
 
 _BOOT_MARKER = RESYNC_BOOT_MARKER.rstrip(b"\n")
+APPLIED_CAPACITY = 512  # step stamps kept between two status lines
 
 
 class FleetConsumer:
@@ -107,6 +109,19 @@ class FleetConsumer:
         self.pump_resumes = 0
         # Sockets the last pump's select found ready (0: it read nothing).
         self.last_ready = 0
+        # The step stamps (D13): ``[t_seen, t_applied, rows_staged]`` per
+        # step that advanced ``rows_staged``, on the flight recorder's clock
+        # (``perf_counter``).  ``t_seen``: when this iteration's ``select``
+        # reported work, or the iteration's start for a step on paused
+        # partitions or an ack alone; ``t_applied``: ``eng.step()`` has
+        # returned, everything staged is applied and the error latch read
+        # back.  A status line takes them (``take_applied``); past
+        # ``APPLIED_CAPACITY`` a stamp is counted in ``applied_dropped``.
+        self.applied: list[list] = []
+        self.applied_dropped = 0
+        self._applied_rows = 0
+        self._t_iter = time.perf_counter()
+        self._t_seen: float | None = None
         self._sel = selectors.DefaultSelector()  # epoll: no FD_SETSIZE cap
         try:
             for doc_id in doc_ids:
@@ -185,6 +200,7 @@ class FleetConsumer:
         caller), and a pump that found nothing records no span at all, so
         an idle fleet leaves the flight recorder's ring alone."""
         self.last_ready = 0
+        self._t_iter, self._t_seen = time.perf_counter(), None
         if len(self.dead_socks) == len(self._socks):
             return 0
         if idle is None:
@@ -207,6 +223,8 @@ class FleetConsumer:
     def _drain_ready(self, ready, sp) -> int:
         """``pump``'s work once ``select`` has returned: read, peel and
         ingest every ready socket; labels the pump's span ``sp``."""
+        if ready:
+            self._t_seen = time.perf_counter()
         staged = 0
         acked: list[int] = []
         bytes_before = self.bytes_consumed
@@ -274,6 +292,11 @@ class FleetConsumer:
             self.engine.counters.bump("acks_seen", len(acked))
         sp.set(ready=len(ready), bytes=self.bytes_consumed - bytes_before,
                staged=staged)
+        # How old the oldest sequencer stamp this pump read was when its
+        # feed arrived: "the bytes came late" or "the pump came late".
+        age = self.engine.op_clock.take_wire_age()
+        if age is not None:
+            sp.set(wire_age_ms=round(age * 1e3, 3))
         return staged
 
     def _handle_boot_marker(self, idx: int, feed: bytes) -> int:
@@ -384,9 +407,28 @@ class FleetConsumer:
         with span("step", docs=len(eng._busy)) as sp:
             dispatches = eng.counters.get("megastep_dispatches")
             slices = eng.step()
+            self._stamp_applied()
             sp.set(slices=slices, dispatches=eng.counters.get(
                 "megastep_dispatches") - dispatches)
         return slices
+
+    def _stamp_applied(self) -> None:
+        """``eng.step()`` has just returned: stamp the step if it advanced
+        ``rows_staged``."""
+        now = time.perf_counter()
+        if self.rows_staged <= self._applied_rows:
+            return
+        self._applied_rows = self.rows_staged
+        if len(self.applied) >= APPLIED_CAPACITY:
+            self.applied_dropped += 1
+            return
+        seen = self._t_seen if self._t_seen is not None else self._t_iter
+        self.applied.append([seen, now, self.rows_staged])
+
+    def take_applied(self) -> list[list]:
+        """The stamps taken since the last call (a status line's)."""
+        out, self.applied = self.applied, []
+        return out
 
     def health(self) -> dict:
         """Engine health counters + this consumer's transport state."""

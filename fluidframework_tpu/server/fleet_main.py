@@ -94,6 +94,11 @@ def status_snapshot(eng, doc_ids, rows=0, bytes_consumed=0, **extra) -> dict:
         "health": health,
         **extra,
     }
+    if getattr(eng, "op_clock", None) is not None:
+        # The op's own clock, cumulative since start: sequencer stamp ->
+        # received -> applied, three lossless histograms and four counters
+        # (observability/op_clock.py); readers take window deltas.
+        out["op_clock"] = eng.op_clock.status()
     if health.get("overload"):
         # Sustained-overload visibility at the top of the status line (the
         # supervisor's graceful-degradation signal, next to error state).
@@ -503,6 +508,11 @@ def main(argv: list[str] | None = None) -> int:
                 paused_docs=len(fc.paused_socks),
                 pump_pauses=fc.pump_pauses,
                 pump_resumes=fc.pump_resumes,
+                # The consumer's own step stamps since the line before
+                # (``[t_seen, t_applied, rows]`` each; ``lag.stamps_of``
+                # reads them), and how many its log could not keep.
+                applied=fc.take_applied(),
+                applied_dropped=fc.applied_dropped,
                 **extra,
             )
             with span("status.emit"):

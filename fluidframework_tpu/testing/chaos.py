@@ -573,7 +573,6 @@ class ChaosStack:
             ops_per_step=ops_per_step, megastep_k=megastep_k, use_mesh=False,
             checkpoint_store=self.checkpoint_store,
             checkpoint_every=checkpoint_every, doc_keys=list(doc_ids),
-            latency_sample_every=4,
         )
         self.engine = None
         self.consumer = None

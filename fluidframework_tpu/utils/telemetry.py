@@ -267,10 +267,12 @@ class Histogram:
         self.max = -math.inf
         self._buckets: dict[int, int] = {}
 
-    def record(self, value: float) -> None:
+    def record(self, value: float, n: int = 1) -> None:
+        """Record ``value`` ``n`` times (a sample that stands for ``n``
+        ops: the mean stays the ops' mean)."""
         v = float(value)
-        self.count += 1
-        self.sum += v
+        self.count += n
+        self.sum += v * n
         if v < self.min:
             self.min = v
         if v > self.max:
@@ -280,7 +282,7 @@ class Histogram:
         i = 0 if v <= self.base else math.ceil(
             math.log(v / self.base) / self._lg - 1e-12
         )
-        self._buckets[i] = self._buckets.get(i, 0) + 1
+        self._buckets[i] = self._buckets.get(i, 0) + n
 
     def merge(self, other: "Histogram") -> "Histogram":
         """Fold ``other`` into this histogram (same layout required)."""

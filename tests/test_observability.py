@@ -10,6 +10,7 @@ readback spans with consistent nesting plus a scrapeable metrics surface.
 from __future__ import annotations
 
 import json
+import time
 import urllib.error
 import urllib.request
 
@@ -410,6 +411,7 @@ def _feed_engine(eng, n_docs: int, rounds: int, seq0: int = 0) -> int:
                 seq=seq, min_seq=0, ref_seq=seq - 1, client_id="w0",
                 client_seq=seq, type=MessageType.OP,
                 contents={"type": 0, "pos1": 0, "seg": "ab"},
+                timestamp=time.time(),  # as the sequencer stamps it
             ))
         eng.ingest_batch(idxs, msgs)
         eng.step()
@@ -421,21 +423,27 @@ class TestEngineObservability:
         eng = DocBatchEngine(
             2, max_segments=64, text_capacity=512, max_insert_len=8,
             ops_per_step=4, use_mesh=False, recovery="off",
-            latency_sample_every=1,
         )
         _feed_engine(eng, n_docs=2, rounds=4)
         h = eng.health()
+        # Every message is a sample now (one row each on this path): the
+        # constructor's sampling option went with the second sampler.
         assert h["latency_samples"] == 8
         assert h["latency_p99_ms"] >= h["latency_p50_ms"] >= 0
         hists = eng.latency_histograms()
         assert hists["op_latency"].count == 8
+        # ``op_latency`` IS the op clock's sequenced -> applied, and the
+        # stages ride beside it.
+        assert hists["op_latency"] is eng.op_clock.sequenced_to_applied
+        assert {"sequenced_to_received", "received_to_applied",
+                "sequenced_to_applied", "recovery_time"} <= set(hists)
+        assert eng.op_clock.rows == 8 and eng.op_clock.unstamped_rows == 0
 
     def test_engine_spans_and_metrics_text(self):
         rec = install(FlightRecorder())
         eng = DocBatchEngine(
             2, max_segments=64, text_capacity=512, max_insert_len=8,
             ops_per_step=4, use_mesh=False, recovery="grow",
-            latency_sample_every=1,
         )
         _feed_engine(eng, n_docs=2, rounds=2)
         names = {e.name for e in rec.events()}
@@ -449,6 +457,9 @@ class TestEngineObservability:
         assert parsed[
             ("fftpu_latency_op_latency", (("quantile", "0.99"),))
         ] > 0
+        for stage in ("sequenced_to_received", "received_to_applied",
+                      "sequenced_to_applied"):
+            assert parsed[(f"fftpu_latency_{stage}_count", ())] == 4.0
 
 
 # ---------------------------------------------------------------------------
@@ -525,7 +536,6 @@ class TestFleetE2E:
             eng = DocBatchEngine(
                 1, max_segments=128, text_capacity=1024, max_insert_len=8,
                 ops_per_step=8, use_mesh=False, recovery="grow",
-                latency_sample_every=1,
             )
             fc = FleetConsumer("127.0.0.1", srv.port, eng, ["d0"])
             try:
@@ -542,8 +552,10 @@ class TestFleetE2E:
         # -> megastep dispatch -> error-latch readback.
         assert {"ingest", "upload", "dispatch", "readback"} <= names, names
         _assert_consistent_nesting(events)
-        # Sampled e2e latency resolved through the same run.
-        assert eng.op_latency.count > 0
+        # Op latency resolved through the same run, from the sequencer's
+        # wire stamps: every row the native path staged is on the clock.
+        assert eng.op_clock.sequenced_to_applied.count == rows
+        assert eng.op_clock.unstamped_rows == 0
         assert eng.health()["latency_p99_ms"] > 0
         # The trace is Perfetto-loadable JSON.
         path = str(tmp_path / "fleet.json")

@@ -73,9 +73,11 @@ def _edit(server, writers, doc_ids, text: str) -> int:
 class _Fleet:
     """``fleet_main`` as its own process, with its JSON lines."""
 
+    LAUNCH = ("-m", "fluidframework_tpu.server.fleet_main")
+
     def __init__(self, server, doc_ids, trace_path, extra=()):
         self.proc = subprocess.Popen(
-            [sys.executable, "-m", "fluidframework_tpu.server.fleet_main",
+            [sys.executable, *self.LAUNCH,
              "--port", str(server.port), "--docs", ",".join(doc_ids),
              "--capacity", "64", "--text-capacity", "512",
              "--ops-per-step", "4", "--megastep-k", "1",
