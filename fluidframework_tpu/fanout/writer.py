@@ -14,6 +14,23 @@ bytes) and are always finished before the next claim — a resync can
 therefore never split a claimed frame.  When a claim reports the peer is
 behind, the writer invokes the plane's resync (which takes the service
 lock; the writer holds no plane lock at that point — lock order preserved).
+
+Wake protocol.  Publishers put peers into ``_pending`` and send one byte
+down the wake channel when the set grew (one byte per scatter, however
+many peers).  The invariant the loop keeps: *a peer in ``_pending``
+implies an unread byte in the wake channel or a pass that has not swapped
+yet*.  A pass therefore drains the channel FIRST and swaps ``_pending``
+out AFTER: a wake that lands before the swap is taken by this pass, one
+that lands after it leaves its byte unread and the next ``select`` returns
+at once.  (Swap first and drain after, and the drain eats the byte of a
+peer that sits in the new ``_pending``: the thread then sleeps on work it
+was told about until some other publish, or the 1 s safety net, wakes it.)
+
+``stats()`` says whether a wake ever waits: ``passes`` counts the loop
+iterations that took a non-empty ``_pending``, ``wake_to_pass_ms_sum`` /
+``wake_to_pass_ms_max`` the wait from the moment ``_pending`` turned
+non-empty to the swap that took it (a GIL switch or two when sound; the
+publishers' period when wakes are being lost).
 """
 
 from __future__ import annotations
@@ -22,6 +39,7 @@ import contextlib
 import selectors
 import socket
 import threading
+import time
 
 from ..observability import instant
 
@@ -52,6 +70,10 @@ class FanoutWriter:
         self.send_bytes = 0
         self.partial_sends = 0
         self.dead_peers = 0
+        self.passes = 0                 # passes that took a non-empty _pending
+        self.wake_to_pass_ms_sum = 0.0  # _pending non-empty -> swapped out
+        self.wake_to_pass_ms_max = 0.0
+        self._pending_since = 0.0       # perf_counter of the empty -> non-empty edge
         self._thread = threading.Thread(
             target=self._run, name="fanout-writer", daemon=True
         )
@@ -66,6 +88,8 @@ class FanoutWriter:
             before = len(self._pending)
             self._pending.update(p for p in peers if p.is_socket and not p.dead)
             changed = len(self._pending) != before
+            if changed and not before:
+                self._pending_since = time.perf_counter()
         if changed:
             with contextlib.suppress(BlockingIOError, OSError):
                 # A byte already in flight wakes the loop just the same.
@@ -100,6 +124,12 @@ class FanoutWriter:
     def _run(self) -> None:
         while True:
             ready = self._sel.select(timeout=1.0)
+            # Drain BEFORE the swap (module docstring): a byte read here
+            # belongs to a peer the swap below still takes.
+            if any(key.data is None for key, _ev in ready):
+                with contextlib.suppress(BlockingIOError, OSError):
+                    while self._wake_r.recv(4096):
+                        pass
             with self._lock:
                 if self._stopped:
                     return
@@ -107,17 +137,20 @@ class FanoutWriter:
                 self._pending = set()
                 forgotten = self._forgotten
                 self._forgotten = set()
+                if fresh:
+                    waited_ms = (time.perf_counter() - self._pending_since) * 1e3
+                    self.passes += 1
+                    self.wake_to_pass_ms_sum += waited_ms
+                    self.wake_to_pass_ms_max = max(
+                        self.wake_to_pass_ms_max, waited_ms
+                    )
             for peer in forgotten:
                 # selectors' unregister falls back to a map scan when the
                 # fd is already closed, so parked dead peers always leave.
                 self._deregister(peer)
                 fresh.discard(peer)
             for key, _ev in ready:
-                if key.data is None:  # wake channel
-                    with contextlib.suppress(BlockingIOError, OSError):
-                        while self._wake_r.recv(4096):
-                            pass
-                else:
+                if key.data is not None:  # a parked peer turned writable
                     fresh.add(key.data)
             for peer in fresh:
                 self._service_peer(peer)
@@ -208,4 +241,7 @@ class FanoutWriter:
                 "send_bytes": self.send_bytes,
                 "partial_sends": self.partial_sends,
                 "dead_peers": self.dead_peers,
+                "passes": self.passes,
+                "wake_to_pass_ms_sum": self.wake_to_pass_ms_sum,
+                "wake_to_pass_ms_max": self.wake_to_pass_ms_max,
             }
