@@ -1020,7 +1020,7 @@ class DocBatchEngine:
         # already did any building with the lock free.
         from ..native.ingest_native import NativeIngestEncoder, loaded
 
-        t_received = self.op_clock.now()
+        t_received = self.op_clock.received()
         h = self.hosts[doc_idx]
         if self._in_lane(doc_idx) or not loaded():
             # Lanes, checkpoint-restored docs, and the no-native fallback
@@ -1411,7 +1411,7 @@ class DocBatchEngine:
         self._count_row_slots(scanned, K)
         return K
 
-    def step(self) -> int:
+    def step(self, in_flight=None) -> int:
         """Run device dispatches until all staged ops are applied; returns
         the number of batched SLICES applied (a K-slice megastep counts K,
         so the return value is K-invariant).  Busy-doc cohorts far below
@@ -1423,6 +1423,14 @@ class DocBatchEngine:
         bits are recovered (grow-and-replay or oracle routing), so
         ``errors()`` is all-zero on return unless recovery is off.
 
+        ``in_flight``, if given, is called once, between the step's last
+        dispatch and the readback that waits for it, with the error latch
+        of that dispatch: host work the caller can do until the latch
+        ``is_ready()`` (the consumer reads its sockets there, asleep
+        between arrivals).  It must not touch the engine: every queue is
+        still empty when ``recover()`` runs.  Where nothing is read back
+        (recovery off) nothing waits, and it is not called.
+
         Holds ``ckpt_lock`` end to end (the background checkpoint writer
         can only sweep between steps), and is the recovery clock's
         completion point: the first step that applies staged work after a
@@ -1430,7 +1438,7 @@ class DocBatchEngine:
         applied)."""
         with self.ckpt_lock:
             had_work = self._has_staged_rows()
-            steps = self._step_fleet()
+            steps = self._step_fleet(in_flight)
             if had_work and self.recovery_tracker.active:
                 self.recovery_tracker.complete()
         # Cadence checkpoints run AFTER the serving lock releases: the
@@ -1453,7 +1461,13 @@ class DocBatchEngine:
             or any(ln.queue for ln in self.seg_lanes.values())
         )
 
-    def _step_fleet(self) -> int:
+    def _step_fleet(self, in_flight=None) -> int:
+        """One loop of the serving thread, in order (ROADMAP S2): dispatch
+        every slice the queues hold (nothing waits for the device), hand
+        ``in_flight`` the last dispatch's error latch (the consumer reads
+        its sockets until the device has caught up), then ``recover()``'s
+        readback of that latch, recover what latched, compact the acked
+        documents and resolve the op clock."""
         t0 = time.perf_counter() if self.sampled is not None else 0.0
         steps = 0
         while self._busy:
@@ -1469,6 +1483,11 @@ class DocBatchEngine:
         self._step_seg_lanes()
         self._step_count += 1
         if self.recovery != "off":
+            if in_flight is not None:
+                # Still the wait for the device, so still a ``readback``
+                # span: the readback below finds the latch ready.
+                with span("readback", kind="in_flight"):
+                    in_flight(self.state.error)
             self.recover()
             self._steps_since_watchdog += 1
             if (

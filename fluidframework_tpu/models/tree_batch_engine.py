@@ -484,7 +484,7 @@ class TreeBatchEngine:
         raises through the Python decode (which owns error semantics) —
         per-document isolation, other docs' feeds are untouched.  Returns
         op rows staged (applied edits for fallback-routed docs)."""
-        t_received = self.op_clock.now()
+        t_received = self.op_clock.received()
         with self.ckpt_lock, span("ingest", doc=doc_idx, bytes=len(data)):
             rows = self._ingest_lines(doc_idx, data)
             # The feed is the op clock's sample, on either decode.
@@ -1000,14 +1000,18 @@ class TreeBatchEngine:
             self.step()
         self._compact_fleet()
 
-    def step(self) -> int:
+    def step(self, in_flight=None) -> int:
         """Apply everything staged as batched device megasteps.  Holds
         ``ckpt_lock`` end to end (the background checkpoint writer only
         sweeps between steps) and closes any open recovery incident once
-        staged work actually applied (kill -> first post-restore op)."""
+        staged work actually applied (kill -> first post-restore op).
+        ``in_flight``, if given, is called once between the last dispatch
+        and the readback that waits for it, with that dispatch's error
+        latch (``DocBatchEngine.step``'s seam: the consumer reads its
+        sockets there)."""
         with self.ckpt_lock:
             had_work = bool(self._busy)
-            steps = self._step_fleet()
+            steps = self._step_fleet(in_flight)
             # The sync boundary: the error readback has proved the
             # dispatches retired, so every feed staged so far is applied.
             self.op_clock.resolve()
@@ -1020,7 +1024,7 @@ class TreeBatchEngine:
             self.maybe_checkpoint()
         return steps
 
-    def _step_fleet(self) -> int:
+    def _step_fleet(self, in_flight=None) -> int:
         steps = 0
         while self._busy:
             # Proactive compact: dead rows accumulate monotonically (stable
@@ -1066,6 +1070,9 @@ class TreeBatchEngine:
             self.counters.bump("megastep_slices", K)
         with span("housekeeping"):
             self.recompile_watchdog.poll()
+        if in_flight is not None:
+            with span("readback", kind="in_flight"):
+                in_flight(self.state.error)
         if self.mesh is not None:
             # Per-shard latch reduce: one scalar readback instead of a
             # cross-mesh [D] error gather on every step.

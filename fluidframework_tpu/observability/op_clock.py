@@ -4,6 +4,10 @@ The sequencer stamps every message with ``time.time()`` when it orders it
 (``SequencedMessage.timestamp``), and the stamp rides every wire line.  The
 served path reads it once per FEED (one document's complete lines from one
 pump) with ``wire_stamp``: no JSON parse, the oldest line's top-level field.
+``received`` is the moment the feed's bytes were read from the socket: the
+moment it is staged, unless the reader says otherwise (the consumer reads
+ahead while a step is in flight and ingests a step later, inside
+``received_at``).
 ``OpClock`` keeps ``(t_sequenced, t_received, rows, doc)`` per feed until the
 engine's sync boundary, where every pending feed is resolved at one
 ``t_applied`` into three mergeable histograms whose samples are weighted by
@@ -24,6 +28,7 @@ feed's rows count in ``dropped_rows``.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Callable
 
@@ -82,6 +87,7 @@ class OpClock:
         # The oldest stamp fed since ``take_wire_age`` last asked, with the
         # moment its feed was received (the pump span's ``wire_age_ms``).
         self._oldest: tuple[float, float] | None = None
+        self._read_at: float | None = None  # inside ``received_at`` only
         self.offset = self._take_offset()
 
     def _take_offset(self) -> float:
@@ -97,6 +103,21 @@ class OpClock:
         return min(pairs)[1]
 
     # ----------------------------------------------------------------- feeds
+    @contextlib.contextmanager
+    def received_at(self, t_read: float):
+        """Whatever is fed inside the block was read at ``t_read`` (this
+        clock's axis), earlier than it is staged."""
+        self._read_at = t_read
+        try:
+            yield
+        finally:
+            self._read_at = None
+
+    def received(self) -> float:
+        """``received`` of the feed being staged: now, or inside
+        ``received_at`` what the reader said."""
+        return self.now() if self._read_at is None else self._read_at
+
     def feed(self, stamp: float, t_received: float, rows: int,
              doc: int = -1) -> None:
         """One feed that staged ``rows`` rows for ``doc``: ``stamp`` is its

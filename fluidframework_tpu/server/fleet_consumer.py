@@ -13,10 +13,24 @@ application runs on device in the batched engine step.
 
 With a megastep-enabled engine (``DocBatchEngine(megastep_k=K)``, the
 ``fleet_main --megastep-k`` flag) each ``step()`` fuses up to K staged op
-slices into one donated device dispatch, and the next ``pump()``'s staging
-overlaps the in-flight upload/dispatch — ``health()`` surfaces the realized
+slices into one donated device dispatch — ``health()`` surfaces the realized
 amortization as ``steps_per_dispatch`` / ``megastep_k`` /
 ``staging_overlap_packs`` alongside the transport counters.
+
+The serving thread reads the sockets while a step is in flight (ROADMAP S2):
+``step()`` hands the engine ``_read_ahead``, which the engine calls once,
+between the step's last dispatch and the readback of its error latch, with
+that latch.  Where the thread used to sleep in the readback it now sleeps in
+ONE ``select`` that wakes for bytes or for the device: a helper thread
+(``_DeviceWaker``) blocks in the latch's ``block_until_ready`` and writes a
+byte to a socket pair in the consumer's selector.  What the sockets deliver
+meanwhile is read and KEPT; the next ``pump()`` hands it to the engine first,
+in the order read.  Only bytes move early: nothing is staged, probed for an
+ack or counted in ``rows_staged`` before that pump, so a step's stamp still
+proves exactly the rows the step applied and an ack still compacts after the
+rows read before it.  ``health()`` counts how often it engages:
+``reads_in_flight`` and ``bytes_read_in_flight`` (engine health counters, so
+in every status line) beside ``bytes_consumed``.
 
 With a mesh-served engine (``fleet_main --mesh N``) the same dispatch is a
 ``shard_map`` program over an N-device docs mesh: staging packs by doc
@@ -30,8 +44,10 @@ from __future__ import annotations
 import contextlib
 import http.client
 import json
+import queue
 import selectors
 import socket
+import threading
 import time
 
 from ..fanout.plane import RESYNC_BOOT_MARKER
@@ -40,6 +56,37 @@ from ..observability.flight_recorder import span
 
 _BOOT_MARKER = RESYNC_BOOT_MARKER.rstrip(b"\n")
 APPLIED_CAPACITY = 512  # step stamps kept between two status lines
+_WAKE = -1  # the waker's key in the selector, where a socket's is its doc index
+
+
+class _DeviceWaker:
+    """Sleeps in ``block_until_ready`` so that the serving thread need not:
+    ``watch(latch)`` answers with one byte on ``sock`` when the device has
+    produced ``latch``, whatever became of the computation (an error is the
+    serving thread's own readback's to raise).  One thread for the
+    consumer's life, one byte per ``watch``."""
+
+    def __init__(self) -> None:
+        self.sock, self._tell = socket.socketpair()
+        self._latches: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="device-waker", daemon=True)
+        self._thread.start()
+
+    def watch(self, latch) -> None:
+        self._latches.put(latch)
+
+    def _run(self) -> None:
+        while (latch := self._latches.get()) is not None:
+            with contextlib.suppress(Exception):
+                latch.block_until_ready()
+            self._tell.send(b"\0")
+
+    def close(self) -> None:
+        self._latches.put(None)
+        self._thread.join(5)
+        self.sock.close()
+        self._tell.close()
 
 
 class FleetConsumer:
@@ -107,13 +154,29 @@ class FleetConsumer:
         self.paused_socks: set[int] = set()
         self.pump_pauses = 0
         self.pump_resumes = 0
-        # Sockets the last pump's select found ready (0: it read nothing).
+        # Sockets whose feeds the last pump handed on: those its select
+        # found ready and those read ahead of it (0: it had nothing).
         self.last_ready = 0
+        # Feeds ``_read_ahead`` read while a step was in flight, kept for
+        # the next pump: ``(doc index, complete lines, time read)``.  They
+        # are what arrived while the device ran ONE step (a compile happens
+        # inside the dispatch call, before the seam), from sockets that flow
+        # control has not parked; a fleet that exits holds them as it holds
+        # the bytes still in its sockets, unread and under no durable floor
+        # (that advances at ingest).
+        self._kept: list[tuple[int, bytes, float]] = []
+        self._waker: _DeviceWaker | None = None
+        # How often that engages, among the engine's health counters (so in
+        # every status line, from 0): wake-ups in the seam that found a
+        # socket ready, and the bytes of ``bytes_consumed`` read there.
+        engine.counters.bump("reads_in_flight", 0)
+        engine.counters.bump("bytes_read_in_flight", 0)
         # The step stamps (D13): ``[t_seen, t_applied, rows_staged]`` per
         # step that advanced ``rows_staged``, on the flight recorder's clock
-        # (``perf_counter``).  ``t_seen``: when this iteration's ``select``
-        # reported work, or the iteration's start for a step on paused
-        # partitions or an ack alone; ``t_applied``: ``eng.step()`` has
+        # (``perf_counter``).  ``t_seen``: when the oldest feed the step
+        # applied was read (this iteration's ``select`` reported work, or
+        # the seam of the step before), or the iteration's start for a step
+        # on paused partitions alone; ``t_applied``: ``eng.step()`` has
         # returned, everything staged is applied and the error latch read
         # back.  A status line takes them (``take_applied``); past
         # ``APPLIED_CAPACITY`` a stamp is counted in ``applied_dropped``.
@@ -124,6 +187,8 @@ class FleetConsumer:
         self._t_seen: float | None = None
         self._sel = selectors.DefaultSelector()  # epoll: no FD_SETSIZE cap
         try:
+            self._waker = _DeviceWaker()
+            self._sel.register(self._waker.sock, selectors.EVENT_READ, _WAKE)
             for doc_id in doc_ids:
                 s = self._subscribe(doc_id)
                 self._socks.append(s)  # tracked immediately: any later
@@ -187,21 +252,27 @@ class FleetConsumer:
 
     # ------------------------------------------------------------ data plane
     def pump(self, wait_s: float = 0.02, idle=None) -> int:
-        """Drain every READY socket once; returns op rows staged this pass.
+        """Hand the engine every feed read since the last pump; returns op
+        rows staged this pass.
 
-        One ``select`` readiness wait covers the whole socket set — an
-        idle socket costs nothing (the old per-socket recv-timeout walk
+        First the feeds ``_read_ahead`` kept while the last step was in
+        flight, in the order read, then every socket READY now, drained
+        once.  One ``select`` readiness wait covers the whole socket set —
+        an idle socket costs nothing (the old per-socket recv-timeout walk
         stalled the drain up to 50ms per quiet socket per pass, which was
-        most of the measured wire-ingest gap).
+        most of the measured wire-ingest gap); a pump that already holds
+        feeds does not wait at all.
 
         ``idle`` is the caller's open ``idle`` span (``fleet_main``), if it
         has one: the wait in ``select`` then belongs to that span, which
-        ends here the moment a socket is ready (``last_ready`` tells the
-        caller), and a pump that found nothing records no span at all, so
-        an idle fleet leaves the flight recorder's ring alone."""
+        ends here the moment there is a feed to hand on (``last_ready``
+        tells the caller), and a pump that found nothing records no span at
+        all, so an idle fleet leaves the flight recorder's ring alone."""
         self.last_ready = 0
         self._t_iter, self._t_seen = time.perf_counter(), None
-        if len(self.dead_socks) == len(self._socks):
+        if self._kept:
+            wait_s = 0
+        elif len(self.dead_socks) == len(self._socks):
             return 0
         if idle is None:
             with span("pump") as sp:
@@ -214,21 +285,43 @@ class FleetConsumer:
                 return self._drain_ready(ready, sp)
         self._apply_flow_control()
         ready = self._sel.select(wait_s)
-        if not ready:
+        if not ready and not self._kept:
             return 0
         idle.__exit__(None, None, None)
         with span("pump") as sp:
             return self._drain_ready(ready, sp)
 
     def _drain_ready(self, ready, sp) -> int:
-        """``pump``'s work once ``select`` has returned: read, peel and
-        ingest every ready socket; labels the pump's span ``sp``."""
-        if ready:
+        """``pump``'s work once ``select`` has returned: read every ready
+        socket, then ingest the kept feeds and this pump's own; labels the
+        pump's span ``sp``."""
+        kept, self._kept = self._kept, []
+        if kept:
+            # The oldest feed the next step applies was read in the seam.
+            self._t_seen = kept[0][2]
+            self.engine.counters.bump(
+                "bytes_read_in_flight", sum(len(f) for _i, f, _t in kept))
+        elif ready:
             self._t_seen = time.perf_counter()
-        staged = 0
-        acked: list[int] = []
+        self.last_ready = len(ready) + len(kept)
         bytes_before = self.bytes_consumed
-        self.last_ready = len(ready)
+        staged = self._ingest(kept + self._read(ready))
+        sp.set(ready=len(ready), bytes=self.bytes_consumed - bytes_before,
+               staged=staged)
+        # How old the oldest sequencer stamp this pump handed on was when
+        # its feed was read: "the bytes came late" or "the pump came late".
+        age = self.engine.op_clock.take_wire_age()
+        if age is not None:
+            sp.set(wire_age_ms=round(age * 1e3, 3))
+        return staged
+
+    def _read(self, ready) -> list[tuple[int, bytes, float]]:
+        """``recv`` every ready socket until it would block, join with the
+        socket's tail and cut at the last newline: ``(doc index, complete
+        lines, time read)`` per socket that completed a line.  Marks the
+        sockets the server closed; calls nothing of the engine's, so it may
+        run while a step is in flight."""
+        feeds = []
         for key, _events in ready:
             idx, sock = key.data, key.fileobj
             if idx in self.dead_socks:
@@ -253,7 +346,47 @@ class FleetConsumer:
             if cut < 0:
                 self._tails[idx] = buf
                 continue
-            feed, self._tails[idx] = buf[: cut + 1], buf[cut + 1 :]
+            self._tails[idx] = buf[cut + 1 :]
+            feeds.append((idx, buf[: cut + 1], time.perf_counter()))
+        return feeds
+
+    def _read_ahead(self, latch) -> None:
+        """The engine's seam (``engine.step(in_flight)``): the step's last
+        dispatch is in flight and ``latch`` is its error latch.  Until the
+        device has produced it, sleep in ``select`` and read what the
+        sockets deliver, keeping it for the next ``pump``; the waker's byte
+        ends the wait, and is always taken before this returns."""
+        if latch.is_ready():
+            return
+        self._waker.watch(latch)
+        woken = False
+        try:
+            while not woken:
+                ready = self._sel.select()
+                socks = [kv for kv in ready if kv[0].data != _WAKE]
+                woken = len(socks) < len(ready)
+                if socks:
+                    with span("pump.ahead", ready=len(socks)) as sp:
+                        feeds = self._read(socks)
+                        self._kept += feeds
+                        self.engine.counters.bump("reads_in_flight")
+                        sp.set(bytes=sum(len(f) for _i, f, _t in feeds))
+        finally:
+            self._waker.sock.recv(1)  # blocks only on the way out of a raise
+
+    def _ingest(self, feeds) -> int:
+        """Hand ``feeds`` (``_read``'s) to the engine in order: the ack
+        probe, the boot marker, ``ingest_lines``, then flow control and the
+        acked documents' compaction.  Returns the op rows staged."""
+        staged = 0
+        acked: list[int] = []
+        resynced: set[int] = set()
+        clock = self.engine.op_clock
+        for idx, feed, t_read in feeds:
+            if idx in resynced:
+                # Read from the socket a boot marker has since replaced:
+                # post-marker bytes, which the new subscription re-delivers.
+                continue
             self.bytes_consumed += len(feed)
             # Scribe-driven MSN: a summary ack in the feed is the zamboni
             # TRIGGER for THIS document (a feed is one document's socket;
@@ -267,14 +400,17 @@ class FleetConsumer:
             if n_acks:
                 acked.append(idx)
                 self.acks_by_doc[idx] += n_acks
-            if _BOOT_MARKER in feed:
-                # Fan-out plane drop-to-catch-up, boot flavor: the missed
-                # range left the retained log — snapshot-boot instead of
-                # consuming a gapped stream (one substring probe per
-                # chunk, same idiom as the summaryAck trigger).
-                staged += self._handle_boot_marker(idx, feed)
-                continue
-            staged += self.engine.ingest_lines(idx, feed)
+            # The op clock's ``received`` is when the bytes were read.
+            with clock.received_at(t_read):
+                if _BOOT_MARKER in feed:
+                    # Fan-out plane drop-to-catch-up, boot flavor: the missed
+                    # range left the retained log — snapshot-boot instead of
+                    # consuming a gapped stream (one substring probe per
+                    # chunk, same idiom as the summaryAck trigger).
+                    staged += self._handle_boot_marker(idx, feed)
+                    resynced.add(idx)
+                    continue
+                staged += self.engine.ingest_lines(idx, feed)
         self.rows_staged += staged
         if staged:
             # Pause any doc this pass pushed over its high watermark BEFORE
@@ -290,13 +426,6 @@ class FleetConsumer:
             self.acks_unstepped = True
             self.engine.counters.bump("msn_compactions")
             self.engine.counters.bump("acks_seen", len(acked))
-        sp.set(ready=len(ready), bytes=self.bytes_consumed - bytes_before,
-               staged=staged)
-        # How old the oldest sequencer stamp this pump read was when its
-        # feed arrived: "the bytes came late" or "the pump came late".
-        age = self.engine.op_clock.take_wire_age()
-        if age is not None:
-            sp.set(wire_age_ms=round(age * 1e3, 3))
         return staged
 
     def _handle_boot_marker(self, idx: int, feed: bytes) -> int:
@@ -404,9 +533,12 @@ class FleetConsumer:
         summary ack a pump handed over since the last step."""
         eng = self.engine
         self.acks_unstepped = False
+        # A consumer with a dead socket is about to checkpoint and leave to
+        # its supervisor: it reads nothing more that it would not apply.
+        ahead = None if self.dead_socks else self._read_ahead
         with span("step", docs=len(eng._busy)) as sp:
             dispatches = eng.counters.get("megastep_dispatches")
-            slices = eng.step()
+            slices = eng.step(ahead)
             self._stamp_applied()
             sp.set(slices=slices, dispatches=eng.counters.get(
                 "megastep_dispatches") - dispatches)
@@ -480,3 +612,6 @@ class FleetConsumer:
         self._socks = []
         with contextlib.suppress(OSError, AttributeError):
             self._sel.close()
+        if self._waker is not None:
+            self._waker.close()
+            self._waker = None

@@ -160,12 +160,13 @@ class _Loop:
             fleet_consumer.time, "perf_counter", lambda: self.now)
         engine = types.SimpleNamespace(
             n_docs=1, _busy=set(), step=self._engine_step,
-            counters=types.SimpleNamespace(get=lambda _name: 0),
+            counters=types.SimpleNamespace(
+                get=lambda _name: 0, bump=lambda _name, _by=1: 0),
             op_clock=OpClock())
         self.fc = fleet_consumer.FleetConsumer("127.0.0.1", 0, engine, [])
         self.step_s = 0.0
 
-    def _engine_step(self) -> int:
+    def _engine_step(self, in_flight=None) -> int:
         self.now += self.step_s
         return 1
 
