@@ -374,24 +374,6 @@ def _columns(s: DocState) -> _NewSeg:
     return _NewSeg(*(getattr(s, name) for name in _NewSeg._fields))
 
 
-@jax.named_scope("open_slot")
-def _open_slot(s: DocState, k, do: jnp.ndarray, new: _NewSeg) -> DocState:
-    """Conditionally (``do``) shift all per-segment arrays right at ``k`` and
-    write the new segment's values there.  Capacity overflow sets error."""
-    S = s.seg_len.shape[0]
-    overflow = do & (s.nseg >= S)
-    do = do & ~overflow
-    cols = jax.tree.map(
-        lambda arr, newval: jnp.where(do, _shift_right(arr, k, newval), arr),
-        _columns(s), new,
-    )
-    return s._replace(
-        **cols._asdict(),
-        nseg=s.nseg + do.astype(I32),
-        error=s.error | jnp.where(overflow, ERR_SEG_OVERFLOW, 0),
-    )
-
-
 def _geometry(s: DocState, ref_seq, client):
     """(vis, vlen, excl) of ``s`` from one perspective: the visibility mask,
     the visible lengths and their exclusive prefix sum."""
@@ -400,21 +382,36 @@ def _geometry(s: DocState, ref_seq, client):
     return vis, vlen, excl
 
 
+class _Cut(NamedTuple):
+    """One boundary cut of a row, as ``_plan_cuts`` plans it."""
+
+    k: jnp.ndarray        # index of the segment that holds the cut (see there)
+    do: jnp.ndarray       # the cut is made: left half trimmed, a uid spent
+    split: jnp.ndarray    # ... and there is room for the right half's slot
+    off: jnp.ndarray      # the left half's length
+    src_uid: jnp.ndarray  # the holder's uid
+    right: _NewSeg        # the right half; ``right.seg_uid`` is the uid spent
+
+
+class _Insert(NamedTuple):
+    """The segment an insert row adds, as ``_apply_row`` plans it."""
+
+    k: jnp.ndarray   # where it lands, in the index space cut 1 leaves
+    ok: jnp.ndarray  # the row is an insert that is applied
+    new: _NewSeg
+
+
 @jax.named_scope("ensure_boundary")
-def _ensure_boundaries(
-    s: DocState, geom, cut1, gate1, cut2, gate2
-) -> DocState:
-    """Under each cut's gate, split the segment that holds it strictly
-    inside, if any: cut 1, then cut 2 in the document cut 1 leaves.  ``geom``
-    is ``_geometry`` of ``s`` from the op's perspective.  Both cuts are
-    planned from ``geom`` and their (up to two) slots opened in ONE rewrite
-    of the per-segment columns.
+def _plan_cuts(s: DocState, geom, cut1, gate1, cut2, gate2) -> tuple[_Cut, _Cut]:
+    """Under each cut's gate, plan the split of the segment that holds it
+    strictly inside, if any: cut 1, then cut 2 in the document cut 1 leaves.
+    ``geom`` is ``_geometry`` of ``s`` from the op's perspective, and both
+    cuts are planned from it; ``_open_slots`` carries them out.  ``k`` of
+    cut 1 indexes ``s``, ``k`` of cut 2 the document with cut 1's slot open.
 
     Mirrors the reference's split-on-walk (ensureIntervalBoundary /
-    insertingWalk split path): after this, each cut falls on a segment
-    boundary of the perspective-visible sequence.  Obliterate anchors on a
-    split segment follow the half holding their endpoint char: Before sides
-    keep the left half's uid, After sides move to the right half.
+    insertingWalk split path): after the cuts, each falls on a segment
+    boundary of the perspective-visible sequence.
 
     A split that finds the document full (``nseg == S``) latches
     ERR_SEG_OVERFLOW and opens no slot, but its left half is trimmed, its
@@ -423,7 +420,6 @@ def _ensure_boundaries(
     """
     vis, vlen, excl = geom
     S = s.seg_len.shape[0]
-    idx = jnp.arange(S, dtype=I32)
     cols = _columns(s)
 
     def holder(pos):
@@ -471,50 +467,79 @@ def _ensure_boundaries(
     k2 = k2g + (split1 & (k2g > k1)).astype(I32) + in_right.astype(I32)
     split2 = do2 & (s.nseg + split1.astype(I32) < S)
     uid2 = uid1 + do1.astype(I32)
-    right2 = right_half(src2, off2, uid2)
+    return (
+        _Cut(k1, do1, split1, off1, src1.seg_uid, right1),
+        _Cut(k2, do2, split2, off2, src2.seg_uid, right_half(src2, off2, uid2)),
+    )
+
+
+@jax.named_scope("ensure_boundary")
+def _open_slots(s: DocState, c1: _Cut, c2: _Cut, ins: _Insert) -> DocState:
+    """Carry out a row's planned cuts and its insert in ONE rewrite of the
+    per-segment columns, which opens up to two slots: cut 1's right half,
+    and cut 2's right half or the inserted segment.  The kinds exclude one
+    another: a row that inserts (``ins.ok``) cuts at its position only, so
+    its second slot is free.  The state that comes out is that of cut 1,
+    then cut 2, then the insert, each a shift of its own, padding included.
+
+    Obliterate anchors on a split segment follow the half holding their
+    endpoint char: Before sides keep the left half's uid, After sides move
+    to the right half.  An insert that finds the document full latches
+    ERR_SEG_OVERFLOW and opens no slot, but spends its uid.
+    """
+    S = s.seg_len.shape[0]
+    idx = jnp.arange(S, dtype=I32)
+    k1, k2 = c1.k, c2.k
+    split1 = c1.split
+    lands = ins.ok & (s.nseg + split1.astype(I32) < S)
+    split2 = c2.split | lands
+    v2 = jax.tree.map(lambda n, r: jnp.where(ins.ok, n, r), ins.new, c2.right)
 
     with jax.named_scope("open_slot"):
-        # ``shift_right(shift_right(arr, k1 + 1, v1), k2 + 1, v2)``, each
-        # under its gate, element by element: slot 2 ends up at q2, slot 1 at
-        # q1 (one higher where slot 2 opened at or below it), and every other
+        # ``shift_right(shift_right(arr, k1 + 1, v1), q2, v2)``, each under
+        # its gate, element by element: slot 2 ends up at q2, slot 1 at q1
+        # (one higher where slot 2 opened at or below it), and every other
         # element comes from as many indices lower as slots opened below it.
-        q2 = k2 + 1
-        q1 = k1 + 1 + (split2 & (k1 >= k2)).astype(I32)
+        q2 = jnp.where(ins.ok, ins.k, k2 + 1)
+        q1 = k1 + 1 + (split2 & (k1 + 1 >= q2)).astype(I32)
         at1, past1 = split1 & (idx == q1), split1 & (idx > q1)
         at2, past2 = split2 & (idx == q2), split2 & (idx > q2)
         by1, by2 = past1 ^ past2, past1 & past2
 
         def rewrite(arr, v1, v2):
-            # Static shifts, as in ``_shift_right``.
+            # Static shifts: indexing with idx - 1 lowers to a gather per
+            # column, which the TPU runs far slower.
             prev1 = jnp.concatenate([arr[:1], arr[:-1]])
             prev2 = jnp.concatenate([arr[:2], arr[:-2]])
             moved = jnp.where(by2, prev2, jnp.where(by1, prev1, arr))
             return jnp.where(at2, v2, jnp.where(at1, v1, moved))
 
-        out = jax.tree.map(rewrite, cols, right1, right2)
+        out = jax.tree.map(rewrite, _columns(s), c1.right, v2)
         # Trim the left halves in the same write (masked, not ``.at[k].set``:
         # one element per document is a scatter).  Slot 2 may have opened
         # below cut 1's left half; cut 2's own trim comes last.
-        t1 = k1 + (split2 & (k1 > k2)).astype(I32)
+        t1 = k1 + (split2 & (k1 >= q2)).astype(I32)
         seg_len = jnp.where(
-            do2 & (idx == k2),
-            off2,
-            jnp.where(do1 & (idx == t1), off1, out.seg_len),
+            c2.do & (idx == k2),
+            c2.off,
+            jnp.where(c1.do & (idx == t1), c1.off, out.seg_len),
         )
 
     def anchored(uids, sides):
-        after = sides == SIDE_AFTER
-        uids = jnp.where(do1 & after & (uids == src1.seg_uid), uid1, uids)
-        return jnp.where(do2 & after & (uids == src2.seg_uid), uid2, uids)
+        for cut in (c1, c2):
+            moved = cut.do & (sides == SIDE_AFTER) & (uids == cut.src_uid)
+            uids = jnp.where(moved, cut.right.seg_uid, uids)
+        return uids
 
+    over = (c1.do & ~split1) | (c2.do & ~c2.split) | (ins.ok & ~lands)
     return s._replace(
         **out._replace(seg_len=seg_len)._asdict(),
         nseg=s.nseg + split1.astype(I32) + split2.astype(I32),
-        uid_next=uid2 + do2.astype(I32),
+        # Cut 2's uid follows cut 1's, and so does the inserted segment's.
+        uid_next=c2.right.seg_uid + (c2.do | ins.ok).astype(I32),
         ob_start_uid=anchored(s.ob_start_uid, s.ob_start_side),
         ob_end_uid=anchored(s.ob_end_uid, s.ob_end_side),
-        error=s.error
-        | jnp.where(lost1 | (do2 & ~split2), ERR_SEG_OVERFLOW, 0),
+        error=s.error | jnp.where(over, ERR_SEG_OVERFLOW, 0),
     )
 
 
@@ -546,16 +571,32 @@ def _ob_anchor_indices(s: DocState) -> tuple[jnp.ndarray, ...]:
     return s_idx, m_start.any(axis=1), e_idx, m_end.any(axis=1)
 
 
-def _obliterate_new_segment(s: DocState, k, key, client, ref_seq):
+def _obliterate_new_segment(s: DocState, c1: _Cut, k, key, client, ref_seq):
     """The insert-time obliterate rule (ref mergeTree.ts blockInsert
-    :1647-1745): decide whether the segment about to land at index ``k`` is
-    swallowed by concurrent obliterates, and with which remove stamps.
+    :1647-1745): decide whether the segment about to land at index ``k`` of
+    the document cut ``c1`` leaves of ``s`` is swallowed by concurrent
+    obliterates, and with which remove stamps.
 
     Returns (rem_keys, rem_clients, obpre, overflow): the new segment's
     remove slots (sorted ascending, NO_REMOVE padded), its
     obliteratePrecedingInsertion stamp key (-1 none), and whether the
     candidate stamps overflowed the R slots."""
-    return _obliterate_swallow(s, _ob_anchor_indices(s), k, key, client, ref_seq)
+    s_idx, s_found, e_idx, e_found = _ob_anchor_indices(s)
+
+    def after_cut(i, found, uids, sides):
+        # An After-side anchor on the split segment follows the right half
+        # (``_open_slots``), which exists only where its slot opened.  Every
+        # other anchor stays on its segment; one behind the holder lies one
+        # higher behind the slot, and at or past ``k`` (at most the right
+        # half's index) either way, so its index serves as it is.
+        moved = c1.do & (sides == SIDE_AFTER) & (uids == c1.src_uid)
+        return jnp.where(moved, c1.k + 1, i), jnp.where(moved, c1.split, found)
+
+    anchors = (
+        *after_cut(s_idx, s_found, s.ob_start_uid, s.ob_start_side),
+        *after_cut(e_idx, e_found, s.ob_end_uid, s.ob_end_side),
+    )
+    return _obliterate_swallow(s, anchors, k, key, client, ref_seq)
 
 
 def _obliterate_swallow(s: DocState, anchors, k, key, client, ref_seq):
@@ -853,11 +894,11 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
     every row and selects between whole ``DocState``s, text pool included.
     All kinds read the document from the same perspective (``ref_seq``,
     ``client`` of the row), so here the shared work runs once (one geometry,
-    from which both gated boundary splits are planned and their slots opened
-    in one rewrite of the segment columns, and one geometry after them) and
-    the kinds differ only in the masks their writes go under.  ``flag`` is
-    the Python bool of ``apply_op``: with False the obliterate parts trace to
-    nothing.
+    from which both gated boundary splits and the insert are planned and
+    their slots opened in ONE rewrite of the segment columns, and one
+    geometry after it) and the kinds differ only in the masks their writes
+    go under.  ``flag`` is the Python bool of ``apply_op``: with False the
+    obliterate parts trace to nothing.
     """
     kind, key, client, ref_seq = op[0], op[1], op[2], op[3]
     pos1, pos2, a, b = op[4], op[5], op[6], op[7]
@@ -873,9 +914,10 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
 
     with jax.named_scope(SHARED_SCOPE):
         geom = _geometry(s, ref_seq, client)
+        vis, vlen, excl = geom
         # A split moves no visible length: this total serves every range
         # check below.
-        total = jnp.sum(geom[1])
+        total = jnp.sum(vlen)
         # The row's two boundaries: insert (pos1, none), remove/annotate
         # (pos1, pos2), a valid obliterate its sided endpoints, else none.
         cut1, cut2 = pos1, pos2
@@ -890,21 +932,22 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
             cut1 = jnp.where(is_ob, start_pos, cut1)
             cut2 = jnp.where(is_ob, end_pos, cut2)
             do_cut1, do_cut2 = do_cut1 | ob_ok, do_cut2 | ob_ok
-        s = _ensure_boundaries(s, geom, cut1, do_cut1, cut2, do_cut2)
-        vis, vlen, excl = _geometry(s, ref_seq, client)
-        alive = _alive(s)
-        with jax.named_scope("mark_range"):
-            in_range = (
-                vis & (excl >= pos1) & (excl + vlen <= pos2) & (vlen > 0)
-            )
-        error = s.error | jnp.where(is_range & (pos2 > total), ERR_POS_RANGE, 0)
+        c1, c2 = _plan_cuts(s, geom, cut1, do_cut1, cut2, do_cut2)
 
     with jax.named_scope("insert"):
         text_len = a
         # Boundary walk: insert before the first segment at/after pos that
-        # is visible or wins the tie-break; else append at nseg.
-        stop = alive & (excl >= pos1) & ((vlen > 0) | _tiebreak(s, key))
+        # is visible or wins the tie-break; else append at nseg.  The walk is
+        # over the document cut 1 leaves, read off the geometry before it: a
+        # split puts its right half, which starts at pos1, behind the holder,
+        # and a cut that found no room leaves what follows the holder that
+        # half's length lower.
+        behind = jnp.arange(excl.shape[0], dtype=I32) > c1.k
+        lost = c1.do & ~c1.split
+        excl1 = excl - jnp.where(lost & behind, c1.right.seg_len, 0)
+        stop = _alive(s) & (excl1 >= pos1) & ((vlen > 0) | _tiebreak(s, key))
         k = _first_true(stop, s.nseg)
+        k = jnp.where(c1.split, jnp.minimum(k, c1.k + 1), k)
         # The payload goes into the text pool at ``text_end`` whenever it
         # fits, whether or not the position is in range.  Only this write
         # ever touches the pool (``_write_text``): a row that is no insert,
@@ -919,7 +962,7 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
         # The [OB,S] swallow analysis only traces when an obliterate can
         # exist (apply_ops hoists the runtime branch to whole-loop level).
         new_rem_k, new_rem_c, obpre, swallow_over = (
-            _obliterate_new_segment(s, k, key, client, ref_seq)
+            _obliterate_new_segment(s, c1, k, key, client, ref_seq)
             if flag
             else _no_obliterate_swallow(s)
         )
@@ -930,7 +973,7 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
             seg_len=text_len,
             ins_key=key,
             ins_client=client,
-            seg_uid=s.uid_next,
+            seg_uid=s.uid_next + c1.do.astype(I32),
             seg_obpre=obpre,
             rem_keys=new_rem_k,
             rem_clients=new_rem_c,
@@ -938,11 +981,26 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
             prop_vals=tuple(zero for _ in range(P)),
         )
         ok = fits & (pos1 <= total)
-        error = (
-            error
-            | jnp.where(text_over, ERR_TEXT_OVERFLOW, 0)
+        text_end = s.text_end + jnp.where(ok, text_len, 0)
+        insert_error = (
+            jnp.where(text_over, ERR_TEXT_OVERFLOW, 0)
             | jnp.where(is_insert & (pos1 > total), ERR_POS_RANGE, 0)
             | jnp.where(ok & swallow_over, ERR_REM_OVERFLOW, 0)
+        )
+
+    with jax.named_scope(SHARED_SCOPE):
+        s = _open_slots(s, c1, c2, _Insert(k, ok, new))
+        # On an insert row nothing below writes: every mask is off.
+        vis, vlen, excl = _geometry(s, ref_seq, client)
+        alive = _alive(s)
+        with jax.named_scope("mark_range"):
+            in_range = (
+                vis & (excl >= pos1) & (excl + vlen <= pos2) & (vlen > 0)
+            )
+        error = (
+            s.error
+            | insert_error
+            | jnp.where(is_range & (pos2 > total), ERR_POS_RANGE, 0)
         )
 
     stamp = in_range & is_remove
@@ -1002,14 +1060,7 @@ def _apply_row(s: DocState, op, payload, flag: bool, text_capacity: int):
         s = _annotate_marked(s, in_range & is_annotate, op)
     with jax.named_scope("ack"):
         s = _restamp_acked(s, op, is_ack)
-    with jax.named_scope("insert"):
-        # The kinds exclude one another: on an insert row nothing above has
-        # written, so ``k`` still indexes this state.
-        s = _open_slot(s._replace(error=error), k, ok, new)
-        return s._replace(
-            text_end=s.text_end + jnp.where(ok, text_len, 0),
-            uid_next=s.uid_next + ok.astype(I32),
-        ), write
+    return s._replace(error=error, text_end=text_end), write
 
 
 def row_count(ops: jnp.ndarray) -> jnp.ndarray:
@@ -1274,7 +1325,7 @@ def apply_cohort_megastep(
 #          insert index / anchor index / owner decision.
 #
 # Mutations are OWNER-LOCAL: exactly one shard owns the op's landing
-# segment, and the O(S_local) suffix shift of ``_open_slot`` runs under a
+# segment, and the O(S_local) suffix shift of ``_open_slot_seg`` runs under a
 # real ``lax.cond`` on that shard only — legal here because a segment lane
 # is a single-document program (no vmap to degrade the cond to a select).
 # Range ops (remove/annotate/obliterate) are purely-local mask updates once
@@ -1351,8 +1402,10 @@ def _seg_contains(vlen, q_local, strict: bool):
 
 
 def _open_slot_seg(s: DocState, k, do, new: _NewSeg, axis: str) -> DocState:
-    """Owner-local ``_open_slot``: ``do`` is a SHARD-LOCAL scalar (exactly
-    one shard owns the insert), so the O(S_local) suffix shift runs under a
+    """Open a slot at ``k`` on its owner: conditionally (``do``) shift all
+    per-segment arrays right at ``k`` and write the new segment's values
+    there.  ``do`` is a SHARD-LOCAL scalar (exactly one shard owns the
+    insert), so the O(S_local) suffix shift runs under a
     real branch on the owning shard only — the non-owners skip the heavy
     gather/select entirely.  Shard capacity overflow latches
     ERR_SEG_OVERFLOW globally (psum), exactly like the single-lane latch;
@@ -1390,8 +1443,9 @@ def _open_slot_seg(s: DocState, k, do, new: _NewSeg, axis: str) -> DocState:
 
 
 def _ensure_boundary_seg(s: DocState, pos, ref_seq, client, axis: str) -> DocState:
-    """One cut of ``_ensure_boundaries``, distributed: the containing segment
-    (if any) is strictly inside exactly one shard; that shard splits locally.
+    """One cut of ``_plan_cuts`` / ``_open_slots``, distributed: the
+    containing segment (if any) is strictly inside exactly one shard; that
+    shard splits locally.
     The split uid allocation and obliterate anchor side-moves replay
     identically on every shard from the replicated uid_next / ob table plus
     one psum broadcast of the split segment's old uid."""

@@ -13,7 +13,7 @@ padding slots hold shift remnants and are held by no independent reference;
 (c) compares them for the cuts.)
 
 (b) The jaxpr of the fleet's row loop keeps the shape the body was written
-for: at most two cumulative sums and two rewrites of each per-segment column
+for: at most two cumulative sums and ONE rewrite of each per-segment column
 a row, no select over the text pool (which the loop does not even carry: one
 strip a document, written after the loop and after the obliterate gate, is
 the pool's only write, and no scatter touches it), no ``cond``/``switch`` on
@@ -21,9 +21,16 @@ a batched predicate.  The strip write itself (``mk._write_text``) is held to
 the rows' writes applied one after another.
 
 (c) A row's two boundary cuts, planned from one geometry and opened in one
-pass (``mk._ensure_boundaries``), against the one-cut split applied twice,
-each time on a geometry of its own, which is what the body did before: the
-reference is kept here.
+pass (``mk._plan_cuts``, ``mk._open_slots``), against the one-cut split
+applied twice, each time on a geometry of its own, which is what the body did
+before: the reference is kept here.
+
+(d) The row whose insert rides the second slot of that pass, against the row
+as it ran before: the cuts, then a geometry of the document they leave, the
+boundary walk and the obliterate rule on it, and a second shift of every
+column for the insert's slot (``_reference_row``, kept here with its
+``_open_slot``).  Every leaf after every row, ``error`` and the padding
+included.
 """
 
 import functools
@@ -390,10 +397,10 @@ def test_vmapped_scan_body_structure(guard, flag):
         assert names.count("cumsum") <= 2, names.count("cumsum")
         assert names.count("cumsum") >= 1
     elif guard == "slot_passes":
-        # Every per-segment column is rewritten twice a row: once for both
-        # boundary cuts, once for the insert.  (The ``concatenate``s do not
-        # tell: a two-slot pass has two a column.)
-        assert _shifted_versions(scan, n_docs) == [2] * (6 + 2 * R + 2 * P)
+        # Every per-segment column is rewritten once a row, for both
+        # boundary cuts and the insert.  (The ``concatenate``s do not tell:
+        # a two-slot pass has two a column.)
+        assert _shifted_versions(scan, n_docs) == [1] * (6 + 2 * R + 2 * P)
     elif guard == "text_pool":
         pool = (n_docs, T)
 
@@ -425,6 +432,21 @@ REF_SEQ, CLIENT = 10, 1
 INT_MIN, INT_MAX = -2**31, 2**31 - 1
 
 
+def _open_slot(s, k, do, new):
+    """Conditionally (``do``) shift all per-segment arrays right at ``k`` and
+    write the new segment's values there: the pass of its own that a cut
+    took until (c), and an insert until (d).  Capacity overflow sets error."""
+    overflow = do & (s.nseg >= s.seg_len.shape[0])
+    do = do & ~overflow
+    cols = jax.tree.map(
+        lambda arr, newval: jnp.where(do, mk._shift_right(arr, k, newval), arr),
+        mk._columns(s), new)
+    return s._replace(
+        **cols._asdict(),
+        nseg=s.nseg + do.astype(jnp.int32),
+        error=s.error | jnp.where(overflow, mk.ERR_SEG_OVERFLOW, 0))
+
+
 def _one_cut_reference(s, geom, pos, gate):
     """``_ensure_boundary`` as the row body ran it until the two cuts were
     planned together (one cut; the caller takes a geometry before each)."""
@@ -447,7 +469,7 @@ def _one_cut_reference(s, geom, pos, gate):
         prop_keys=tuple(a[k] for a in s.prop_keys),
         prop_vals=tuple(a[k] for a in s.prop_vals),
     )
-    s2 = mk._open_slot(s, k + 1, do, right)
+    s2 = _open_slot(s, k + 1, do, right)
     at_k = jnp.arange(s2.seg_len.shape[0], dtype=jnp.int32) == k
     moved_start = (do & (s2.ob_start_uid == old_uid)
                    & (s2.ob_start_side == mk.SIDE_AFTER))
@@ -468,8 +490,13 @@ def _cuts_in_turn(s, cut1, gate1, cut2, gate2):
 
 
 def _cuts_planned(s, cut1, gate1, cut2, gate2):
-    return mk._ensure_boundaries(
+    cuts = mk._plan_cuts(
         s, mk._geometry(s, REF_SEQ, CLIENT), cut1, gate1, cut2, gate2)
+    # No insert: whatever segment it names stays out.
+    no_insert = mk._Insert(
+        jnp.int32(3), jnp.bool_(False),
+        jax.tree.map(lambda arr: arr[0] + 7, mk._columns(s)))
+    return mk._open_slots(s, *cuts, no_insert)
 
 
 _CUT_PROGRAMS = {
@@ -582,7 +609,7 @@ def _run_cuts(batching, docs, cuts):
     if batching == "alone":
         (doc,), args = docs, [a[0] for a in args]
     else:
-        doc = jax.tree.map(lambda *xs: jnp.stack(xs), *docs)
+        doc = _stack(docs)
     return planned(doc, *args), in_turn(doc, *args)
 
 
@@ -651,6 +678,545 @@ def test_two_cuts_random(seed):
     got, want = _run_cuts("vmap", docs, cuts)
     _assert_states_equal(got, want, seed)
     assert len({int(n) for n in np.asarray(want.nseg)}) > 3
+
+
+# ------------------------------- the insert in the cuts' rewrite of a row
+def _reference_row(s, op, payload, flag, text_capacity):
+    """``mk._apply_row`` as it ran until the insert's slot joined the cuts'
+    rewrite: both cuts (each a one-cut split on a geometry of its own, which
+    (c) holds equal to the planned pair), a geometry of the document they
+    leave, on which the insert finds its index and its obliterates, every
+    kind's writes, and last a shift of every column for the insert's slot."""
+    I32 = jnp.int32
+    kind, key, client, ref_seq = op[0], op[1], op[2], op[3]
+    pos1, pos2, a, b = op[4], op[5], op[6], op[7]
+    is_insert, is_remove = kind == K.INSERT, kind == K.REMOVE
+    is_annotate, is_ack = kind == K.ANNOTATE, kind == K.ACK
+    is_range = is_remove | is_annotate
+    is_ob = (kind >= K.OBLITERATE) if flag else False
+
+    geom = mk._geometry(s, ref_seq, client)
+    total = jnp.sum(geom[1])
+    cut1, cut2 = pos1, pos2
+    do_cut1, do_cut2 = is_insert | is_range, is_range
+    if flag:
+        start_pos, end_pos = pos1 + a, pos2 + b
+        valid = ((0 <= pos1) & (pos1 <= pos2) & (pos2 < total)
+                 & (start_pos <= end_pos))
+        ob_ok = is_ob & valid
+        cut1 = jnp.where(is_ob, start_pos, cut1)
+        cut2 = jnp.where(is_ob, end_pos, cut2)
+        do_cut1, do_cut2 = do_cut1 | ob_ok, do_cut2 | ob_ok
+    s = _one_cut_reference(s, geom, cut1, do_cut1)
+    s = _one_cut_reference(s, mk._geometry(s, ref_seq, client), cut2, do_cut2)
+    vis, vlen, excl = mk._geometry(s, ref_seq, client)
+    alive = mk._alive(s)
+    in_range = vis & (excl >= pos1) & (excl + vlen <= pos2) & (vlen > 0)
+    error = s.error | jnp.where(is_range & (pos2 > total), mk.ERR_POS_RANGE, 0)
+
+    text_len = a
+    stop = alive & (excl >= pos1) & ((vlen > 0) | mk._tiebreak(s, key))
+    k = mk._first_true(stop, s.nseg)
+    text_over = is_insert & (s.text_end + text_len > text_capacity)
+    fits = is_insert & ~text_over
+    write = mk._TextWrite(
+        s.text_end, jnp.where(fits, jnp.clip(text_len, 0, payload.shape[0]), 0))
+    new_rem_k, new_rem_c, obpre, swallow_over = (
+        mk._obliterate_swallow(
+            s, mk._ob_anchor_indices(s), k, key, client, ref_seq)
+        if flag else mk._no_obliterate_swallow(s))
+    n_props = len(s.prop_keys)
+    new = mk._NewSeg(
+        seg_start=s.text_end, seg_len=text_len, ins_key=key,
+        ins_client=client, seg_uid=s.uid_next, seg_obpre=obpre,
+        rem_keys=new_rem_k, rem_clients=new_rem_c,
+        prop_keys=tuple(jnp.full((), -1, I32) for _ in range(n_props)),
+        prop_vals=tuple(jnp.zeros((), I32) for _ in range(n_props)))
+    ok = fits & (pos1 <= total)
+    error = (error
+             | jnp.where(text_over, mk.ERR_TEXT_OVERFLOW, 0)
+             | jnp.where(is_insert & (pos1 > total), mk.ERR_POS_RANGE, 0)
+             | jnp.where(ok & swallow_over, mk.ERR_REM_OVERFLOW, 0))
+
+    stamp = in_range & is_remove
+    if flag:
+        cont_s = vis & (excl <= pos1) & (pos1 < excl + vlen)
+        cont_e = vis & (excl <= pos2) & (pos2 < excl + vlen)
+        s_idx = mk._first_true(cont_s, s.nseg)
+        e_idx = mk._first_true(cont_e, s.nseg)
+        lo = s_idx + (a == mk.SIDE_AFTER).astype(I32)
+        hi = e_idx - (b == mk.SIDE_BEFORE).astype(I32)
+        idx = jnp.arange(s.seg_len.shape[0], dtype=I32)
+        visit, skip = mk._obliterate_visit(s, vis, key, client, ref_seq)
+        stamp = stamp | (
+            ob_ok & alive & (idx >= lo) & (idx <= hi) & visit & ~skip)
+        free = s.ob_key < 0
+        has_free = jnp.any(free)
+        at_slot = (ob_ok & has_free) & (
+            jnp.arange(s.ob_key.shape[0], dtype=I32)
+            == mk._first_true(free, jnp.asarray(0, I32)))
+        put = lambda arr, val: jnp.where(at_slot, val, arr)
+        s = s._replace(
+            ob_key=put(s.ob_key, key), ob_client=put(s.ob_client, client),
+            ob_start_uid=put(s.ob_start_uid, s.seg_uid[s_idx]),
+            ob_end_uid=put(s.ob_end_uid, s.seg_uid[e_idx]),
+            ob_start_side=put(s.ob_start_side, a),
+            ob_end_side=put(s.ob_end_side, b),
+            ob_ref_seq=put(s.ob_ref_seq, ref_seq))
+        error = (error
+                 | jnp.where(is_ob & ~valid, mk.ERR_POS_RANGE, 0)
+                 | jnp.where(ob_ok & ~has_free, mk.ERR_OB_OVERFLOW, 0))
+
+    rem_keys, rem_clients, stamp_over = mk._splice_remove_stamp(
+        s, stamp, key, client)
+    s = s._replace(rem_keys=rem_keys, rem_clients=rem_clients)
+    error = error | jnp.where(stamp_over, mk.ERR_REM_OVERFLOW, 0)
+    s = mk._annotate_marked(s, in_range & is_annotate, op)
+    s = mk._restamp_acked(s, op, is_ack)
+    s = _open_slot(s._replace(error=error), k, ok, new)
+    return s._replace(
+        text_end=s.text_end + jnp.where(ok, text_len, 0),
+        uid_next=s.uid_next + ok.astype(I32)), write
+
+
+CT, CL = 64, 4                      # the small document's pool and payload
+OP_KEY = REF_SEQ + 1
+
+
+@functools.lru_cache(maxsize=None)
+def _row_programs(flag):
+    """(the row, the reference row) over a batch of documents."""
+    return tuple(
+        jax.jit(jax.vmap(functools.partial(
+            row, flag=flag, text_capacity=CT)))
+        for row in (mk._apply_row, _reference_row))
+
+
+def _stack(docs):
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *docs)
+
+
+def _unstack(state):
+    n_docs = state.nseg.shape[0]
+    return [jax.tree.map(lambda x, d=d: x[d], state) for d in range(n_docs)]
+
+
+def _rows_agree(docs, ops, flag, note, between=None):
+    """Each document through its ROWS of ``ops`` ([D, rows, 8]), by the row
+    and by the reference row, each side carrying its own state (and both
+    through ``between`` after every row but the last): every leaf equal after
+    every row.  Returns the states after each row."""
+    new_row, ref_row = _row_programs(flag)
+    got = want = _stack(docs)
+    ops = np.asarray(ops, np.int32)
+    pays = np.arange(1, 1 + CL, dtype=np.int32) + 10 * np.arange(
+        len(docs), dtype=np.int32)[:, None]
+    after = []
+    for i in range(ops.shape[1]):
+        got, got_w = new_row(got, jnp.asarray(ops[:, i]), jnp.asarray(pays))
+        want, want_w = ref_row(want, jnp.asarray(ops[:, i]), jnp.asarray(pays))
+        _assert_states_equal(got, want, (note, "row", i))
+        for g, w in zip(got_w, want_w):
+            assert np.array_equal(np.asarray(g), np.asarray(w)), (note, i)
+        after.append(jax.tree.map(np.asarray, want))
+        if between is not None and i + 1 < ops.shape[1]:
+            got, want = between(got), between(want)
+            _assert_states_equal(got, want, (note, "between", i))
+    return after
+
+
+# Where an insert lands in ``_cut_doc``'s document, seen from (REF_SEQ,
+# CLIENT): segments 1, 4 and 7 are visible and anchor obliterates; 2 and 5
+# are not visible (a later insert, an acked remove); 3 is the client's own
+# pending insert.
+def _insert_at(place, doc):
+    vis, excl = _visible_excl(doc)
+    total = int((np.asarray(doc.seg_len) * vis).sum())
+    return {
+        "splits_4": int(excl[_K_CUT1]) + _OFF1,
+        "splits_1": int(excl[_K_BEFORE]) + 2,
+        "splits_7": int(excl[_K_AFTER]) + 4,
+        "splits_7_last_char": int(excl[_K_AFTER]) + _LENS[_K_AFTER] - 1,
+        "boundary_before_1": int(excl[_K_BEFORE]),
+        "boundary_after_1_before_invisible_2": int(excl[2]),
+        "boundary_before_4": int(excl[_K_CUT1]),
+        "boundary_after_4_before_removed_5": int(excl[5]),
+        "boundary_before_7": int(excl[_K_AFTER]),
+        "boundary_after_7": int(excl[_K_AFTER]) + _LENS[_K_AFTER],
+        "zero": 0,
+        "negative": -2,
+        "at_the_end": total,
+        "past_the_end": total + 1,
+    }[place]
+
+
+INSERT_PLACES = [
+    "splits_4", "splits_1", "splits_7", "splits_7_last_char",
+    "boundary_before_1", "boundary_after_1_before_invisible_2",
+    "boundary_before_4", "boundary_after_4_before_removed_5",
+    "boundary_before_7", "boundary_after_7", "zero", "negative",
+    "at_the_end", "past_the_end"]
+# nseg: room for the split and the insert; for the split alone (the insert is
+# refused); for neither (the split is refused, its left half trimmed all the
+# same, and so is the insert).
+INSERT_FILLS = {"room": CS - 4, "two_slots": CS - 2, "last_slot": CS - 1,
+                "full": CS}
+# The obliterates the insert meets: ``_cut_doc``'s five windows (both sides
+# of both kinds on segments 1, 4 and 7) under keys the row's perspective has
+# seen, has not seen (concurrent: they swallow), has not seen and outnumber
+# the R remove slots, or pending at the row's own client.
+OB_TABLES = {
+    "seen": ([3, 4, 5, 6, 7], [2, 2, 2, 2, 2]),
+    "concurrent": ([REF_SEQ + 1, 4, REF_SEQ + 3, 6, 7], [2, 2, 3, 2, 2]),
+    "concurrent_over_R": ([REF_SEQ + 1, REF_SEQ + 2, REF_SEQ + 3,
+                           REF_SEQ + 4, REF_SEQ + 5], [2, 3, 4, 2, 3]),
+    "own_pending": ([mk.LOCAL_BASE + 2, REF_SEQ + 2, 5, mk.LOCAL_BASE + 4, 7],
+                    [CLIENT, 2, 2, CLIENT, 3]),
+    "others_pending": ([mk.LOCAL_BASE + 2, REF_SEQ + 2, 5, 6, REF_SEQ + 4],
+                       [3, 2, 2, 2, CLIENT]),
+    # A sixth window, from After segment 4 to After segment 7: where the cut
+    # of segment 4 finds no room its start anchor is on no segment, and the
+    # R stamps of the two windows around it fit.
+    "start_after_the_holder": ([3, 4, 5, REF_SEQ + 1, REF_SEQ + 2, REF_SEQ + 3],
+                               [2, 2, 2, 2, 3, 4]),
+}
+
+
+def _with(doc, **fields):
+    """``doc`` with whole leaves replaced, or single elements ({index:
+    value}) of a leaf."""
+    out = {}
+    for name, v in fields.items():
+        if isinstance(v, dict):
+            arr = np.array(getattr(doc, name))
+            for i, x in v.items():
+                arr[i] = x
+            v = arr
+        out[name] = v
+    return doc._replace(**out)
+
+
+def _insert_doc(rng, nseg, table=None, text_end=20):
+    """``_cut_doc`` with the obliterate table of a gate that is on (``table``
+    of ``OB_TABLES``) or off (None: empty)."""
+    doc = _cut_doc(rng, nseg)
+    ob_key, ob_client = np.full(COB, -1, np.int32), np.full(COB, -1, np.int32)
+    if table is not None:
+        keys, clients = OB_TABLES[table]
+        ob_key[:len(keys)], ob_client[:len(keys)] = keys, clients
+    return _with(
+        doc, ob_key=ob_key, ob_client=ob_client,
+        ob_start_uid={5: doc.seg_uid[_K_CUT1]}, ob_end_uid={5: doc.seg_uid[_K_AFTER]},
+        ob_start_side={5: mk.SIDE_AFTER}, ob_end_side={5: mk.SIDE_AFTER},
+        text_end=np.int32(text_end), error=np.int32(0))
+
+
+def _insert_op(pos, key=OP_KEY, client=CLIENT, ref=REF_SEQ, text_len=3):
+    return [K.INSERT, key, client, ref, pos, 0, text_len, 0]
+
+
+@pytest.mark.parametrize("table", [None, *OB_TABLES])
+@pytest.mark.parametrize("fill", INSERT_FILLS)
+@pytest.mark.parametrize("place", INSERT_PLACES)
+def test_insert_in_the_cuts_rewrite_equals_a_slot_of_its_own(place, fill, table):
+    """One insert, then a second one at each place around it (so that it
+    meets the halves, the new segment and the anchors the first one moved),
+    in documents of every fill, under every obliterate table and with the
+    gate off."""
+    rng = np.random.default_rng(INSERT_PLACES.index(place))
+    nseg = INSERT_FILLS[fill]
+    docs, ops = [], []
+    for second in INSERT_PLACES:
+        doc = _insert_doc(rng, nseg, table)
+        first_at = _insert_at(place, doc)
+        second_at = _insert_at(second, doc)
+        # The second row's places are the first document's: past the first
+        # insert they lie its length higher.
+        second_at += 3 if second_at > first_at else 0
+        docs.append(doc)
+        ops.append([_insert_op(first_at),
+                    _insert_op(second_at, key=OP_KEY + 1, ref=OP_KEY)])
+    after = _rows_agree(docs, ops, table is not None, (place, fill, table))
+    # The case is the case it says it is: what the first row did.
+    first = jax.tree.map(lambda x: x[0], after[0])
+    splits = place.startswith("splits")
+    in_range = place != "past_the_end"
+    want_uids = splits + in_range
+    assert int(first.uid_next) == 200 + want_uids
+    assert int(first.nseg) == min(nseg + want_uids, CS)
+    assert int(first.text_end) == 20 + 3 * in_range
+    bits = int(first.error)
+    assert bool(bits & mk.ERR_SEG_OVERFLOW) == (nseg + want_uids > CS)
+    assert bool(bits & mk.ERR_POS_RANGE) == (not in_range)
+    if table == "concurrent_over_R" and place == "splits_4":
+        # Inside more windows than there are remove slots, whether or not
+        # the segment lands; in a full document the right half is gone, and
+        # the After-side anchors that followed it bound no window.
+        assert bool(bits & mk.ERR_REM_OVERFLOW) == (fill != "full")
+    if table == "concurrent" and place == "splits_4" and fill == "room":
+        # Swallowed on arrival, between the halves of the anchor's segment:
+        # inside the window that ends After the right half (key REF_SEQ + 3),
+        # outside the one that starts After it (key REF_SEQ + 1).
+        k = _K_CUT1 + 1
+        assert int(first.seg_uid[k]) == 201 and int(first.seg_len[k]) == 3
+        assert int(first.rem_keys[0][k]) == REF_SEQ + 3
+        assert int(first.rem_keys[1][k]) == mk.NO_REMOVE
+        assert int(first.seg_obpre[k]) == REF_SEQ + 3
+    if table in (None, "seen"):
+        landed = np.asarray(first.seg_uid[:int(first.nseg)]) == 200 + splits
+        if landed.any():
+            assert int(first.rem_keys[0][np.argmax(landed)]) == mk.NO_REMOVE
+
+
+def test_walk_behind_a_cut_that_found_no_room():
+    """A full document whose prefix sums fall (a negative length, which no
+    encoder makes): the cut of segment 4 finds no room, what follows it lies
+    the lost half's length lower, and segment 7 is then BELOW the insert's
+    position and no stop.  The insert is refused either way; where the walk
+    ends decides which windows it would have met, and so the remove-slot
+    latch."""
+    doc = _insert_doc(np.random.default_rng(5), CS)
+    three = slice(0, 3)
+    doc = _with(
+        doc, seg_len={6: -4},
+        # Neither the removed segment 5 nor segment 6 wins the tie-break
+        # against key 4.
+        ins_key={5: 9, 6: 8}, ins_client={6: 2},
+        # Three concurrent windows from Before segment 7 to After segment 9.
+        ob_key={three: [REF_SEQ + 1, REF_SEQ + 2, REF_SEQ + 3]},
+        ob_client={three: [2, 3, 4]},
+        ob_start_uid={three: doc.seg_uid[7]}, ob_end_uid={three: doc.seg_uid[9]},
+        ob_start_side={three: mk.SIDE_BEFORE}, ob_end_side={three: mk.SIDE_AFTER})
+    vis, excl = _visible_excl(doc)
+    assert vis[[4, 6, 7, 8, 9]].all() and not vis[5]
+    at = int(excl[_K_CUT1]) + _OFF1
+    assert excl[7] >= at > excl[7] - (_LENS[_K_CUT1] - _OFF1)
+    (after,) = _rows_agree([doc], [[_insert_op(at, key=4)]], True, "no room")
+    # Past segment 7 the walk is inside all three windows: one stamp too many.
+    assert int(after.error[0]) == mk.ERR_SEG_OVERFLOW | mk.ERR_REM_OVERFLOW
+    assert int(after.nseg[0]) == CS and int(after.uid_next[0]) == 202
+
+
+TIEBREAK_KEYS = {
+    # Against segment 5's acked remove (key 4) and the acked inserts around
+    # it: an older key loses the insert clause and wins by the remove.
+    "older_than_the_remove": 2,
+    "the_removes_own_key": 4,
+    "newer": OP_KEY,
+    "same_key_as_a_neighbour": None,       # drawn from the document
+    "pending": mk.LOCAL_BASE + 7,
+}
+
+
+@pytest.mark.parametrize("key", TIEBREAK_KEYS)
+@pytest.mark.parametrize("gate", [False, True], ids=["no_ob", "ob"])
+def test_insert_tiebreak_reads_the_document_before_the_cut(key, gate):
+    """The boundary walk against segments that are not visible: removed
+    ones, later inserts, the same key (a grouped batch), from an acked and a
+    pending op, at every boundary and split of the document."""
+    rng = np.random.default_rng(len(key))
+    docs, ops = [], []
+    for place in INSERT_PLACES:
+        doc = _insert_doc(rng, CS - 4, "concurrent" if gate else None)
+        op_key = TIEBREAK_KEYS[key]
+        if op_key is None:
+            op_key = int(doc.ins_key[5])
+        # Another client's op, so that segment 3 (this client's pending
+        # insert) is no longer visible either.
+        docs.append(doc)
+        ops.append([_insert_op(_insert_at(place, doc), key=op_key, client=4),
+                    _insert_op(_insert_at(place, doc), key=op_key, client=4)])
+    after = _rows_agree(docs, ops, gate, (key, gate))
+    landed = [int(np.argmax(np.asarray(after[0].seg_uid[d]) >= 200))
+              for d in range(len(docs))]
+    assert len(set(landed)) > 5
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["no_ob", "ob"])
+@pytest.mark.parametrize("fill", INSERT_FILLS)
+def test_insert_that_overflows_the_pool_still_cuts(fill, gate):
+    """``text_over``: the boundary is cut, a uid spent on the right half,
+    nothing lands and the pool's end stays.  Lengths no encoder makes land
+    the same segment in both bodies: one the payload cannot hold, and a
+    negative one, after which the second row walks prefix sums that fall."""
+    rng = np.random.default_rng(3)
+    docs, ops = [], []
+    for text_len, text_end in [(5, CT - 4), (CT + 1, 0), (1, CT), (0, CT),
+                               (-1, CT - 2), (CL + 3, 10)]:
+        for place in ("splits_4", "boundary_before_7"):
+            doc = _insert_doc(rng, INSERT_FILLS[fill],
+                              "concurrent" if gate else None, text_end)
+            docs.append(doc)
+            ops.append([_insert_op(_insert_at(place, doc), text_len=text_len),
+                        _insert_op(_insert_at(place, doc), key=OP_KEY + 1,
+                                   ref=OP_KEY)])
+    after = _rows_agree(docs, ops, gate, fill)
+    bits = np.asarray(after[0].error)
+    assert (bits[:6] & mk.ERR_TEXT_OVERFLOW).all()
+    assert not (bits[6:] & mk.ERR_TEXT_OVERFLOW).any()
+    assert (np.asarray(after[0].text_end)[:2] == CT - 4).all()
+
+
+def _compact_to(min_seq, flag):
+    """A summary ack's zamboni over a batch of documents."""
+    return jax.jit(jax.vmap(
+        lambda s: mk.compact(mk.set_min_seq(s, min_seq), flag)))
+
+
+@pytest.mark.parametrize("min_seq", [REF_SEQ, OP_KEY + 1, OP_KEY + 4])
+@pytest.mark.parametrize("fill", ["room", "two_slots", "full"])
+@pytest.mark.parametrize("table", ["concurrent", "concurrent_over_R",
+                                   "others_pending"])
+def test_inserts_at_an_obliterates_edge_across_a_compaction(table, fill, min_seq):
+    """The acks cell's sequence, which nothing held before: an insert at an
+    obliterate's edge, a summary ack's ``set_min_seq`` + ``compact`` (records
+    expire, the gate may close, evicted segments move every index), and a
+    second insert at each edge of the same document, ``error`` bit for bit.
+    The gate stays on in both bodies, as a fleet's does while any document
+    holds a record."""
+    rng = np.random.default_rng(min_seq)
+    docs, ops = [], []
+    for first in ("splits_4", "boundary_before_4", "splits_7",
+                  "boundary_after_1_before_invisible_2"):
+        for second in ("splits_1", "boundary_before_4", "splits_4",
+                       "boundary_after_4_before_removed_5", "splits_7",
+                       "boundary_after_7"):
+            doc = _insert_doc(rng, INSERT_FILLS[fill], table)
+            first_at, second_at = (_insert_at(p, doc) for p in (first, second))
+            second_at += 3 if second_at > first_at else 0
+            docs.append(doc)
+            ops.append([
+                _insert_op(first_at, client=4),
+                _insert_op(second_at, key=OP_KEY + 5, client=4, ref=OP_KEY),
+                _insert_op(second_at + 1, key=OP_KEY + 6, client=2,
+                           ref=OP_KEY + 5)])
+    after = _rows_agree(docs, ops, True, (table, fill, min_seq),
+                        between=_compact_to(min_seq, True))
+    # The acked records at or under the floor expired.
+    left = sum(k >= mk.LOCAL_BASE or k > min_seq for k in OB_TABLES[table][0])
+    assert ((np.asarray(after[-1].ob_key) >= 0).sum(axis=1) == left).all()
+
+
+def _random_rows(rng, docs, flag, step):
+    """One row a document, of every kind, at positions around its visible
+    length from the row's own perspective."""
+    ops = np.zeros((len(docs), 8), np.int32)
+    seq = OP_KEY + step
+    for d, doc in enumerate(docs):
+        u = rng.random()
+        kind = (K.INSERT if u < 0.5 else K.REMOVE if u < 0.62
+                else K.ANNOTATE if u < 0.7 else K.ACK if u < 0.78
+                else (K.OBLITERATE if flag else K.REMOVE) if u < 0.95
+                else K.NOOP)
+        client, ref = int(rng.integers(0, 4)), int(seq - rng.integers(0, 4))
+        key = (int(mk.LOCAL_BASE + rng.integers(1, 4))
+               if rng.random() < 0.2 else seq)
+        _vis, vlen, _excl = mk._geometry(doc, ref, client)
+        total = int(np.asarray(vlen).sum())
+        p1 = int(rng.integers(-1, total + 2))
+        p2 = int(rng.integers(p1, total + 2))
+        a = b = 0
+        if kind == K.INSERT:
+            a = int(rng.integers(0, CL + 1)) if rng.random() < 0.95 else CT
+        elif kind == K.ANNOTATE:
+            a, b = int(rng.integers(0, CP)), int(rng.integers(0, 50))
+        elif kind == K.ACK:
+            a, b = int(rng.integers(1, 4)), seq
+            client = client if rng.random() < 0.5 else -1
+            ref = ref if rng.random() < 0.5 else -1
+        elif kind == K.OBLITERATE:
+            a, b = int(rng.integers(0, 2)), int(rng.integers(0, 2))
+            if total > 0 and rng.random() < 0.9:
+                p1 = int(rng.integers(0, total))
+                p2 = int(rng.integers(p1, total))
+        ops[d] = [kind, key, client, ref, p1, p2, a, b]
+    return ops
+
+
+@pytest.mark.parametrize("gate", [False, True], ids=["no_ob", "ob"])
+@pytest.mark.parametrize("seed", range(4))
+def test_rows_of_every_kind_equal_the_reference_row(seed, gate):
+    """Streams of rows of every kind over documents that fill up, overflow
+    their remove slots, their obliterate table and their pool, with a
+    compaction now and then: every leaf after every row."""
+    rng = np.random.default_rng(7000 + seed)
+    new_row, ref_row = _row_programs(gate)
+    n_docs = 24
+    state = _stack([
+        _insert_doc(rng, int(rng.choice([3, 9, CS - 2, CS - 1, CS])),
+                    rng.choice(list(OB_TABLES)) if gate else None)
+        for _ in range(n_docs)])
+    seen = set()
+    for step in range(40):
+        ops = jnp.asarray(_random_rows(rng, _unstack(state), gate, step))
+        pays = jnp.asarray(rng.integers(1, 99, (n_docs, CL)).astype(np.int32))
+        got, got_w = new_row(state, ops, pays)
+        want, want_w = ref_row(state, ops, pays)
+        _assert_states_equal(got, want, (seed, gate, step))
+        assert all(np.array_equal(np.asarray(g), np.asarray(w))
+                   for g, w in zip(got_w, want_w))
+        seen |= set(np.asarray(want.error).tolist())
+        # The latch is cleared on most rows, so that later rows latch anew.
+        state = want._replace(error=jnp.zeros_like(want.error)) \
+            if step % 3 else want
+        if step % 8 == 7:
+            state = _compact_to(OP_KEY + step - 2, gate)(state)
+    assert any(b & mk.ERR_SEG_OVERFLOW for b in seen)
+    assert any(b & mk.ERR_POS_RANGE for b in seen)
+
+
+def _drive(program, state, ops, pays):
+    """``ops`` [n, D, B, 8] through ``program`` of ``_STEP_PROGRAMS``."""
+    n_docs = ops.shape[1]
+    if program == "fleet":
+        step = jax.jit(mk.apply_fleet_ops)
+        for o, p in zip(ops, pays):
+            state = step(state, o, p)
+    elif program == "megastep_k2":
+        step = jax.jit(mk.apply_megastep)
+        for i in range(0, ops.shape[0], 2):
+            state = step(state, ops[i:i + 2], pays[i:i + 2])
+    else:
+        # A cohort of every other document of a fleet twice as large, its
+        # pool left in the fleet's.
+        rows = jnp.arange(n_docs, dtype=jnp.int32) * 2
+        pool = jnp.zeros((2 * n_docs, CT), jnp.int32).at[rows].set(state.text)
+        sub = state._replace(text=jnp.zeros((n_docs, 0), jnp.int32))
+        step = jax.jit(mk.apply_cohort_ops)
+        for o, p in zip(ops, pays):
+            pool, sub = step(pool, sub, rows, o, p)
+        state = sub._replace(text=pool[rows])
+    return state
+
+
+@pytest.mark.parametrize("program", ["fleet", "megastep_k2", "cohort"])
+def test_step_programs_equal_the_reference_row(program, monkeypatch):
+    """``apply_fleet_ops``, ``apply_megastep`` (K = 2) and
+    ``apply_cohort_ops`` over slices of rows of every kind (the gate opens
+    with the first obliterate), against the same programs with the reference
+    row for their body."""
+    rng = np.random.default_rng(81)
+    n_docs, n_slices, depth = 8, 4, 4
+    state = _stack([
+        _insert_doc(rng, fill, None) for fill in (1, 2, 3, 5, 9, CS - 2, CS, 4)])
+    ops = np.zeros((n_slices, n_docs, depth, 8), np.int32)
+    step_state = state
+    row = _row_programs(True)[1]
+    for i in range(n_slices):
+        for j in range(depth):
+            ops[i, :, j] = _random_rows(
+                rng, _unstack(step_state), i > 0, i * depth + j)
+            step_state, _w = row(step_state, jnp.asarray(ops[i, :, j]),
+                                 jnp.zeros((n_docs, CL), jnp.int32))
+    pays = rng.integers(1, 99, (n_slices, n_docs, depth, CL)).astype(np.int32)
+    ops, pays = jnp.asarray(ops), jnp.asarray(pays)
+    assert (ops[..., 0] == K.OBLITERATE).any()
+    got = _drive(program, state, ops, pays)
+    monkeypatch.setattr(mk, "_apply_row", _reference_row)
+    want = _drive(program, state, ops, pays)
+    _assert_states_equal(got, want, program)
+    assert len({int(n) for n in np.asarray(want.nseg)}) > 2
+    assert len({int(e) for e in np.asarray(want.error)}) > 2
 
 
 # ------------------------------------------------ the pool's strip write

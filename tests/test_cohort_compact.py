@@ -234,6 +234,92 @@ def test_served_per_document_equals_fleet_wide_equals_oracle():
         srv.stop()
 
 
+# ------------------------------- 2b. inserts at an obliterate's edge
+def _edge_round(a, b, doc, at: int, edge: str) -> None:
+    """``b`` obliterates [at, at + 3] with both sides inward and ``a``, which
+    has not seen it, inserts at one ``edge`` of that window: sequenced after
+    the obliterate, concurrent with it."""
+    b.obliterate_range_sided((at, True), (at + 3, False))
+    where = {"before_start": at, "inside_at_start": at + 1,
+             "inside_at_end": at + 3, "after_end": at + 4}[edge]
+    a.insert_text(where, "XY")
+    _flush(doc, [b, a])
+
+
+@pytest.mark.parametrize("floor", ["record_live", "record_expired"])
+@pytest.mark.parametrize("path", ["cohort", "fleet_wide"])
+def test_inserts_at_an_obliterates_edge_across_a_summary_ack(path, floor):
+    """The acks cell's sequence at engine level: an insert at an obliterate's
+    edge, a summary ack (``compact(docs)``: the record expires or stays, the
+    gate may close, evicted segments move every index), then a second
+    obliterate and a second insert at an edge of the same document.  No
+    document leaves the batch, none latches, every text is the oracle's."""
+    edges = ["before_start", "inside_at_start", "inside_at_end", "after_end"]
+    # A cohort is at most a quarter of the fleet; the documents beside it
+    # never hold a record, so the cohort's gate is its own.
+    n_docs, busy = (16, 4) if path == "cohort" else (4, 4)
+    svc = LocalService()
+    eng = DocBatchEngine(n_docs, **GEOM)
+    docs, ws, summ = [], [], []
+    for d in range(busy):
+        doc = svc.document(f"x{d}")
+        docs.append(doc)
+        ws.append(_writers(doc, f"x{d}"))
+        summ.append({})
+        ws[d][0].insert_text(0, "abcdefghijklmnopqrst")
+        _flush(doc, ws[d])
+    fed = [0] * busy
+
+    def feed():
+        for d, doc in enumerate(docs):
+            log = doc.sequencer.log
+            eng.ingest_lines(d, _wire(log[fed[d]:]))
+            fed[d] = len(log)
+        eng.step()
+        for d, doc in enumerate(docs):
+            a, b = ws[d]
+            assert a.text == b.text == oracle_text(doc.sequencer.log)
+            assert eng.text(d) == a.text, (d, edges[d])
+        h = eng.health()
+        assert not eng.errors().any()
+        assert not (h["overflow_docs"] or h["oracle_docs"]
+                    or h["quarantined_docs"])
+
+    for d, doc in enumerate(docs):
+        _edge_round(*ws[d], doc, 4, edges[d])
+    feed()
+    assert (np.asarray(eng.state.ob_key) >= 0).any()
+    # Inside the window the insert was swallowed on arrival, outside it not.
+    assert ["XY" in ws[d][0].text for d in range(busy)] == [
+        True, False, False, True]
+    for d, doc in enumerate(docs):
+        a, b = ws[d]
+        if floor == "record_expired":
+            # Both writers move on, so the MSN passes the obliterate.
+            for i in range(3):
+                (a, b)[i % 2].insert_text(0, "12"[i % 2])
+                _flush(doc, [a, b])
+        _summarize(doc, summ[d])
+    for d, doc in enumerate(docs):
+        eng.ingest_lines(d, _wire(doc.sequencer.log[fed[d]:]))
+        fed[d] = len(doc.sequencer.log)
+    eng.compact(range(busy))     # what FleetConsumer.pump does on the acks
+    eng.step()
+    assert eng.counters.get("compacted_docs") == busy
+    live = (np.asarray(eng.state.ob_key) >= 0).any()
+    assert live == (floor == "record_live")
+    for d, doc in enumerate(docs):
+        # The second window overlaps the first one's far edge.
+        at = len(ws[d][0].text) - 14
+        _edge_round(*ws[d], doc, at, edges[(d + 1) % 4])
+        _edge_round(*ws[d], doc, 2, edges[(d + 2) % 4])
+    feed()
+    if path == "cohort":
+        assert eng._built and not eng._full_built
+    else:
+        assert eng._full_built and not eng._built
+
+
 # ----------------------------------------------------------------- 3. order
 def _tombstone_history(tail: int):
     """A remove, an insert concurrent with it that resolves its position

@@ -1,6 +1,9 @@
 """Differential: matrix TPU kernel vs host SharedMatrix oracle."""
 
+import json
+import os
 import random
+import sys
 
 import numpy as np
 import pytest
@@ -13,6 +16,11 @@ from fluidframework_tpu.ops import matrix_kernel as mxk
 from fluidframework_tpu.server.local_service import LocalDocument
 
 from test_shared_matrix import make_matrices, pump
+
+sys.path.insert(0, os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"))
+
+import matrix_reference  # noqa: E402
 
 
 _FLEET_STEP = jax.jit(mxk.apply_matrix_fleet)
@@ -121,3 +129,71 @@ def test_fww_kernel_semantics(step):
     pump(doc, [a, b])
     state = replay_through_kernel(doc, value_intern=lambda v: int(v), step=step)
     assert mxk.to_grid(state) == a.to_grid() == [[7]]
+
+
+def _perm_entries(perm):
+    """A permutation vector handle by handle, in document order: (handle,
+    insert seq, remove seqs)."""
+    n = int(perm.nseg)
+    text = np.asarray(perm.text)
+    rem = [np.asarray(r)[:n] for r in perm.rem_keys]
+    out = []
+    for i in range(n):
+        start, length = int(perm.seg_start[i]), int(perm.seg_len[i])
+        removes = sorted(int(r[i]) for r in rem if r[i] != mxk.mk.NO_REMOVE)
+        out += [(int(h), int(perm.ins_key[i]), removes)
+                for h in text[start:start + length]]
+    return out
+
+
+def _reference_entries(perm):
+    return [(e.handle, e.ins_seq, sorted(s for s, _c in e.removes))
+            for e in perm.entries]
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+@pytest.mark.parametrize("vector", ["rows", "cols"])
+def test_inserts_at_a_split_and_on_a_boundary_match_the_plain_reference(
+        vector, step):
+    """Row and column inserts that land inside a run of handles inserted
+    together (the run's segment is split and the new one goes between its
+    halves, in the same rewrite of the vector's columns), on the boundary
+    between two runs, at both ends, and concurrently at one place, against
+    the benchmark's plain reference: every handle of the vector with its
+    stamps, in order, and the grid."""
+    doc = LocalDocument("d")
+    a, b = make_matrices(doc, 2)
+    ins = {"rows": SharedMatrix.insert_rows, "cols": SharedMatrix.insert_cols}
+    rem = {"rows": SharedMatrix.remove_rows, "cols": SharedMatrix.remove_cols}
+    other = "cols" if vector == "rows" else "rows"
+    ins[other](a, 0, 2)
+    ins[vector](a, 0, 6)            # one run: handles 0-5
+    pump(doc, [a, b])
+    ins[vector](a, 3, 2)            # splits the run
+    pump(doc, [a, b])
+    ins[vector](b, 3, 1)            # on the boundary the split left
+    ins[vector](a, 5, 1)            # concurrent: the other boundary
+    pump(doc, [a, b])
+    ins[vector](a, 1, 1)            # splits the left half again
+    ins[vector](b, 1, 2)            # ... and so does b, at the same place
+    pump(doc, [a, b])
+    rem[vector](b, 2, 3)            # a remove whose ends split two runs
+    ins[vector](a, 4, 1)            # concurrent, inside the removed range
+    pump(doc, [a, b])
+    ins[vector](a, 0, 1)            # both ends
+    ins[vector](b, b.row_count if vector == "rows" else b.col_count, 1)
+    pump(doc, [a, b])
+    a.set_cell(1, 1, 42)
+    pump(doc, [a, b])
+
+    state = replay_through_kernel(doc, value_intern=int, step=step)
+    assert int(state.error) == 0
+    table = matrix_reference.replay(
+        json.loads(m.wire_line()) for m in doc.sequencer.log)
+    for name in ("rows", "cols"):
+        got = _perm_entries(getattr(state, name))
+        assert got == _reference_entries(getattr(table, name)), name
+    assert len(_perm_entries(getattr(state, vector))) == 16
+    # Splits happened: more segments than inserts of the vector.
+    assert int(getattr(state, vector).nseg) > 9
+    assert mxk.to_grid(state) == table.grid() == a.to_grid()

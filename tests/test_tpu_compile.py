@@ -210,6 +210,39 @@ def test_cohort_trio_moves_rows_and_never_the_pool(one_chip, monkeypatch):
                    "bitcast", "while"}, ops
 
 
+def test_fleet_step_has_no_pass_of_its_own_for_the_inserts_slot(
+        one_chip, monkeypatch):
+    """``_fleet_step`` at fleet_main's geometry (6,144 x 4,096 x 65,536,
+    B = 32): the insert's segment rides the second slot of the boundary
+    cuts' rewrite, so no instruction under ``insert/open_slot`` makes a
+    whole [6144, 4096] column (five fusions and 6.65 of a row's 29.56 GB
+    until PR 45), the cuts' rewrite is still there under its own scope, and
+    what the program keeps beside its arguments stays under the 2.27 GiB it
+    kept with that pass."""
+    from fluidframework_tpu.models import doc_batch_engine as dbe
+    from fluidframework_tpu.ops import mergetree_kernel as mk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_docs, segments = 6144, 4096
+    proto = jax.eval_shape(lambda: mk.init_state(segments, 4, 4, 65536, 8))
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (n_docs, *x.shape), x.dtype, sharding=one_chip), proto)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)
+
+    compiled = dbe._fleet_step.lower(
+        state, arg(n_docs, 32, mk.OP_FIELDS), arg(n_docs, 32, 8)).compile()
+    column = re.compile(r" = \(?[^=]*s32\[%d,%d\]" % (n_docs, segments))
+    scoped = [ln for ln in compiled.as_text().splitlines()
+              if "/open_slot" in ln and column.search(ln.split(" fusion(")[0])]
+    assert scoped and all(
+        "shared/ensure_boundary/open_slot" in ln for ln in scoped)
+    assert not [ln for ln in scoped if "insert/open_slot" in ln]
+    assert compiled.memory_analysis().temp_size_in_bytes < int(2.27 * 2**30)
+
+
 def test_matrix_step_keeps_the_cell_planes_in_place(one_chip, monkeypatch):
     """The matrix fleet's one program at matrix_fleet_2048x256's geometry:
     the four [D, HR, HC] planes go through the row loop aliased, touched by

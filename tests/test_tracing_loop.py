@@ -327,9 +327,11 @@ def test_every_apply_op_branch_is_scoped_in_the_lowered_step():
     # helpers nested as before; the kinds keep theirs for what only they do.
     assert mk.SHARED_SCOPE == "shared"
     for path in (*mk.BRANCH_SCOPES, "shared",
-                 "shared/ensure_boundary/open_slot", "shared/mark_range",
-                 "insert/open_slot"):
+                 "shared/ensure_boundary/open_slot", "shared/mark_range"):
         assert re.search(rf'loc\("(?:[^"]*/)?{path}(?:/[^"]*)?"', text), path
+    # The insert's slot is the second of the cuts' one rewrite: the kind
+    # has no pass over the columns of its own.
+    assert not re.search(r'loc\("(?:[^"]*/)?insert/open_slot', text)
     # No kind's scope encloses the shared phase: a trace reads it as no
     # kind's time, not as the first kind's that happens to call a helper.
     for kind in mk.BRANCH_SCOPES:
